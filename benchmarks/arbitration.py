@@ -17,9 +17,7 @@ number that matters is the wall clock of the whole arbitration window:
 
 Medians of TRIALS runs on the tiny model; a fresh orchestrator + plane
 per trial (grants mutate placements), compiled steps shared across
-trials. On a multi-chip TPU the real devices are used; elsewhere the
-CPU platform is forced to 8 virtual chips — smoke numbers, same
-protocol.
+trials. Needs 4 accelerator chips; with fewer it exits non-zero.
 
 Every row is one machine-readable JSON line; the LAST line is the
 ``arbitration_seconds`` headline ``bench.py`` forwards (value = the
@@ -34,33 +32,12 @@ import sys
 sys.path.insert(0, str(__import__('pathlib').Path(__file__).parent.parent))
 
 import json
-import os
 import tempfile
 import time
-
-if os.environ.get('_ARBITRATION_VIRTUAL'):
-    from tpusystem.parallel import force_host_platform
-    force_host_platform(8)
 
 import jax
 
 TRIALS = 3
-
-
-def _ensure_devices():
-    """Real 8-chip mesh when it exists; else re-exec onto an 8-device
-    virtual CPU mesh (force_host_platform must precede backend init, so
-    a fresh process is the only clean path — the fsdp_overlap pattern)."""
-    devices = jax.devices()
-    if len(devices) >= 8:
-        return devices[:8]
-    env = dict(os.environ)
-    env['_ARBITRATION_VIRTUAL'] = '1'
-    env['JAX_PLATFORMS'] = 'cpu'
-    flag = '--xla_force_host_platform_device_count'
-    if flag not in env.get('XLA_FLAGS', ''):
-        env['XLA_FLAGS'] = (env.get('XLA_FLAGS', '') + f' {flag}=8').strip()
-    os.execve(sys.executable, [sys.executable] + sys.argv, env)
 
 
 class _Runner:
@@ -78,7 +55,7 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import materialize
+    from bench import materialize, require_chips
     from tpusystem.checkpoint import Checkpointer
     from tpusystem.checkpoint.memstore import HotState, MemStore, blob_digest
     from tpusystem.models import gpt2_tiny
@@ -88,7 +65,7 @@ def main() -> None:
     from tpusystem.train import (AdamW, NextTokenLoss, build_train_step,
                                  flax_apply, init_state)
 
-    devices = _ensure_devices()
+    devices = require_chips(4)
     identity = 'bench-arbitration'
     spec = MeshSpec(fsdp=4)
     module = gpt2_tiny()

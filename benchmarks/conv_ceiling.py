@@ -56,8 +56,8 @@ def time_op(fn, x, w) -> float:
     by zero; a data dependency defeats CSE/hoisting), so every iteration
     really runs the op. The chain adds one x-sized broadcast-add per rep
     — the realistic inter-op condition inside a residual network. 1000
-    reps keep the ~15 ms per-dispatch relay overhead under 1% even for
-    the smallest conv."""
+    reps keep the per-dispatch host overhead small even for the smallest
+    conv."""
     def body(_, carry):
         y = fn(carry, w)
         feedback = y[(0,) * y.ndim].astype(carry.dtype)

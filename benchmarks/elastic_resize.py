@@ -14,9 +14,8 @@ the alternative it replaces, a disk restore onto the shrunk mesh:
    what a non-elastic restart would pay *after* the relaunch).
 
 Both arms are medians of TRIALS runs on the tiny model, both end with
-the params materialized on host. On a multi-chip TPU the real devices
-are used; elsewhere the CPU platform is forced to 4 virtual chips —
-smoke numbers, same protocol.
+the params materialized on host. Needs 4 accelerator chips; with fewer
+it exits non-zero.
 
 Every row is one machine-readable JSON line; the LAST line is the
 ``resize_seconds`` headline ``bench.py`` forwards.
@@ -30,40 +29,19 @@ import sys
 sys.path.insert(0, str(__import__('pathlib').Path(__file__).parent.parent))
 
 import json
-import os
 import tempfile
 import time
-
-if os.environ.get('_ELASTIC_RESIZE_VIRTUAL'):
-    from tpusystem.parallel import force_host_platform
-    force_host_platform(4)
 
 import jax
 
 TRIALS = 3
 
 
-def _ensure_devices():
-    """Real 4-chip mesh when it exists; else re-exec onto a 4-device
-    virtual CPU mesh (force_host_platform must precede backend init, so
-    a fresh process is the only clean path — the fsdp_overlap pattern)."""
-    devices = jax.devices()
-    if len(devices) >= 4:
-        return devices[:4]
-    env = dict(os.environ)
-    env['_ELASTIC_RESIZE_VIRTUAL'] = '1'
-    env['JAX_PLATFORMS'] = 'cpu'
-    flag = '--xla_force_host_platform_device_count'
-    if flag not in env.get('XLA_FLAGS', ''):
-        env['XLA_FLAGS'] = (env.get('XLA_FLAGS', '') + f' {flag}=4').strip()
-    os.execve(sys.executable, [sys.executable] + sys.argv, env)
-
-
 def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import materialize
+    from bench import materialize, require_chips
     from tpusystem.checkpoint import Checkpointer
     from tpusystem.checkpoint.memstore import HotState, blob_digest
     from tpusystem.models import gpt2_tiny
@@ -72,10 +50,10 @@ def main() -> None:
     from tpusystem.train import (AdamW, NextTokenLoss, build_train_step,
                                  flax_apply, init_state)
 
-    devices = _ensure_devices()
+    devices = require_chips(4)
     identity = 'bench-elastic'
     spec = MeshSpec(fsdp=4)
-    mesh4 = spec.build(devices)
+    mesh4 = spec.build(devices[:4])
     module = gpt2_tiny()
     optimizer = AdamW(lr=1e-3)
     policy = TensorParallel(module.partition_rules(), fsdp=True,

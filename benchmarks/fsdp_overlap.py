@@ -25,11 +25,7 @@ is timed too. `python benchmarks/fsdp_overlap.py` prints the table +
 summary; `... headline` prints the single JSON line `bench.py` forwards
 (`fsdp_overlap_speedup_vs_gspmd`).
 
-Hardware: uses the real accelerator mesh when >= 2 devices are present
-(real numbers); otherwise re-execs itself onto an 8-device virtual CPU
-mesh at smoke shapes — same code paths, scheduler-free numbers that only
-smoke-test the sweep (BASELINE.md "tp_overlap protocol" applies
-verbatim: XLA:CPU has no latency-hiding scheduler).
+Hardware: needs >= 2 accelerator chips; with fewer it exits non-zero.
 """
 
 from __future__ import annotations
@@ -39,12 +35,7 @@ sys.path.insert(0, str(__import__('pathlib').Path(__file__).parent.parent))
 
 import functools
 import json
-import os
 import time
-
-if os.environ.get('_FSDP_OVERLAP_VIRTUAL'):
-    from tpusystem.parallel import force_host_platform
-    force_host_platform(8)
 
 import jax
 import jax.numpy as jnp
@@ -53,32 +44,11 @@ from flax import linen as nn
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from bench import materialize as _materialize
+from bench import materialize as _materialize, require_chips
 
-
-def _ensure_devices():
-    """Real accelerator mesh when it exists; else re-exec onto the
-    virtual CPU mesh (force_host_platform must precede backend init, so
-    a fresh process is the only clean path)."""
-    devices = jax.devices()
-    if devices[0].platform != 'cpu' and len(devices) >= 2:
-        return devices, False
-    if devices[0].platform == 'cpu' and len(devices) >= 4:
-        return devices, True
-    env = dict(os.environ)
-    env['_FSDP_OVERLAP_VIRTUAL'] = '1'
-    flag = '--xla_force_host_platform_device_count'
-    if flag not in env.get('XLA_FLAGS', ''):
-        env['XLA_FLAGS'] = (env.get('XLA_FLAGS', '') + f' {flag}=8').strip()
-    os.execve(sys.executable, [sys.executable] + sys.argv, env)
-
-
-DEVICES, VIRTUAL = _ensure_devices()
+DEVICES = require_chips(2)
 RING = max(size for size in (2, 4) if size <= len(DEVICES))
-# smoke shapes on the virtual mesh (XLA:CPU has no latency-hiding
-# scheduler — the rows only prove the sweep runs); real shapes on chips
-BATCH, SEQ, DIM, FFN, REPS = ((8, 64, 256, 1024, 5) if VIRTUAL
-                              else (8, 1024, 4096, 14336, 20))
+BATCH, SEQ, DIM, FFN, REPS = 8, 1024, 4096, 14336, 20
 CHUNK_COUNTS = (1, 2, 4)
 
 
@@ -126,7 +96,7 @@ def _build(include_composed: bool = True):
     fsdp x model rows — their operands are a SECOND full device_put of
     every tensor onto the composed mesh (~300 MB of extra HBM +
     host-to-device at the real shapes), which ``headline`` never times."""
-    from tpusystem.parallel.mesh import FSDP, MeshSpec, shard_map
+    from tpusystem.parallel.mesh import FSDP, MeshSpec
     from tpusystem.parallel.schedule import (OverlapSchedule, fsdp_plan,
                                              prefetched, scheduled_ffn)
     from tpusystem.parallel.sharding import fsdp_shard_dim
@@ -161,8 +131,8 @@ def _build(include_composed: bool = True):
     b_down_repl = put(b_down, P(None))
 
     def manual(body, in_specs, out_specs):
-        return shard_map(body, mesh=mesh, check_vma=False,
-                         in_specs=in_specs, out_specs=out_specs)
+        return jax.shard_map(body, mesh=mesh, check_vma=False,
+                             in_specs=in_specs, out_specs=out_specs)
 
     cases = {}
 
@@ -254,8 +224,7 @@ def sweep() -> dict[str, float]:
         ((chunks, times[f'ffn[overlap c{chunks}]']) for chunks in CHUNK_COUNTS),
         key=lambda pair: pair[1])
     summary = {
-        'mesh': f"{DEVICES[0].platform} fsdp={RING}"
-                + (' (virtual smoke)' if VIRTUAL else ''),
+        'mesh': f"{DEVICES[0].platform} fsdp={RING}",
         'batch': BATCH, 'seq': SEQ, 'dim': DIM, 'ffn': FFN,
         'ffn_us': {tag.split('[')[1][:-1]: round(times[tag] * 1e6, 1)
                    for tag in times if tag.startswith('ffn[')},
@@ -284,8 +253,7 @@ def headline() -> None:
         'metric': 'fsdp_overlap_speedup_vs_gspmd',
         'value': round(speedup, 4),
         'unit': 'x',
-        'mesh': f"{DEVICES[0].platform} fsdp={RING}"
-                + (' (virtual smoke)' if VIRTUAL else ''),
+        'mesh': f"{DEVICES[0].platform} fsdp={RING}",
         'chunks': best_chunks,
         'gspmd_us': round(times['ffn[gspmd]'] * 1e6, 1),
         'overlap_us': round(best * 1e6, 1),

@@ -7,7 +7,7 @@ FLOPs per step come from XLA's own cost model on the compiled single-step
 program (`compile().cost_analysis()['flops']`): it counts the executed
 fwd+bwd+optimizer HLO, so the number is an *executed*-FLOPs utilization —
 marginally above a hand-counted model-FLOPs MFU (optimizer/elementwise
-included), stated as such in BASELINE.md.
+included).
 """
 import sys, time, json, pathlib
 sys.path.insert(0, str(pathlib.Path(__file__).parent.parent))
@@ -21,16 +21,6 @@ from tpusystem.train import (AdamW, CrossEntropyLoss, build_train_step,
                              flax_apply, init_state)
 
 
-def _flops(compiled) -> float:
-    """XLA cost-model FLOPs per executed program; ``cost_analysis()``
-    returns a dict on current jax and a one-element list of dicts on the
-    0.4.x pins — accept both."""
-    analysis = compiled.cost_analysis()
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
-    return float(analysis.get('flops', 0.0))
-
-
 def measure(tag, module, inputs, targets, steps):
     optimizer = AdamW(lr=1e-3)
     state = init_state(module, optimizer, inputs[:1])
@@ -38,7 +28,7 @@ def measure(tag, module, inputs, targets, steps):
                             optimizer, jit=False)
 
     single = jax.jit(lambda st: step(st, inputs, targets)[0])
-    flops = _flops(single.lower(state).compile())
+    flops = float(single.lower(state).compile().cost_analysis()['flops'])
 
     @partial(jax.jit, donate_argnums=0)
     def run(state):
@@ -58,9 +48,8 @@ def measure(tag, module, inputs, targets, steps):
         'workload': tag, 'steps_per_sec': round(steps_per_sec, 2),
         'flops_per_step': float(flops),
         'examples_per_sec': round(steps_per_sec * inputs.shape[0], 1),
+        'mfu': round(flops * steps_per_sec / peak, 4),
     }
-    if peak:
-        result['mfu'] = round(flops * steps_per_sec / peak, 4)
     print(json.dumps(result))
 
 
@@ -81,12 +70,6 @@ def composed_row(steps: int = 20):
         return
     from tpusystem.parallel import (MeshSpec, OverlapSchedule,
                                     PipelineParallel, batch_sharding)
-    from tpusystem.parallel.mesh import partial_manual_skip_reason
-    reason = partial_manual_skip_reason()
-    if reason is not None:
-        print(json.dumps({'workload': 'composed_gpt2_pp_tp_fsdp_moe',
-                          'mfu': None, 'note': f'skipped: {reason[:140]}'}))
-        return
     from tpusystem.models import GPT2Pipelined
     from tpusystem.train import (NextTokenLoss, WithAuxLoss,
                                  build_train_step, flax_apply)
@@ -111,7 +94,7 @@ def composed_row(steps: int = 20):
                             optimizer, jit=False)
 
     single = jax.jit(lambda st: step(st, placed, placed)[0])
-    flops = _flops(single.lower(state).compile())
+    flops = float(single.lower(state).compile().cost_analysis()['flops'])
 
     @partial(jax.jit, donate_argnums=0)
     def run(state):
@@ -131,11 +114,9 @@ def composed_row(steps: int = 20):
               'mesh': {axis: size for axis, size in mesh.shape.items()
                        if size > 1},
               'steps_per_sec': round(steps_per_sec, 3),
-              'flops_per_step': float(flops)}
-    if peak:
-        # per-chip MFU: executed FLOPs over every chip's peak
-        result['mfu'] = round(flops * steps_per_sec
-                              / (peak * len(devices)), 4)
+              'flops_per_step': float(flops),
+              # per-chip MFU: executed FLOPs over every chip's peak
+              'mfu': round(flops * steps_per_sec / (peak * len(devices)), 4)}
     print(json.dumps(result))
 
 

@@ -17,11 +17,7 @@ All rows are fwd+bwd with the conv_ceiling data-chained discipline.
 ``... headline`` prints the single JSON line `bench.py` forwards
 (`moe_a2a_overlap_speedup`).
 
-Hardware: uses the real accelerator mesh when >= 2 devices are present
-(real numbers); otherwise re-execs itself onto an 8-device virtual CPU
-mesh at smoke shapes — same code paths, scheduler-free numbers that only
-smoke-test the sweep (XLA:CPU has no latency-hiding scheduler; see
-BASELINE.md "pp/moe overlap protocol").
+Hardware: needs >= 2 accelerator chips; with fewer it exits non-zero.
 """
 
 from __future__ import annotations
@@ -30,40 +26,18 @@ import sys
 sys.path.insert(0, str(__import__('pathlib').Path(__file__).parent.parent))
 
 import json
-import os
 import time
-
-if os.environ.get('_MOE_A2A_VIRTUAL'):
-    from tpusystem.parallel import force_host_platform
-    force_host_platform(8)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from bench import materialize as _materialize
+from bench import materialize as _materialize, require_chips
 
-
-def _ensure_devices():
-    devices = jax.devices()
-    if devices[0].platform != 'cpu' and len(devices) >= 2:
-        return devices, False
-    if devices[0].platform == 'cpu' and len(devices) >= 4:
-        return devices, True
-    env = dict(os.environ)
-    env['_MOE_A2A_VIRTUAL'] = '1'
-    flag = '--xla_force_host_platform_device_count'
-    if flag not in env.get('XLA_FLAGS', ''):
-        env['XLA_FLAGS'] = (env.get('XLA_FLAGS', '') + f' {flag}=8').strip()
-    os.execve(sys.executable, [sys.executable] + sys.argv, env)
-
-
-DEVICES, VIRTUAL = _ensure_devices()
+DEVICES = require_chips(2)
 EXPERT_AX = max(size for size in (2, 4) if size <= len(DEVICES))
-# smoke shapes on the virtual mesh; real shapes on chips
-TOKENS, DIM, EXPERTS, REPS = ((512, 128, 4, 5) if VIRTUAL
-                              else (8192, 2048, 16, 20))
+TOKENS, DIM, EXPERTS, REPS = 8192, 2048, 16, 20
 
 
 def time_fwd_bwd(fn, *args) -> float:
@@ -106,7 +80,7 @@ def _build():
     data = len(DEVICES) // EXPERT_AX
     mesh = MeshSpec(data=data, expert=EXPERT_AX).build(DEVICES)
     rng = np.random.default_rng(0)
-    dtype = jnp.float32 if VIRTUAL else jnp.bfloat16
+    dtype = jnp.bfloat16
     hidden = jnp.asarray(rng.normal(size=(TOKENS, DIM)) * 0.1, jnp.float32)
     local_rows = TOKENS // (data * EXPERT_AX)
     assert moe_plan(local_rows, EXPERT_AX).path == 'overlap', (
@@ -144,8 +118,7 @@ def sweep() -> dict[str, float]:
         print(json.dumps({'phase': tag, 'us': round(seconds * 1e6, 1),
                           'note': note}))
     print(json.dumps({'summary': {
-        'mesh': f"{DEVICES[0].platform} expert={EXPERT_AX}"
-                + (' (virtual smoke)' if VIRTUAL else ''),
+        'mesh': f"{DEVICES[0].platform} expert={EXPERT_AX}",
         'tokens': TOKENS, 'dim': DIM, 'experts': EXPERTS,
         'overlap_vs_one_shot': round(times['moe[one-shot]']
                                      / times['moe[overlap]'], 3),
@@ -161,8 +134,7 @@ def headline() -> None:
         'metric': 'moe_a2a_overlap_speedup',
         'value': round(times['moe[one-shot]'] / times['moe[overlap]'], 4),
         'unit': 'x',
-        'mesh': f"{DEVICES[0].platform} expert={EXPERT_AX}"
-                + (' (virtual smoke)' if VIRTUAL else ''),
+        'mesh': f"{DEVICES[0].platform} expert={EXPERT_AX}",
         'one_shot_us': round(times['moe[one-shot]'] * 1e6, 1),
         'overlap_us': round(times['moe[overlap]'] * 1e6, 1),
     }))

@@ -20,12 +20,7 @@ inputs — nothing hoists or DCEs). ``python benchmarks/pp_overlap.py``
 prints the table + summary; ``... headline`` prints the single JSON line
 `bench.py` forwards (`pp_overlap_speedup_vs_gspmd`).
 
-Hardware: uses the real accelerator mesh when >= 2 devices are present
-(real numbers); otherwise re-execs itself onto an 8-device virtual CPU
-mesh at smoke shapes — same code paths, scheduler-free numbers that only
-smoke-test the sweep (XLA:CPU has no latency-hiding scheduler, and the
-skewed schedule's extra fill ticks make the virtual ratio < 1; see
-BASELINE.md "pp/moe overlap protocol").
+Hardware: needs >= 2 accelerator chips; with fewer it exits non-zero.
 """
 
 from __future__ import annotations
@@ -34,40 +29,18 @@ import sys
 sys.path.insert(0, str(__import__('pathlib').Path(__file__).parent.parent))
 
 import json
-import os
 import time
-
-if os.environ.get('_PP_OVERLAP_VIRTUAL'):
-    from tpusystem.parallel import force_host_platform
-    force_host_platform(8)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from bench import materialize as _materialize
+from bench import materialize as _materialize, require_chips
 
-
-def _ensure_devices():
-    devices = jax.devices()
-    if devices[0].platform != 'cpu' and len(devices) >= 2:
-        return devices, False
-    if devices[0].platform == 'cpu' and len(devices) >= 4:
-        return devices, True
-    env = dict(os.environ)
-    env['_PP_OVERLAP_VIRTUAL'] = '1'
-    flag = '--xla_force_host_platform_device_count'
-    if flag not in env.get('XLA_FLAGS', ''):
-        env['XLA_FLAGS'] = (env.get('XLA_FLAGS', '') + f' {flag}=8').strip()
-    os.execve(sys.executable, [sys.executable] + sys.argv, env)
-
-
-DEVICES, VIRTUAL = _ensure_devices()
+DEVICES = require_chips(2)
 STAGES = max(size for size in (2, 4) if size <= len(DEVICES))
-# smoke shapes on the virtual mesh; real shapes on chips
-LAYERS, BATCH, DIM, MICRO, REPS = ((STAGES * 2, 8, 256, 4, 5) if VIRTUAL
-                                   else (STAGES * 2, 8, 4096, 8, 20))
+LAYERS, BATCH, DIM, MICRO, REPS = STAGES * 2, 8, 4096, 8, 20
 CHUNK_COUNTS = (1, 2)
 
 
@@ -106,7 +79,7 @@ def _build():
 
     mesh = MeshSpec(stage=STAGES, data=len(DEVICES) // STAGES).build(DEVICES)
     rng = np.random.default_rng(0)
-    dtype = jnp.float32 if VIRTUAL else jnp.bfloat16
+    dtype = jnp.bfloat16
     weights = jnp.asarray(
         rng.normal(size=(LAYERS, DIM, DIM)) * (1.0 / np.sqrt(DIM)), dtype)
     inputs = jnp.asarray(rng.normal(size=(BATCH * MICRO
@@ -144,8 +117,7 @@ def sweep() -> dict[str, float]:
     overlaps = {tag: t for tag, t in times.items() if 'overlap' in tag}
     best_tag, best = min(overlaps.items(), key=lambda pair: pair[1])
     print(json.dumps({'summary': {
-        'mesh': f"{DEVICES[0].platform} stage={STAGES}"
-                + (' (virtual smoke)' if VIRTUAL else ''),
+        'mesh': f"{DEVICES[0].platform} stage={STAGES}",
         'layers': LAYERS, 'batch': BATCH, 'dim': DIM, 'microbatches': MICRO,
         'best_overlap': best_tag,
         'overlap_vs_classic': round(times['pipe[classic]'] / best, 3),
@@ -163,8 +135,7 @@ def headline() -> None:
         'metric': 'pp_overlap_speedup_vs_gspmd',
         'value': round(times['pipe[classic]'] / best, 4),
         'unit': 'x',
-        'mesh': f"{DEVICES[0].platform} stage={STAGES}"
-                + (' (virtual smoke)' if VIRTUAL else ''),
+        'mesh': f"{DEVICES[0].platform} stage={STAGES}",
         'chunks': int(best_tag.split('c')[-1].rstrip(']')),
         'classic_us': round(times['pipe[classic]'] * 1e6, 1),
         'overlap_us': round(best * 1e6, 1),

@@ -1,9 +1,7 @@
-"""Probe: where does the scan+Pallas AOT compile time go on the relay?
+"""Probe: where does the scan+Pallas compile time go?
 
-Round-3 finding: the headline bench keeps the unrolled stack because the
-relay's AOT compiler took ~500 s on the scan+Pallas composition (XLA:CPU
-compiles the same program in seconds). This probe times ``lower()`` and
-``compile()`` separately for one composition so the slow axis (scan,
+The headline bench keeps the unrolled stack. This probe times ``lower()``
+and ``compile()`` separately for one composition so the slow axis (scan,
 flash kernel, remat, steps-loop) can be bisected.
 
 Run (one composition per process — a hung compile shouldn't block the

@@ -20,10 +20,7 @@ inputs — nothing hoists or DCEs). `python benchmarks/tp_overlap.py`
 prints the table + summary; `... headline` prints the single JSON line
 `bench.py` forwards (`tp_ffn_overlap_speedup_vs_gspmd`).
 
-Hardware: uses the real accelerator mesh when >= 2 devices are present
-(real numbers); otherwise re-execs itself onto an 8-device virtual CPU
-mesh at smoke shapes — same code paths, scheduler-free numbers that only
-smoke-test the sweep (BASELINE.md "tp_overlap protocol").
+Hardware: needs >= 2 accelerator chips; with fewer it exits non-zero.
 """
 
 from __future__ import annotations
@@ -33,12 +30,7 @@ sys.path.insert(0, str(__import__('pathlib').Path(__file__).parent.parent))
 
 import functools
 import json
-import os
 import time
-
-if os.environ.get('_TP_OVERLAP_VIRTUAL'):
-    from tpusystem.parallel import force_host_platform
-    force_host_platform(8)
 
 import jax
 import jax.numpy as jnp
@@ -47,32 +39,11 @@ from flax import linen as nn
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from bench import materialize as _materialize
+from bench import materialize as _materialize, require_chips
 
-
-def _ensure_devices():
-    """Real accelerator mesh when it exists; else re-exec onto the
-    virtual CPU mesh (force_host_platform must precede backend init, so
-    a fresh process is the only clean path)."""
-    devices = jax.devices()
-    if devices[0].platform != 'cpu' and len(devices) >= 2:
-        return devices, False
-    if devices[0].platform == 'cpu' and len(devices) >= 4:
-        return devices, True
-    env = dict(os.environ)
-    env['_TP_OVERLAP_VIRTUAL'] = '1'
-    flag = '--xla_force_host_platform_device_count'
-    if flag not in env.get('XLA_FLAGS', ''):
-        env['XLA_FLAGS'] = (env.get('XLA_FLAGS', '') + f' {flag}=8').strip()
-    os.execve(sys.executable, [sys.executable] + sys.argv, env)
-
-
-DEVICES, VIRTUAL = _ensure_devices()
+DEVICES = require_chips(2)
 RING = max(size for size in (2, 4) if size <= len(DEVICES))
-# smoke shapes on the virtual mesh (XLA:CPU has no latency-hiding
-# scheduler — the rows only prove the sweep runs); real shapes on chips
-TOKENS, DIM, FFN, REPS = ((512, 256, 1024, 5) if VIRTUAL
-                          else (8192, 4096, 14336, 20))
+TOKENS, DIM, FFN, REPS = 8192, 4096, 14336, 20
 CHUNK_COUNTS = (1, 2, 4)
 
 
@@ -116,7 +87,7 @@ def _report(tag, seconds, note=None):
 
 
 def _build():
-    from tpusystem.parallel.mesh import MODEL, MeshSpec, shard_map
+    from tpusystem.parallel.mesh import MODEL, MeshSpec
     from tpusystem.parallel.overlap import (allgather_matmul,
                                             matmul_reducescatter)
 
@@ -142,8 +113,8 @@ def _build():
     down_rows = put(w_down, P(MODEL, None))
 
     def manual(body, in_specs, out_specs):
-        return shard_map(body, mesh=mesh, check_vma=False,
-                         in_specs=in_specs, out_specs=out_specs)
+        return jax.shard_map(body, mesh=mesh, check_vma=False,
+                             in_specs=in_specs, out_specs=out_specs)
 
     cases = {}
 
@@ -223,8 +194,7 @@ def sweep() -> dict[str, float]:
         ((chunks, times[f'ffn[overlap c{chunks}]']) for chunks in CHUNK_COUNTS),
         key=lambda pair: pair[1])
     print(json.dumps({'summary': {
-        'mesh': f"{DEVICES[0].platform} model={RING}"
-                + (' (virtual smoke)' if VIRTUAL else ''),
+        'mesh': f"{DEVICES[0].platform} model={RING}",
         'tokens': TOKENS, 'dim': DIM, 'ffn': FFN,
         'ffn_us': {tag.split('[')[1][:-1]: round(times[tag] * 1e6, 1)
                    for tag in times if tag.startswith('ffn[')},
@@ -249,8 +219,7 @@ def headline() -> None:
         'metric': 'tp_ffn_overlap_speedup_vs_gspmd',
         'value': round(speedup, 4),
         'unit': 'x',
-        'mesh': f"{DEVICES[0].platform} model={RING}"
-                + (' (virtual smoke)' if VIRTUAL else ''),
+        'mesh': f"{DEVICES[0].platform} model={RING}",
         'chunks': best_chunks,
         'gspmd_us': round(times['ffn[gspmd]'] * 1e6, 1),
         'overlap_us': round(best * 1e6, 1),

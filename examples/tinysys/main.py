@@ -64,7 +64,7 @@ def main(epochs: int = 10) -> None:
     runtime.producer.register(saver)
     runtime.producer.register(logging_consumer())
     training.producer = runtime.producer   # handlers dispatch on the runtime bus
-    # 8 jitted steps per host dispatch: the per-batch Python/relay cost is
+    # 8 jitted steps per host dispatch: the per-batch Python cost is
     # paid once per 8 batches (events/metrics keep phase cadence)
     training.provider.override(training.steps_per_dispatch, lambda: 8)
 
@@ -113,4 +113,6 @@ def main(epochs: int = 10) -> None:
 
 
 if __name__ == '__main__':
+    from tpusystem.runtime import compile_cache
+    compile_cache()
     main(int(sys.argv[1]) if len(sys.argv) > 1 else 10)

@@ -238,13 +238,14 @@ class TestCorruptCheckpoints:
         one-call resume path."""
         loader, state, step = make_parts()
         with Checkpointer(tmp_path, async_save=False) as checkpointer:
-            state_two = None
+            step_two = None
             while int(state.step) < 3:
                 for inputs, targets in loader:
                     state, _ = step(state, inputs, targets)
                     checkpointer.save(IDENTITY, int(state.step), state)
                     if int(state.step) == 2:
-                        state_two = state
+                        # a host copy: the next step donates `state`
+                        step_two = np.asarray(state.step)
                     if int(state.step) == 3:
                         break
         # corrupt step 3's payload but keep every integrity marker
@@ -256,8 +257,8 @@ class TestCorruptCheckpoints:
             with caplog.at_level(logging.WARNING, 'tpusystem.checkpoint'):
                 restored, resumed_step, _ = fresh.resume(IDENTITY, blank)
             assert resumed_step == 2
-            np.testing.assert_array_equal(
-                np.asarray(restored.step), np.asarray(state_two.step))
+            np.testing.assert_array_equal(np.asarray(restored.step),
+                                          step_two)
         assert 'falling back' in caplog.text
 
     def test_repository_auto_version_respects_in_flight_async_save(
@@ -1149,8 +1150,7 @@ def test_multiprocess_kill_at_step_restart_resumes_bitwise(tmp_path):
            for output in outputs):
         # same jaxlib gap that fails tests/test_multiprocess.py's training
         # workers on this host: the CPU backend cannot execute
-        # cross-process computations at all (probe precedent:
-        # parallel/mesh.py partial_manual_skip_reason)
+        # cross-process computations at all
         pytest.skip('this jaxlib cannot run multiprocess computations '
                     'on the CPU backend')
     for proc, output in zip(procs, outputs):

@@ -23,7 +23,7 @@ from tpusystem.models.llama import llama_tiny
 from tpusystem.parallel import (MeshSpec, ShardingPolicy, batch_sharding,
                                 allgather_matmul, allgather_plan,
                                 matmul_reducescatter, reducescatter_plan)
-from tpusystem.parallel.mesh import MODEL, shard_map
+from tpusystem.parallel.mesh import MODEL
 
 RING = 4           # >= 4-device virtual mesh (conftest forces 8 devices)
 
@@ -42,7 +42,7 @@ def _operands(dtype, rows=16, inner=12, cols=24, seed=0):
 def _mapped_allgather(mesh, chunks):
     # x row-sharded over model (the sequence-sharded activation), w
     # column-sharded (Megatron up-projection): the gathered matmul
-    @functools.partial(shard_map, mesh=mesh, check_vma=False,
+    @functools.partial(jax.shard_map, mesh=mesh, check_vma=False,
                        in_specs=(P(MODEL, None), P(None, MODEL)),
                        out_specs=P(None, MODEL))
     def mapped(x, w):
@@ -53,7 +53,7 @@ def _mapped_allgather(mesh, chunks):
 def _mapped_reducescatter(mesh, chunks):
     # x column-sharded (the grown activation), w row-sharded (Megatron
     # down-projection): partial products sum + scatter rows
-    @functools.partial(shard_map, mesh=mesh, check_vma=False,
+    @functools.partial(jax.shard_map, mesh=mesh, check_vma=False,
                        in_specs=(P(None, MODEL), P(MODEL, None)),
                        out_specs=P(MODEL, None))
     def mapped(x, w):

@@ -167,10 +167,10 @@ class TestCollectiveVocabulary:
         import jax
         from jax.sharding import PartitionSpec as P
         from tpusystem.parallel import MeshSpec
-        from tpusystem.parallel.mesh import shard_map
         mesh = MeshSpec(data=n).build(jax.devices()[:n])
-        return shard_map(fn, mesh=mesh, in_specs=P('data'),
-                         out_specs=P('data') if out_spec is None else out_spec)
+        return jax.shard_map(
+            fn, mesh=mesh, in_specs=P('data'),
+            out_specs=P('data') if out_spec is None else out_spec)
 
     def test_reductions_and_gather(self):
         import jax.numpy as jnp

@@ -14,18 +14,7 @@ import pytest
 from tpusystem.models import GPT2Pipelined
 from tpusystem.parallel import (MeshSpec, PipelineParallel, ShardingPolicy,
                                batch_sharding, pipeline_apply)
-from tpusystem.parallel.mesh import partial_manual_skip_reason
 from tpusystem.train import AdamW, NextTokenLoss, build_train_step, flax_apply, init_state
-
-# PP x TP rides a *partially manual* shard_map (stage manual, model auto)
-# that needs this jaxlib to lower PartitionId under SPMD on CPU; the
-# probe compiles the miniature composition in a subprocess and returns
-# the failure line as the skip reason where it cannot.
-_PARTIAL_MANUAL_REASON = partial_manual_skip_reason()
-needs_partial_manual = pytest.mark.skipif(
-    _PARTIAL_MANUAL_REASON is not None,
-    reason=_PARTIAL_MANUAL_REASON or 'partial-manual shard_map supported')
-
 
 def make_model(stages=4, data=2, microbatches=2, model=1, **overrides):
     mesh = MeshSpec(data=data, stage=stages, model=model).build()
@@ -437,7 +426,6 @@ def test_pp_tp_placement_shards_stage_and_model():
         ('stage', None, 'model')
 
 
-@needs_partial_manual
 def test_pp_tp_forward_matches_sequential():
     """PP x TP: with the model axis live (stage=2 x model=2) and stacked
     params model-sharded, the pipelined forward still matches the
@@ -455,7 +443,6 @@ def test_pp_tp_forward_matches_sequential():
 
 
 @pytest.mark.slow
-@needs_partial_manual
 def test_pp_tp_1f1b_matches_gpipe_autodiff_step():
     """The 1F1B schedule composes with within-stage TP: loss and updated
     params on a stage=2 x model=2 mesh match the GPipe autodiff path."""

@@ -31,7 +31,7 @@ from tpusystem.parallel import (MeshSpec, OverlapSchedule, ShardingPolicy,
                                 schedule_applicable, scheduled_ffn)
 from tpusystem.parallel.collectives import (ring_allgather,
                                             ring_reducescatter)
-from tpusystem.parallel.mesh import FSDP, MODEL, shard_map
+from tpusystem.parallel.mesh import FSDP, MODEL
 from tpusystem.parallel.sharding import fsdp_shard_dim
 
 RING = 4           # >= 4-device virtual mesh (conftest forces 8 devices)
@@ -59,13 +59,13 @@ def test_ring_allgather_is_bitwise_identical_to_lax(dimension, chunks):
         np.random.default_rng(0).normal(size=(16, 24)), jnp.float32)
     in_spec = P(FSDP, None) if dimension == 0 else P(None, FSDP)
 
-    @functools.partial(shard_map, mesh=mesh, check_vma=False,
+    @functools.partial(jax.shard_map, mesh=mesh, check_vma=False,
                        in_specs=in_spec, out_specs=P(None, None))
     def ring(shard):
         return ring_allgather(shard, FSDP, dimension=dimension,
                               chunks=chunks)
 
-    @functools.partial(shard_map, mesh=mesh, check_vma=False,
+    @functools.partial(jax.shard_map, mesh=mesh, check_vma=False,
                        in_specs=in_spec, out_specs=P(None, None))
     def monolithic(shard):
         return lax.all_gather(shard, FSDP, axis=dimension, tiled=True)
@@ -85,7 +85,7 @@ def test_ring_reducescatter_matches_psum_scatter(dimension, chunks):
         np.random.default_rng(1).normal(size=(RING, 16, 24)), jnp.float32)
     out_spec = P(FSDP, None) if dimension == 0 else P(None, FSDP)
 
-    @functools.partial(shard_map, mesh=mesh, check_vma=False,
+    @functools.partial(jax.shard_map, mesh=mesh, check_vma=False,
                        in_specs=P(FSDP, None, None), out_specs=out_spec)
     def ring(stacked):
         return ring_reducescatter(stacked[0], FSDP, dimension=dimension,
@@ -593,15 +593,8 @@ def test_compile_guard_scheduled_step_never_retraces():
 from tpusystem.models import GPT2Pipelined, gpt2_tiny  # noqa: E402
 from tpusystem.parallel import (PipelineParallel, moe_plan,  # noqa: E402
                                 pipeline_apply, pp_plan)
-from tpusystem.parallel.mesh import partial_manual_skip_reason  # noqa: E402
 from tpusystem.train import (AdamW, NextTokenLoss, WithAuxLoss,  # noqa: E402
                              build_train_step, flax_apply, init_state)
-
-_PARTIAL_MANUAL_REASON = partial_manual_skip_reason()
-needs_partial_manual = pytest.mark.skipif(
-    _PARTIAL_MANUAL_REASON is not None,
-    reason=_PARTIAL_MANUAL_REASON or 'partial-manual shard_map supported')
-
 
 def test_overlap_schedule_validates_the_new_arms():
     with pytest.raises(ValueError, match='schedule pp'):
@@ -737,6 +730,7 @@ def _moe_loss_and_grads(schedule, mesh, tokens, **overrides):
     return state.params, float(value), grads
 
 
+@pytest.mark.slow
 def test_moe_overlap_dispatch_matches_gspmd_model_level():
     """moe='overlap' on the sharded quota path: the pipelined dispatch
     (piece k+1's all_to_all under the expert matmuls of k) reproduces
@@ -759,6 +753,7 @@ def test_moe_overlap_dispatch_matches_gspmd_model_level():
                                    rtol=1e-4, atol=1e-6)
 
 
+@pytest.mark.slow
 def test_moe_overlap_ragged_exchange_falls_back_one_shot():
     """The ragged exchange keeps its single whole-batch exchange under
     moe='overlap' (pinned by moe_plan) — the knob degrades to the
@@ -818,7 +813,6 @@ def test_composed_pp_fsdp_moe_pipelined_step_is_bitwise_vs_gspmd():
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(ovl))
 
 
-@needs_partial_manual
 def test_composed_pp_tp_fsdp_moe_pipelined_step_matches_gspmd():
     """The full four-axis composition (dp-free fsdp x model x stage mesh,
     partial-manual pipeline: GSPMD partitions the stage bodies over

@@ -130,18 +130,24 @@ class TestGuardedStep:
 
     def test_finite_spike_flagged_by_zscore(self):
         """A finite 200x grad spike passes every isfinite check — only the
-        EMA z-score rung catches it (armed after warmup)."""
+        EMA z-score rung catches it (armed after warmup). Every step draws
+        a fresh batch so the grad-norm history is stationary: one batch
+        repeated is memorized within the warmup, the norm falls ~100x, and
+        the spike lands inside the range the EMAs have already seen."""
         guard = Guard(warmup=4, zmax=6.0)
         _, state, step = make_parts(
             guard=guard, fault=CorruptGrads(step=8, mode='spike', scale=200.0),
             dropout=0.0)
         rng = np.random.default_rng(0)
-        inputs = jnp.asarray(rng.standard_normal((8, 28, 28)), jnp.float32)
-        targets = jnp.asarray(rng.integers(0, 10, (8,)), jnp.int32)
+
+        def batch():
+            return (jnp.asarray(rng.standard_normal((8, 28, 28)), jnp.float32),
+                    jnp.asarray(rng.integers(0, 10, (8,)), jnp.int32))
+
         for _ in range(7):
-            state, _ = step(state, inputs, targets)
+            state, _ = step(state, *batch())
         before = snapshot(state.params)
-        state, _ = step(state, inputs, targets)
+        state, _ = step(state, *batch())
         row = np.asarray(state.health.last)
         assert row[HEALTH_OK] == 0.0 and np.isfinite(row[HEALTH_GNORM])
         assert row[HEALTH_Z] > 6.0

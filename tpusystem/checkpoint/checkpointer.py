@@ -391,7 +391,7 @@ class Checkpointer:
         A target pytree that grew optional (leafless) dataclass fields
         since the checkpoint was written — ``TrainState.health`` is the
         canonical case — fails Orbax's structure match even though every
-        *array* still lines up. On that specific key-mismatch the restore
+        *array* still lines up. On that specific mismatch the restore
         retries with the leafless fields pruned from the target
         (:func:`_shrink_empty_fields`) and grafts the arrays back into the
         caller's structure, so pre-upgrade runs keep resuming. A target
@@ -402,7 +402,9 @@ class Checkpointer:
         try:
             return manager.restore(epoch, args=ocp.args.StandardRestore(abstract))
         except ValueError as error:
-            if 'key mismatch' not in str(error).lower():
+            # orbax 0.11's wording for a restore target whose tree keys
+            # differ from the checkpoint's
+            if 'structures do not match' not in str(error):
                 raise
             logger.warning(
                 'restore target for %s/%d has fields the checkpoint '

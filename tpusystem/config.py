@@ -38,10 +38,7 @@ def load(path: str | pathlib.Path) -> dict:
     """Read a config file (``.json`` or ``.toml``) into a dict."""
     path = pathlib.Path(path)
     if path.suffix == '.toml':
-        try:
-            import tomllib            # stdlib from 3.11
-        except ModuleNotFoundError:
-            import tomli as tomllib   # the API-identical 3.10 backport
+        import tomllib
         return tomllib.loads(path.read_text())
     return json.loads(path.read_text())
 

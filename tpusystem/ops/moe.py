@@ -45,7 +45,7 @@ from flax import linen as nn
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from tpusystem.parallel.mesh import EXPERT, axis_size, shard_map
+from tpusystem.parallel.mesh import EXPERT
 
 
 def _ragged_transport(transport: str, axis: str, operand, out_init,
@@ -66,7 +66,7 @@ def _ragged_transport(transport: str, axis: str, operand, out_init,
                                      out_off, recv_sz, axis_name=axis)
     if transport != 'gathered':
         raise ValueError(f'unknown ragged transport {transport!r}')
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     me = lax.axis_index(axis)
     all_ops = lax.all_gather(operand, axis)              # [n, S, cols]
     all_in_off = lax.all_gather(in_off, axis)            # [n, n]
@@ -803,7 +803,7 @@ class MoEMLP(nn.Module):
                     .reshape(experts * piece_quota, dim))
 
         @functools.partial(
-            shard_map, mesh=mesh, check_vma=False,
+            jax.shard_map, mesh=mesh, check_vma=False,
             in_specs=(row_spec, P(), P(EXPERT, None, None), P(EXPERT, None),
                       P(EXPERT, None, None), P(EXPERT, None)),
             out_specs=(row_spec, P()))
@@ -926,7 +926,7 @@ class MoEMLP(nn.Module):
         row_spec = P(row_axes, None)
 
         @functools.partial(
-            shard_map, mesh=mesh, check_vma=False,
+            jax.shard_map, mesh=mesh, check_vma=False,
             in_specs=(row_spec, P(), P(EXPERT, None, None), P(EXPERT, None),
                       P(EXPERT, None, None), P(EXPERT, None)),
             out_specs=(row_spec, P()))

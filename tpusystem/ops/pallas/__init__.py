@@ -1,13 +1,11 @@
-"""Hand-written Pallas TPU kernels (flash attention, grouped gather-matmul).
+"""Hand-written Pallas TPU kernels (flash attention, grouped gather-matmul,
+fused decode matmuls, embedding row movement)."""
 
-Shared compat: jax renamed ``TPUCompilerParams`` -> ``CompilerParams``
-across releases; every kernel module takes the alias from here so the
-fallback logic lives once.
-"""
+from tpusystem.parallel.mesh import on_tpu
 
-from jax.experimental.pallas import tpu as pltpu
 
-CompilerParams = getattr(pltpu, 'CompilerParams', None) \
-    or pltpu.TPUCompilerParams
-
-__all__ = ['CompilerParams']
+def auto_interpret(interpret: bool | None) -> bool:
+    """Every kernel's ``interpret=None`` default: compiled by Mosaic on
+    the chip, the Pallas interpreter everywhere else (so the CPU tests
+    run the same kernel bodies)."""
+    return not on_tpu() if interpret is None else interpret

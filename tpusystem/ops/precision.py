@@ -200,50 +200,18 @@ def qdot(x, w, *, compute=None):
 
 @functools.lru_cache(maxsize=None)
 def fp8_unsupported_reason() -> str | None:
-    """Capability probe: can this jax/jaxlib cast to and matmul from
-    ``float8_e4m3fn`` on the current backend? ``None`` when it can, else
-    a reason string for ``pytest.mark.skipif`` / the ``stream_dtype=
-    'fp8'`` gate. Cached in-process AND on disk keyed by the
-    jax/jaxlib/python versions and backend (the PartitionId probe's
-    discipline, ``parallel/mesh.py``) so the probe compiles once per
-    installation. Unlike that probe this one runs in-process: an
-    unsupported fp8 dtype raises a catchable TypeError/not-implemented,
-    it never hard-aborts the runtime."""
-    import pathlib
-    import sys
-    import tempfile
+    """Capability probe: can the current backend cast to and matmul from
+    ``float8_e4m3fn``? ``None`` when it can, else a reason string for
+    ``pytest.mark.skipif`` / the ``stream_dtype='fp8'`` gate. One small
+    compile, cached for the life of the process."""
     try:
-        import jaxlib
-        jaxlib_version = getattr(jaxlib, '__version__', '?')
-    except ImportError:
-        jaxlib_version = '?'
-    key = (f'{jax.__version__}-{jaxlib_version}-'
-           f'py{sys.version_info[0]}.{sys.version_info[1]}-'
-           f'{jax.default_backend()}')
-    cache = pathlib.Path(tempfile.gettempdir()) / f'tpusystem-fp8-{key}.txt'
-    try:
-        cached = cache.read_text()
-        return None if cached == 'ok' else cached
-    except OSError:
-        pass
-    if not hasattr(jnp, 'float8_e4m3fn'):
-        reason = 'this jax has no float8_e4m3fn dtype'
-    else:
-        try:
-            @jax.jit
-            def probe(x):
-                narrow = x.astype(jnp.float8_e4m3fn)
-                return f32_accum_dot(narrow.astype(jnp.float32), narrow
-                                     .astype(jnp.float32),
-                                     (((1,), (0,)), ((), ())))
-            total = float(jnp.sum(probe(jnp.ones((8, 8), jnp.float32))))
-            reason = (None if total == 8.0 ** 3
-                      else f'fp8 round trip returned {total}, expected 512')
-        except Exception as error:   # unsupported lowering on this backend
-            reason = f'fp8 ops failed on {jax.default_backend()}: ' \
-                     f'{str(error)[:200]}'
-    try:
-        cache.write_text('ok' if reason is None else reason)
-    except OSError:
-        pass
-    return reason
+        @jax.jit
+        def probe(x):
+            narrow = x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
+            return f32_accum_dot(narrow, narrow, (((1,), (0,)), ((), ())))
+        total = float(jnp.sum(probe(jnp.ones((8, 8), jnp.float32))))
+    except Exception as error:   # unsupported lowering on this backend
+        return (f'fp8 ops failed on {jax.default_backend()}: '
+                f'{str(error)[:200]}')
+    return (None if total == 8.0 ** 3
+            else f'fp8 round trip returned {total}, expected 512')

@@ -45,7 +45,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from tpusystem.ops.attention import NEG_INF
-from tpusystem.parallel.mesh import DATA, FSDP, SEQ, axis_size, shard_map
+from tpusystem.parallel.mesh import DATA, FSDP, SEQ
 
 
 def _attention_lse(query, key, value, *, causal, scale, inner):
@@ -118,7 +118,7 @@ def ring_attention(query, key, value, *, axis: str = SEQ, causal: bool = True,
     Returns:
         local output chunk [batch, chunk, heads, head_dim].
     """
-    ring = axis_size(axis)
+    ring = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     head_dim = query.shape[-1]
     scale = scale if scale is not None else head_dim ** -0.5
@@ -234,7 +234,7 @@ def zigzag_ring_attention(query, key, value, *, axis: str = SEQ,
     and out of stripe layout is two half-chunk ``ppermute``s each way.
     Requires an even local chunk. Differentiable end to end.
     """
-    ring = axis_size(axis)
+    ring = lax.axis_size(axis)
     head_dim = query.shape[-1]
     scale = scale if scale is not None else head_dim ** -0.5
     if ring == 1:
@@ -308,7 +308,7 @@ def ulysses_attention(query, key, value, *, axis: str = SEQ,
     (full sequence, head subset), attended with the flash kernel, and
     transposed back.
     """
-    ring = axis_size(axis)
+    ring = lax.axis_size(axis)
     heads = query.shape[2]
     assert heads % ring == 0, (
         f'ulysses needs heads ({heads}) divisible by the seq axis ({ring})')
@@ -380,7 +380,7 @@ def ring_self_attention(query, key, value, mesh, *, causal: bool = True,
     # check_vma=False: the flash pallas_call inside carries no
     # varying-mesh-axis info for the replication checker
     @functools.partial(
-        shard_map, mesh=mesh, check_vma=False,
+        jax.shard_map, mesh=mesh, check_vma=False,
         in_specs=(spec, spec, spec), out_specs=spec)
     def mapped(q, k, v):
         return implementation(q, k, v)

@@ -18,8 +18,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec
 
-from tpusystem.parallel.mesh import axis_size as _axis_size
-from tpusystem.parallel.mesh import shard_map as _shard_map
 
 
 def all_reduce_sum(value, axis: str):
@@ -52,7 +50,7 @@ def ring_shift(value, axis: str, *, reverse: bool = False):
     the ``ppermute`` at the heart of ring attention and 1F1B pipelines.
     Neighbor convention: rank ``i`` sends to ``(i+1) % n`` when forward.
     """
-    size = _axis_size(axis)
+    size = lax.axis_size(axis)
     if reverse:
         permutation = [(source, (source - 1) % size) for source in range(size)]
     else:
@@ -137,7 +135,7 @@ def ring_allgather(value, axis: str, *, dimension: int = 0,
     Requires ``value.shape[0] % chunks == 0`` (callers plan around this;
     see ``schedule.fsdp_plan``).
     """
-    ring = _axis_size(axis)
+    ring = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     rows = value.shape[dimension]
     shape = list(value.shape)
@@ -172,7 +170,7 @@ def ring_reducescatter(value, axis: str, *, dimension: int = 0,
     result is cast back to ``value.dtype``. The FSDP prefetch path uses
     this as the gradient scatter (the transpose of the parameter gather).
     """
-    ring = _axis_size(axis)
+    ring = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     rows = value.shape[dimension] // ring
     sizes = list(value.shape)
@@ -195,7 +193,7 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str):
-    return _axis_size(axis)
+    return lax.axis_size(axis)
 
 
 # ---------------------------------------------------------------------------
@@ -232,7 +230,7 @@ def _checksum_program(mesh, specs, axis: str):
             vec = lax.psum(vec, others)
         return lax.all_gather(vec, axis)
 
-    mapped = _shard_map(local, mesh=mesh, in_specs=specs,
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=specs,
                         out_specs=PartitionSpec(), check_vma=False)
     return jax.jit(mapped)
 

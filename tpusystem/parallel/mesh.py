@@ -24,7 +24,6 @@ experiment identity hash, so checkpoints distinguish incompatible layouts
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Sequence
 
@@ -38,126 +37,11 @@ DATA, FSDP, MODEL, SEQ, EXPERT, STAGE = 'data', 'fsdp', 'model', 'seq', 'expert'
 AXES = (DATA, FSDP, MODEL, SEQ, EXPERT, STAGE)
 
 
-def axis_size(axis) -> int:
-    """Static size of a mapped mesh axis, inside ``shard_map``.
-
-    ``jax.lax.axis_size`` where this install has it; the classic
-    ``psum(1, axis)`` idiom (constant-folded to a Python int) where it
-    predates it. The compat twin of :func:`shard_map` below.
-    """
-    if hasattr(jax.lax, 'axis_size'):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
-              axis_names=None):
-    """``jax.shard_map`` with a fallback for jax installs that predate it.
-
-    Every manual-collective path in the repo (MoE expert dispatch, ring
-    attention, sharded flash, the pipeline schedule) routes through this
-    one seam instead of ``jax.shard_map`` directly. On current jax it is
-    a passthrough; on older installs (``jax.shard_map`` landed after
-    0.4.x) it adapts ``jax.experimental.shard_map.shard_map``:
-    ``check_vma`` maps to the old ``check_rep``, and ``axis_names`` (the
-    axes handled *manually*; all, when omitted) maps to its complement,
-    the old ``auto`` set. Caveat on the legacy path: partially-manual
-    mappings (``axis_names`` smaller than the mesh — PP x TP) lower only
-    where that jaxlib supports the PartitionId instruction under SPMD,
-    which excludes the CPU test backend.
-    """
-    if hasattr(jax, 'shard_map'):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs['axis_names'] = axis_names
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as legacy
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma, auto=auto)
-
-
-# The miniature of the pipeline's PP x TP composition: a *partially
-# manual* shard_map (stage manual, model auto) whose body ppermutes an
-# activation that GSPMD partitions over the auto axis. jaxlibs that
-# cannot lower the PartitionId instruction under SPMD on CPU fail here —
-# some with a catchable UNIMPLEMENTED, some with a fatal
-# spmd_partitioner.cc check abort — so the probe must run out-of-process.
-_PARTIAL_MANUAL_PROBE = """
-import numpy as np
-import jax, jax.numpy as jnp
-from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from tpusystem.parallel.mesh import force_host_platform, shard_map
-force_host_platform(4)
-mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ('stage', 'model'))
-x = jnp.ones((8, 8), jnp.float32)
-w = jax.device_put(jnp.ones((8, 8), jnp.float32),
-                   NamedSharding(mesh, P(None, 'model')))
-body = lambda xs, ws: lax.ppermute(xs @ ws, 'stage', [(0, 1), (1, 0)])
-mapped = shard_map(body, mesh=mesh,
-                   in_specs=(P('stage', None), P(None, None)),
-                   out_specs=P('stage', None), check_vma=False,
-                   axis_names=frozenset({'stage'}))
-print(float(jax.jit(mapped)(x, w).sum()))
-"""
-
-
-@functools.lru_cache(maxsize=None)
-def partial_manual_skip_reason() -> str | None:
-    """Capability probe: can this jaxlib lower a partially-manual
-    ``shard_map`` (the PartitionId instruction under SPMD) on CPU?
-
-    Returns ``None`` when it can, else a reason string carrying the
-    probe's error line — made for ``pytest.mark.skipif`` on the PP x TP
-    tests that exercise the pipeline's partial-manual path (see
-    :func:`shard_map`'s legacy-path caveat). Runs the probe in a
-    subprocess because failing jaxlibs may abort the whole process with
-    a fatal ``spmd_partitioner.cc`` check rather than raise. The result
-    is cached in-process (lru_cache) AND on disk keyed by the
-    jax/jaxlib/python versions, so the ~6 s probe subprocess runs once
-    per installation, not once per pytest invocation.
-    """
-    import pathlib
-    import subprocess
-    import sys
-    import tempfile
-    import jaxlib
-    key = (f"{jax.__version__}-{getattr(jaxlib, '__version__', '?')}-"
-           f'py{sys.version_info[0]}.{sys.version_info[1]}')
-    cache = (pathlib.Path(tempfile.gettempdir())
-             / f'tpusystem-partial-manual-{key}.txt')
-    try:
-        cached = cache.read_text()
-        return None if cached == 'ok' else cached
-    except OSError:
-        pass
-    repo_root = pathlib.Path(__file__).resolve().parents[2]
-    try:
-        probe = subprocess.run(
-            [sys.executable, '-c', _PARTIAL_MANUAL_PROBE],
-            capture_output=True, text=True, timeout=600,
-            cwd=str(repo_root))
-    except (OSError, subprocess.TimeoutExpired) as error:
-        return f'partial-manual shard_map probe could not run: {error}'
-    if probe.returncode == 0:
-        reason = None
-    else:
-        lines = [line.strip() for line in
-                 (probe.stderr + '\n' + probe.stdout).splitlines()
-                 if line.strip()]
-        detail = next((line for line in lines if 'PartitionId' in line
-                       or 'spmd_partitioner' in line),
-                      lines[-1] if lines else f'exit code {probe.returncode}')
-        reason = ('this jaxlib cannot lower partial-manual shard_map '
-                  f'(PartitionId under SPMD) on CPU: {detail[:200]}')
-    try:
-        cache.write_text('ok' if reason is None else reason)
-    except OSError:
-        pass
-    return reason
+def on_tpu() -> bool:
+    """The one "are we on the chip" predicate: kernels pick compiled vs
+    interpret mode, and the serving levers pick their defaults, from
+    this and nothing else."""
+    return jax.default_backend() == 'tpu'
 
 
 def force_host_platform(n_devices: int = 8) -> None:
@@ -165,10 +49,8 @@ def force_host_platform(n_devices: int = 8) -> None:
 
     The standard way to exercise mesh/collective code (DP/FSDP/TP/PP/SP/EP)
     without TPU hardware: the test suite and ``dryrun_multichip`` both run on
-    a virtual CPU mesh set up by this call. Setting ``JAX_PLATFORMS=cpu`` in
-    the environment is NOT enough when an accelerator plugin is installed
-    (plugins prepend themselves to ``jax_platforms``); forcing the config
-    after import wins.
+    a virtual CPU mesh set up by this call, which sets the device-count
+    flag and pins ``jax_platforms`` to ``cpu``.
 
     Must be called before the first JAX backend initialization in the
     process — XLA reads ``--xla_force_host_platform_device_count`` once, at

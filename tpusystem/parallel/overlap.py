@@ -56,7 +56,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from tpusystem.parallel.collectives import ring_shift_chunked
-from tpusystem.parallel.mesh import DATA, FSDP, MODEL, SEQ, axis_size
+from tpusystem.parallel.mesh import DATA, FSDP, MODEL, SEQ
 
 
 class OverlapPlan(NamedTuple):
@@ -116,7 +116,7 @@ def _allgather_matmul_overlap(axis, chunks, x, w):
     """The decomposed ring: shard ``s`` of ``x`` rotates forward; its
     partial matmul lands in row-block ``s`` of the result while the next
     shard's ``ppermute`` is in flight."""
-    ring = axis_size(axis)
+    ring = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     rows = x.shape[0]
     out = jnp.zeros((ring * rows, w.shape[1]), _out_dtype(x, w))
@@ -143,7 +143,7 @@ def _matmul_reducescatter_overlap(axis, chunks, x, w):
     issued *before* the next partial's matmul, so after ``n`` steps
     block ``rank`` lands home having collected all ``n`` contributions
     with the transfers hidden under the matmuls."""
-    ring = axis_size(axis)
+    ring = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     rows = x.shape[0] // ring
     cols = x.shape[1]
@@ -165,7 +165,7 @@ def _ring_transpose_matmul(axis, chunks, rotating, sliced):
     (the gathered operand rotates against static row-blocks of the local
     cotangent/input). f32 accumulator, same overlap order as the forward
     rings."""
-    ring = axis_size(axis)
+    ring = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     rows = rotating.shape[0]
     held = rotating
@@ -247,7 +247,7 @@ def allgather_matmul(x, w, axis: str = MODEL, *, chunks: int = 1):
     ``lax.all_gather`` + matmul when ``axis_size == 1`` or ``chunks``
     cannot tile the shard (see :func:`allgather_plan`).
     """
-    plan = allgather_plan(x.shape[0], axis_size(axis), chunks)
+    plan = allgather_plan(x.shape[0], lax.axis_size(axis), chunks)
     if plan.path == 'one-shot':
         gathered = lax.all_gather(x, axis, axis=0, tiled=True)
         return _partial_matmul(gathered, w).astype(_out_dtype(x, w))
@@ -273,13 +273,13 @@ def matmul_reducescatter(x, w, axis: str = MODEL, *, chunks: int = 1):
     cannot tile the scatter block (:func:`reducescatter_plan`); rows not
     divisible by the ring raise (no scatter semantics exist).
     """
-    plan = reducescatter_plan(x.shape[0], axis_size(axis), chunks)
+    plan = reducescatter_plan(x.shape[0], lax.axis_size(axis), chunks)
     if plan.path == 'one-shot':
         # scatter the f32 partial products and cast AFTER: the fallback
         # must keep the module's f32-reduction contract, or a silently
         # non-tiling layer would sum its ring in bf16
         product = _partial_matmul(x, w)
-        if axis_size(axis) > 1:
+        if lax.axis_size(axis) > 1:
             product = lax.psum_scatter(product, axis, scatter_dimension=0,
                                        tiled=True)
         return product.astype(_out_dtype(x, w))

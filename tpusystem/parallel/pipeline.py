@@ -38,8 +38,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from tpusystem.parallel.mesh import (DATA, FSDP, MODEL, STAGE, axis_size,
-                                     shard_map)
+from tpusystem.parallel.mesh import DATA, FSDP, MODEL, STAGE
 from tpusystem.parallel.sharding import ShardingPolicy
 
 # One layer of the pipelined stack: (layer_params, activations) -> activations
@@ -203,14 +202,14 @@ def pipeline_apply(block_fn: BlockFn, stacked_params: Any, hidden: jax.Array,
     run_unit = _unit_runner(mesh)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(param_specs, activation_spec),
         out_specs=(activation_spec, P()) if has_aux else activation_spec,
         check_vma=False,
         axis_names=_manual_axes(mesh))
     def pipelined(params, local_hidden):
         stage = lax.axis_index(STAGE)
-        count = axis_size(STAGE)
+        count = lax.axis_size(STAGE)
         shape = (microbatches, local_hidden.shape[0] // microbatches)
         batches = local_hidden.reshape(shape + local_hidden.shape[1:])
         if chunks == 1:
@@ -533,7 +532,7 @@ def pipeline_train(head_fn, block_fn, tail_fn, mesh, *, microbatches: int,
             [chunk_spec] * param_structure.num_leaves)
 
         @functools.partial(
-            shard_map, mesh=mesh, check_vma=False,
+            jax.shard_map, mesh=mesh, check_vma=False,
             in_specs=(P(), param_specs, batch_spec, batch_spec),
             out_specs=(P(), (P(), param_specs)),
             axis_names=_manual_axes(mesh))

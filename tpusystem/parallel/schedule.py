@@ -97,7 +97,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from tpusystem.parallel.collectives import ring_allgather, ring_reducescatter
-from tpusystem.parallel.mesh import DATA, FSDP, MODEL, SEQ, shard_map
+from tpusystem.parallel.mesh import DATA, FSDP, MODEL, SEQ
 from tpusystem.parallel.overlap import (_out_dtype, _partial_matmul,
                                         _row_specs, allgather_matmul,
                                         matmul_reducescatter,
@@ -595,7 +595,7 @@ def scheduled_ffn(x, kernel_up, bias_up, kernel_down, bias_down, mesh, *,
         row_split=sizes.get(axis, 1))
 
     @functools.partial(
-        shard_map, mesh=mesh, check_vma=False,
+        jax.shard_map, mesh=mesh, check_vma=False,
         in_specs=(_row_specs(mesh, x.shape[0], axis), spec_up, P(tp_axis),
                   spec_down, P(None)),
         out_specs=_row_specs(mesh, x.shape[0], axis))
@@ -639,7 +639,7 @@ def scheduled_swiglu(x, kernel_gate, kernel_up, kernel_down, mesh, *,
         row_split=sizes.get(axis, 1))
 
     @functools.partial(
-        shard_map, mesh=mesh, check_vma=False,
+        jax.shard_map, mesh=mesh, check_vma=False,
         in_specs=(_row_specs(mesh, x.shape[0], axis), spec_gate, spec_up,
                   spec_down),
         out_specs=_row_specs(mesh, x.shape[0], axis))

@@ -49,7 +49,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from tpusystem.ops.pallas.embedding_lookup import embedding_lookup
-from tpusystem.parallel.mesh import DATA, FSDP, shard_map
+from tpusystem.parallel.mesh import DATA, FSDP
 from tpusystem.parallel.sharding import (TABLE_AXES, constrain_table_rows,
                                          table_row_spec)
 from tpusystem.registry import register
@@ -214,7 +214,7 @@ class ShardedEmbedding(nn.Module):
         in_specs = (P(table_axes, None), row_spec) + (
             (row_spec,) if weighted else ())
 
-        @functools.partial(shard_map, mesh=mesh, check_vma=False,
+        @functools.partial(jax.shard_map, mesh=mesh, check_vma=False,
                            in_specs=in_specs, out_specs=out_spec)
         def run(local_table, ids, *maybe_w):
             # shard index in table_row_spec's expert-major order

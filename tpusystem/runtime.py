@@ -54,7 +54,10 @@ answered in seconds.
 from __future__ import annotations
 
 import os
+import pathlib
 import signal as signal_module
+
+import jax
 
 from tpusystem.observe.ledger import EventLedger
 from tpusystem.parallel import multihost
@@ -63,6 +66,27 @@ from tpusystem.parallel.multihost import (
     World,
 )
 from tpusystem.parallel.recovery import Preempted
+
+
+CACHE_ENV = 'JAX_COMPILATION_CACHE_DIR'
+
+
+def compile_cache() -> str:
+    """Place JAX's persistent compile cache; entry points call this once
+    before their first compile (never ``import tpusystem``, never tests).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and
+    nothing is touched. Otherwise the cache is ``<checkout>/.jax_cache``
+    — a fixed path, because the path is part of the cache key — and the
+    variable is exported so supervised children land in the same cache.
+    Returns the directory in use."""
+    placed = os.environ.get(CACHE_ENV)
+    if placed:
+        return placed
+    path = str(pathlib.Path(__file__).resolve().parents[1] / '.jax_cache')
+    jax.config.update('jax_compilation_cache_dir', path)
+    os.environ[CACHE_ENV] = path
+    return path
 
 
 def _control_address(coordinator: str | None,
