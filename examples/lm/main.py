@@ -28,7 +28,7 @@ from tpusystem.depends import Provider
 from tpusystem.models import GPT2, gpt2_tiny
 from tpusystem.observe import checkpoint_consumer, logging_consumer, tracking
 from tpusystem.observe.events import Iterated, Trained, Validated
-from tpusystem.observe.profile import StepTimer
+from tpusystem.observe.profile import StepTimer, annotate, annotated
 from tpusystem.parallel import (FullyShardedDataParallel, MeshSpec,
                                 batch_sharding)
 from tpusystem.registry import gethash
@@ -256,28 +256,45 @@ def iterate(model, loaders, metrics) -> None:
         producer.dispatch(Iterated(model, loaders))
 
 
+def _epoch(layer: str, model, loader, metrics, dispatch: int, run) -> dict:
+    """One pass over ``loader``, ``dispatch`` batches a call of ``run``.
+    In a device trace each host stage is a ``tpusystem.<layer>.*`` span:
+    ``fetch`` (the next group off the loader), ``shard``, ``dispatch``,
+    ``update``, and ``compute`` — the pass's one host sync."""
+    groups = grouped_batches(loader, dispatch)
+    for (stack,) in annotated(f'tpusystem.{layer}.fetch', groups):
+        with annotate(f'tpusystem.{layer}.shard'):
+            placed = model.shard_batches(stack)
+        with annotate(f'tpusystem.{layer}.dispatch'):
+            losses = run(placed)
+        with annotate(f'tpusystem.{layer}.update'):
+            metrics.update(losses)
+    with annotate(f'tpusystem.{layer}.compute'):
+        return metrics.compute()
+
+
 @service.handler
 def train(model, loader, metrics,
           dispatch: int = Depends(steps_per_dispatch)) -> None:
-    model.phase = 'train'
-    timer = StepTimer(producer).start()
-    for (stack,) in grouped_batches(loader, dispatch):
-        metrics.update(model.fit_many(model.shard_batches(stack)))
-    results = metrics.compute()
-    timer.stop(model, 'train', steps=len(loader))
-    producer.dispatch(Trained(model, results))
+    with annotate('tpusystem.train.epoch'):
+        model.phase = 'train'
+        timer = StepTimer(producer).start()
+        results = _epoch('train', model, loader, metrics, dispatch,
+                         model.fit_many)
+        timer.stop(model, 'train', steps=len(loader))
+        producer.dispatch(Trained(model, results))
 
 
 @service.handler
 def validate(model, loader, metrics,
              dispatch: int = Depends(steps_per_dispatch)) -> None:
-    model.phase = 'evaluation'
-    timer = StepTimer(producer).start()
-    for (stack,) in grouped_batches(loader, dispatch):
-        metrics.update(model.evaluate_many(model.shard_batches(stack)))
-    results = metrics.compute()
-    timer.stop(model, 'evaluation', steps=len(loader))
-    producer.dispatch(Validated(model, results))
+    with annotate('tpusystem.eval.epoch'):
+        model.phase = 'evaluation'
+        timer = StepTimer(producer).start()
+        results = _epoch('eval', model, loader, metrics, dispatch,
+                         model.evaluate_many)
+        timer.stop(model, 'evaluation', steps=len(loader))
+        producer.dispatch(Validated(model, results))
 
 
 # --------------------------------------------------------------------------

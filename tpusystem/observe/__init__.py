@@ -35,7 +35,7 @@ from tpusystem.observe.metrics import (Histogram, ServeLatency,
 # shadowing function and fails — the price of keeping the old name).
 from tpusystem.observe.trace import Span, TraceContext, Tracer
 from tpusystem.observe.profile import (ProfilerBusy, StepTimer, annotate,
-                                       step_span, trace)
+                                       annotated, trace)
 from tpusystem.observe.tensorboard import SummaryWriter, tensorboard_consumer
 from tpusystem.observe.tracking import (
     checkpoint_consumer, experiment, metrics_store, models_store,
@@ -52,7 +52,7 @@ __all__ = [
     'tracking_consumer', 'checkpoint_consumer', 'experiment',
     'metrics_store', 'models_store',
     'modules_store', 'iterations_store', 'repository',
-    'EventLedger', 'LedgerDivergence', 'StepTimer', 'annotate', 'step_span',
+    'EventLedger', 'LedgerDivergence', 'StepTimer', 'annotate', 'annotated',
     'trace', 'ProfilerBusy',
     'Tracer', 'Span', 'TraceContext',
     'Histogram', 'ServeLatency', 'serve_metrics_consumer',

@@ -4,10 +4,14 @@ The reference has none — its nearest artifacts are TensorBoard scalars and
 per-100-batch loss logs (SURVEY.md §5 "tracing/profiling: absent"). Here
 profiling is a first-class citizen with two faces:
 
-- **device plane** — :func:`trace` / :func:`annotate` / :func:`step_span`
+- **device plane** — :func:`trace` / :func:`annotate` / :func:`annotated`
   wrap ``jax.profiler`` so XLA traces (HLO timelines, memory, TPU util)
   land in a TensorBoard-readable logdir. Annotations are zero-cost when no
-  trace is active, so they stay in production code.
+  trace is active, so they stay in production code: the program's own
+  ``tpusystem.<layer>.<what>`` host spans (docs/observability.md lists
+  them) are all opened through :func:`annotate`, and land on the
+  ``/host:CPU`` plane of the same ``.xplane.pb`` as the device's
+  operations — one clock by construction.
 - **bus plane** — :class:`StepTimer` measures host wall-clock around jitted
   step spans and emits :class:`~tpusystem.observe.events.StepTimed` events;
   any consumer (logging, storage, TensorBoard) observes throughput without
@@ -19,7 +23,7 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Any, Iterator
+from typing import Any, Iterable, Iterator
 
 import jax
 
@@ -51,22 +55,34 @@ def trace(logdir: str) -> Iterator[None]:
         raise ProfilerBusy(
             f'jax.profiler.start_trace({logdir!r}) refused: {error} — a '
             f'device trace is already active; stop it (or nest '
-            f'annotate()/step_span() instead, which compose)') from error
+            f'annotate() instead, which composes)') from error
     try:
         yield
     finally:
         jax.profiler.stop_trace()
 
 
-def annotate(name: str) -> Any:
-    """Named span on the host timeline of an active trace (no-op otherwise)."""
-    return jax.profiler.TraceAnnotation(name)
+def annotate(name: str, **stats: Any) -> Any:
+    """Named span on the host timeline of an active trace (no-op
+    otherwise). ``stats`` ride the span as keyword stats and come back
+    through ``jax.profiler.ProfileData`` (``event.stats``). The only place
+    the program opens a ``TraceAnnotation``; the prefix ``tpusystem.`` is
+    reserved for the spans docs/observability.md lists."""
+    return jax.profiler.TraceAnnotation(name, **stats)
 
 
-def step_span(name: str, step: int) -> Any:
-    """Step-correlated span: lets the profiler group device ops per training
-    step (``jax.profiler.StepTraceAnnotation``)."""
-    return jax.profiler.StepTraceAnnotation(name, step_num=step)
+def annotated(name: str, iterable: Iterable) -> Iterator:
+    """``iterable``, with every fetch of its next item inside
+    ``annotate(name)`` — the span a ``for`` loop cannot put around its own
+    ``next()`` (a loader's batch assembly, ``grouped_batches``' stack)."""
+    iterator = iter(iterable)
+    while True:
+        with annotate(name):
+            try:
+                item = next(iterator)
+            except StopIteration:
+                return
+        yield item
 
 
 class StepTimer:

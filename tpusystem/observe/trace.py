@@ -53,6 +53,8 @@ import threading
 import time
 from typing import Any, Callable, Iterator
 
+from tpusystem.observe.profile import annotate
+
 __all__ = ['TraceContext', 'Span', 'Tracer', 'connected_traces']
 
 
@@ -168,10 +170,15 @@ class Tracer:
     def span(self, name: str, *, cat: str = 'span',
              trace: TraceContext | None = None,
              args: dict | None = None) -> Iterator[Span]:
-        """Lexical span: ``with tracer.span('checkpoint-save'): ...``."""
+        """Lexical span: ``with tracer.span('checkpoint-save'): ...``.
+        Also a ``tpusystem.<name>`` host span of a running device trace
+        (:func:`tpusystem.observe.profile.annotate`; nothing otherwise), so
+        checkpoint and recovery spans show up beside the device's
+        operations with no further code."""
         opened = self.begin(name, cat=cat, trace=trace, args=args)
         try:
-            yield opened
+            with annotate('tpusystem.' + name):
+                yield opened
         finally:
             self.end(opened)
 

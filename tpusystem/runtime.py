@@ -79,7 +79,14 @@ def compile_cache() -> str:
     nothing is touched. Otherwise the cache is ``<checkout>/.jax_cache``
     — a fixed path, because the path is part of the cache key — and the
     variable is exported so supervised children land in the same cache.
-    Returns the directory in use."""
+    Returns the directory in use.
+
+    Either way the key covers each operation's metadata (its
+    ``jax.named_scope`` path, file and line), which JAX leaves out by
+    default: a device trace is read by those names, and an executable
+    loaded under a key without them carries the names of whoever
+    compiled it first — another checkout's, in a cache two share."""
+    jax.config.update('jax_compilation_cache_include_metadata_in_key', True)
     placed = os.environ.get(CACHE_ENV)
     if placed:
         return placed

@@ -78,6 +78,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpusystem.observe.profile import annotate
 from tpusystem.parallel.mesh import on_tpu
 from tpusystem.serve.kvcache import (PagedKVCache, _is_kv, adopt_prefill,
                                      pool_shardings, write_tables)
@@ -556,8 +557,9 @@ class Engine:
                         topk, topp, mask):
                 self.trace_count += 1        # runs at trace time only
                 logits, updated = fused(params, cache, tokens)
-                token = sample_rows(logits, seed, pos, temp, topk, topp,
-                                    mask)
+                with jax.named_scope('select'):
+                    token = sample_rows(logits, seed, pos, temp, topk, topp,
+                                        mask)
                 cursor = read_cursor(cache)
                 return (token,
                         rewind(updated, jnp.where(active, cursor + 1, 0)),
@@ -570,8 +572,9 @@ class Engine:
                     {'params': _dequant(params, self._decoder),
                      'cache': cache},
                     tokens[:, None], mutable=['cache'])
-                token = sample_rows(logits[:, -1], seed, pos, temp, topk,
-                                    topp, mask)
+                with jax.named_scope('select'):
+                    token = sample_rows(logits[:, -1], seed, pos, temp, topk,
+                                        topp, mask)
                 # park retired rows' cursors at 0 so their dead writes
                 # stay in the trash block's first slots instead of
                 # walking off the table; active rows keep the cursor
@@ -988,6 +991,15 @@ class Engine:
                 self._mask_dev = self._mask_dev.at[row].set(mask)
         return Admission(rep, first, False)
 
+    def _adopt(self, prefill_cache, rows: list[int], length: int) -> None:
+        """Scatter a contiguous prefill strip into every row's blocks and
+        publish the edited block tables."""
+        for row in rows:
+            self._cache = adopt_prefill(
+                self._cache, prefill_cache,
+                jnp.asarray(self.pool.adoption_slots(row)), row, length)
+        self._cache = write_tables(self._cache, self.pool.table)
+
     def admit(self, prompt, max_new: int, *, stop_token: int | None = None,
               tag=None, sampling=None, emitted=()) -> Admission:
         """Prefill ``prompt`` and seat it in a free row (a free GROUP of
@@ -1006,29 +1018,28 @@ class Engine:
         ops = self._sampling_ops(sampling, emitted)
         rep, rows = self._seat(prompt, max_new)
 
+        # the tpusystem.engine.* spans sit on the brackets `timings`
+        # times, so a device trace and the accumulators agree
         started = time.perf_counter()
-        first, prefill_cache = self._prefill_rows(prompt, rows, ops)
-        first = int(first)
+        with annotate('tpusystem.engine.prefill'):
+            first, prefill_cache = self._prefill_rows(prompt, rows, ops)
+            first = int(first)
         self.timings['prefill'] += time.perf_counter() - started
 
         started = time.perf_counter()
-        for row in rows:
-            self._cache = adopt_prefill(
-                self._cache, prefill_cache,
-                jnp.asarray(self.pool.adoption_slots(row)), row,
-                prompt.size)
-        self._cache = write_tables(self._cache, self.pool.table)
-        if self._spec:
-            dbucket = prefill_bucket(prompt.size, self.block_size,
-                                     self._drafter.max_seq)
-            padded = np.zeros((1, dbucket), np.int32)
-            padded[0, :prompt.size] = prompt
-            _, draft_cache = self._run_prefill(self._draft_prefiller,
-                                               dbucket, padded,
-                                               prompt.size)
-            self._dcache = _adopt_draft_rows(self._dcache, draft_cache,
-                                             jnp.asarray(rows, jnp.int32),
-                                             prompt.size)
+        with annotate('tpusystem.engine.adopt'):
+            self._adopt(prefill_cache, rows, prompt.size)
+            if self._spec:
+                dbucket = prefill_bucket(prompt.size, self.block_size,
+                                         self._drafter.max_seq)
+                padded = np.zeros((1, dbucket), np.int32)
+                padded[0, :prompt.size] = prompt
+                _, draft_cache = self._run_prefill(self._draft_prefiller,
+                                                   dbucket, padded,
+                                                   prompt.size)
+                self._dcache = _adopt_draft_rows(
+                    self._dcache, draft_cache, jnp.asarray(rows, jnp.int32),
+                    prompt.size)
         self.timings['admit'] += time.perf_counter() - started
         return self._register(rep, rows, prompt, first, max_new,
                               stop_token, tag, sampling, emitted)
@@ -1060,9 +1071,10 @@ class Engine:
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :prompt.size] = prompt
         started = time.perf_counter()
-        first, prefill_cache = self._run_prefill(self._prefiller, bucket,
-                                                 padded, prompt.size, ops)
-        first = int(first)
+        with annotate('tpusystem.engine.prefill'):
+            first, prefill_cache = self._run_prefill(
+                self._prefiller, bucket, padded, prompt.size, ops)
+            first = int(first)
         self.timings['prefill'] += time.perf_counter() - started
         kv = {jax.tree_util.keystr(path): np.asarray(leaf)
               for path, leaf
@@ -1117,12 +1129,8 @@ class Engine:
         rep, rows = self._seat(prompt, max_new)
 
         started = time.perf_counter()
-        for row in rows:
-            self._cache = adopt_prefill(
-                self._cache, prefill_cache,
-                jnp.asarray(self.pool.adoption_slots(row)), row,
-                prompt.size)
-        self._cache = write_tables(self._cache, self.pool.table)
+        with annotate('tpusystem.engine.adopt'):
+            self._adopt(prefill_cache, rows, prompt.size)
         self.timings['admit'] += time.perf_counter() - started
         return self._register(rep, rows, prompt, int(first), max_new,
                               stop_token, tag, sampling, emitted)
@@ -1154,35 +1162,40 @@ class Engine:
         if self._spec:
             return self._spec_tick()
         started = time.perf_counter()
-        token_dev, self._cache, self._pos_dev = self._step(
-            self._params, self._cache, self._tokens_dev, self._active_dev,
-            self._seed_dev, self._pos_dev, self._temp_dev, self._topk_dev,
-            self._topp_dev, self._mask_dev)
-        token = np.asarray(token_dev)
+        with annotate('tpusystem.engine.dispatch'):
+            token_dev, self._cache, self._pos_dev = self._step(
+                self._params, self._cache, self._tokens_dev,
+                self._active_dev, self._seed_dev, self._pos_dev,
+                self._temp_dev, self._topk_dev, self._topp_dev,
+                self._mask_dev)
+        with annotate('tpusystem.engine.read'):
+            token = np.asarray(token_dev)
         # retired rows' stale device token stays as-is (in-vocab junk an
         # inactive row may keep embedding — masked, never emitted)
         self._tokens_dev = token_dev
         self.last_step_seconds = time.perf_counter() - started
         self.timings['step'] += self.last_step_seconds
         emitted, finished = {}, []
-        for row in np.flatnonzero(self._active):
-            row = int(row)
-            self._tokens[row] = int(token[row])
-            emitted[row] = [int(token[row])]
-            state = self._rowstate[row]
-            state.tokens.append(int(token[row]))
-            reason = self._finish_reason(row)
-            if reason is not None:
-                state = self.evict(row)
-                finished.append((row, reason, list(state.tokens)))
-            elif (state.sampling is not None
-                  and state.sampling.mask_fn is not None):
-                # the grammar hook: re-evaluate the mask over the full
-                # stream so the NEXT position sees it — a host-side
-                # fixed-shape row write, never a retrace
-                mask = self._grammar_mask(
-                    state.sampling, list(state.prior) + list(state.tokens))
-                self._mask_dev = self._mask_dev.at[row].set(mask)
+        with annotate('tpusystem.engine.rows'):
+            for row in np.flatnonzero(self._active):
+                row = int(row)
+                self._tokens[row] = int(token[row])
+                emitted[row] = [int(token[row])]
+                state = self._rowstate[row]
+                state.tokens.append(int(token[row]))
+                reason = self._finish_reason(row)
+                if reason is not None:
+                    state = self.evict(row)
+                    finished.append((row, reason, list(state.tokens)))
+                elif (state.sampling is not None
+                      and state.sampling.mask_fn is not None):
+                    # the grammar hook: re-evaluate the mask over the full
+                    # stream so the NEXT position sees it — a host-side
+                    # fixed-shape row write, never a retrace
+                    mask = self._grammar_mask(
+                        state.sampling,
+                        list(state.prior) + list(state.tokens))
+                    self._mask_dev = self._mask_dev.at[row].set(mask)
         return StepReport(emitted, finished)
 
     def lowered_step(self) -> str:
@@ -1202,38 +1215,42 @@ class Engine:
 
     def _spec_tick(self) -> StepReport:
         started = time.perf_counter()
-        emitted_dev, accepted_dev, self._tokens_dev, self._cache, \
-            self._dcache, self._pos_dev = self._spec_step(
-                self._params, self._dparams, self._cache, self._dcache,
-                self._tokens_dev, self._active_dev, self._seed_dev,
-                self._pos_dev, self._temp_dev, self._topk_dev,
-                self._topp_dev, self._mask_dev)
-        window = np.asarray(emitted_dev)             # [groups, K+1]
-        accepted = np.asarray(accepted_dev)
+        with annotate('tpusystem.engine.dispatch'):
+            emitted_dev, accepted_dev, self._tokens_dev, self._cache, \
+                self._dcache, self._pos_dev = self._spec_step(
+                    self._params, self._dparams, self._cache, self._dcache,
+                    self._tokens_dev, self._active_dev, self._seed_dev,
+                    self._pos_dev, self._temp_dev, self._topk_dev,
+                    self._topp_dev, self._mask_dev)
+        with annotate('tpusystem.engine.read'):
+            window = np.asarray(emitted_dev)             # [groups, K+1]
+            accepted = np.asarray(accepted_dev)
         self.last_step_seconds = time.perf_counter() - started
         self.timings['step'] += self.last_step_seconds
         fanout = self.tree_fanout
         emitted, finished = {}, []
-        for rep in sorted(self._rowstate):
-            if not self._active[rep]:
-                continue
-            state = self._rowstate[rep]
-            group = rep // fanout
-            count = int(accepted[group]) + 1
-            toks = [int(t) for t in window[group, :count]]
-            # host truncation happens only at a finish (budget or stop),
-            # so the device cursors' extra advance dies with the evict
-            toks = toks[:state.max_new - len(state.tokens)]
-            if state.stop is not None and state.stop in toks:
-                toks = toks[:toks.index(state.stop) + 1]
-            state.tokens.extend(toks)
-            for row in range(rep, rep + fanout):
-                self._tokens[row] = toks[-1]
-            emitted[rep] = toks
-            reason = self._finish_reason(rep)
-            if reason is not None:
-                state = self.evict(rep)
-                finished.append((rep, reason, list(state.tokens)))
+        with annotate('tpusystem.engine.rows'):
+            for rep in sorted(self._rowstate):
+                if not self._active[rep]:
+                    continue
+                state = self._rowstate[rep]
+                group = rep // fanout
+                count = int(accepted[group]) + 1
+                toks = [int(t) for t in window[group, :count]]
+                # host truncation happens only at a finish (budget or
+                # stop), so the device cursors' extra advance dies with
+                # the evict
+                toks = toks[:state.max_new - len(state.tokens)]
+                if state.stop is not None and state.stop in toks:
+                    toks = toks[:toks.index(state.stop) + 1]
+                state.tokens.extend(toks)
+                for row in range(rep, rep + fanout):
+                    self._tokens[row] = toks[-1]
+                emitted[rep] = toks
+                reason = self._finish_reason(rep)
+                if reason is not None:
+                    state = self.evict(rep)
+                    finished.append((rep, reason, list(state.tokens)))
         return StepReport(emitted, finished)
 
     # ------------------------------------------------------------- eviction

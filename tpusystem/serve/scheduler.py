@@ -35,6 +35,7 @@ import time
 from collections import deque
 from typing import Callable
 
+from tpusystem.observe.profile import annotate
 from tpusystem.parallel.mesh import on_tpu
 from tpusystem.serve.engine import Engine, SamplingParams  # noqa: F401
 from tpusystem.serve.failover import RequestJournal, Watermarks  # noqa: F401
@@ -196,6 +197,7 @@ class Scheduler:
         self.results: dict[str, Completion] = {}
         self.steps = 0
         self._trace_open: dict[str, object] = {}    # request id -> Span
+        self._trace_admitting: dict[str, object] = {}   # open 'admit' spans
         self._trace_roots: dict[str, object] = {}   # roots THIS end owns
 
     @property
@@ -359,7 +361,19 @@ class Scheduler:
         self._trace_open[request.id] = self.tracer.begin(
             'queued', cat='serve', trace=request.trace, args=args)
 
+    def _trace_admit(self, request: Request, prompt_tokens: int,
+                     bucket: int) -> None:
+        """Open the request's 'admit' span as it leaves the queue: the
+        prefill and the seating, which 'queued' (submit -> seated) holds
+        too — the wait in the queue alone is ``queued - admit``. Closed
+        where 'queued' closes."""
+        self._trace_admitting[request.id] = self.tracer.begin(
+            'admit', cat='serve', trace=request.trace,
+            args={'request': request.id, 'prompt_tokens': prompt_tokens,
+                  'bucket': bucket})
+
     def _trace_seated(self, request: Request, row: int) -> None:
+        self.tracer.end(self._trace_admitting.pop(request.id, None), row=row)
         self.tracer.end(self._trace_open.pop(request.id, None))
         self._trace_open[request.id] = self.tracer.begin(
             'decode', cat='serve', trace=request.trace,
@@ -369,6 +383,7 @@ class Scheduler:
         """Close 'queued', open 'handoff' — ended by :meth:`shipped`'s
         ack. Parented into ``request.trace`` like every serve span, so
         the decode replica's spans and these share one trace."""
+        self.tracer.end(self._trace_admitting.pop(request.id, None))
         self.tracer.end(self._trace_open.pop(request.id, None))
         self._trace_open[request.id] = self.tracer.begin(
             'handoff', cat='serve', trace=request.trace,
@@ -506,6 +521,35 @@ class Scheduler:
         expired = self._expire()
         depth_at_shed = len(self._queue)
         shed = self._shed()
+        with annotate('tpusystem.scheduler.admit'):
+            admitted, completed = self._admit()
+
+        report = self.engine.step()
+        emitted = {}
+        for row, tokens in report.emitted.items():
+            if row in self._seated:
+                request_id = self._seated[row].request.id
+                emitted[request_id] = list(tokens)
+                if self.journal is not None:
+                    for token in tokens:
+                        self.journal.append(request_id, token)
+        for row, reason, tokens in report.finished:
+            # rows admitted directly on the engine (not through this
+            # scheduler) retire without a seat here — their caller got
+            # the tokens via the engine's StepReport
+            pending = self._seated.pop(row, None)
+            if pending is not None:
+                completed.append(self._complete(pending, list(tokens),
+                                                reason))
+        if self.journal is not None:
+            self.journal.observe_tick()
+        return Tick(admitted, emitted, completed, len(self._queue),
+                    len(self._seated), expired, shed,
+                    depth_at_shed if shed else None)
+
+    def _admit(self) -> tuple[list, list]:
+        """Seat (or, prefill-only, export) queued requests FIFO within the
+        prefill budget; returns ``(admitted, completed)`` for the tick."""
         admitted, completed = [], []
         budget = self.prefill_budget
         while self._queue:
@@ -524,6 +568,8 @@ class Scheduler:
                 break                    # budget spent this step
             if self.prefill_only:
                 self._queue.popleft()
+                if self.tracer is not None:
+                    self._trace_admit(request, len(prompt), cost)
                 first, kv = self.engine.export_prefill(
                     prompt, sampling=getattr(request, 'sampling', None),
                     emitted=pending.prefix)
@@ -540,6 +586,8 @@ class Scheduler:
                                          prompt=prompt):
                 break                    # FIFO: wait for rows/blocks
             self._queue.popleft()
+            if self.tracer is not None:
+                self._trace_admit(request, len(prompt), cost)
             sampling = getattr(request, 'sampling', None)
             if pending.handoff is not None:
                 handoff, pending.handoff = pending.handoff, None
@@ -564,29 +612,7 @@ class Scheduler:
                     pending, [admission.token], admission.reason))
             else:
                 self._seated[admission.row] = pending
-
-        report = self.engine.step()
-        emitted = {}
-        for row, tokens in report.emitted.items():
-            if row in self._seated:
-                request_id = self._seated[row].request.id
-                emitted[request_id] = list(tokens)
-                if self.journal is not None:
-                    for token in tokens:
-                        self.journal.append(request_id, token)
-        for row, reason, tokens in report.finished:
-            # rows admitted directly on the engine (not through this
-            # scheduler) retire without a seat here — their caller got
-            # the tokens via the engine's StepReport
-            pending = self._seated.pop(row, None)
-            if pending is not None:
-                completed.append(self._complete(pending, list(tokens),
-                                                reason))
-        if self.journal is not None:
-            self.journal.observe_tick()
-        return Tick(admitted, emitted, completed, len(self._queue),
-                    len(self._seated), expired, shed,
-                    depth_at_shed if shed else None)
+        return admitted, completed
 
     def _complete(self, pending: _Pending, tokens: list,
                   reason: str) -> Completion:

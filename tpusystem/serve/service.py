@@ -34,6 +34,7 @@ from tpusystem.observe.events import (Backpressure, LoadShed,
                                       RequestAdmitted, RequestCompleted,
                                       RequestEvicted, RequestExpired,
                                       ServeStepped, TokenStreamed)
+from tpusystem.observe.profile import annotate
 from tpusystem.serve.engine import Engine
 from tpusystem.serve.fleet import RouterFenced
 from tpusystem.serve.scheduler import Request, Scheduler, serve_levers
@@ -138,10 +139,24 @@ class InferenceService:
     # ------------------------------------------------------------- serving
 
     def step(self) -> None:
-        """One scheduler iteration, narrated on the bus."""
-        if self._started is None:
-            self._started = self._clock()
-        tick = self.scheduler.step()
+        """One scheduler iteration, narrated on the bus. In a device trace
+        the whole of it is one ``tpusystem.serve.tick`` span whose stats
+        are the scheduler step it runs (``ServeStepped.step``) and this
+        service's ``clock`` at its start: the anchor that places
+        :class:`~tpusystem.observe.Tracer` spans, and any other record on
+        that clock, on the trace's own. Time of a tick that no inner
+        ``tpusystem.*`` span covers is the scheduler's bookkeeping."""
+        with annotate('tpusystem.serve.tick', step=self.scheduler.steps + 1,
+                      clock=self._clock()):
+            if self._started is None:
+                self._started = self._clock()
+            tick = self.scheduler.step()
+            with annotate('tpusystem.service.narrate'):
+                self._narrate(tick)
+
+    def _narrate(self, tick) -> None:
+        """Everything after the scheduler's step: streams fed and closed,
+        the tick's lifecycle events on the bus."""
         # shed/backpressure narrate the depth that TRIGGERED them
         # (tick.shed_depth, pre-shed) — the final queue_depth is
         # post-admission and would under-report the overload

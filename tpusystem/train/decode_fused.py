@@ -169,20 +169,23 @@ def _paged_attention_fused(query, key_pool, value_pool, table, cursor,
 
     def attend_over(width: int):
         def run():
-            mapped = jax.lax.slice_in_dim(table, 0, width, axis=1)
-            tokens = (mapped[:, :, None] * block
-                      + jnp.arange(block)[None, None, :]
-                      ).reshape(batch, width * block)
-            keys = jnp.take(key_pool, tokens, axis=0)    # [B, W*blk, H, hd]
-            values = jnp.take(value_pool, tokens, axis=0)
-            scores = jnp.einsum('bhd,bkhd->bhk', query, keys,
-                                preferred_element_type=jnp.float32) * scale
-            mask = (jnp.arange(width * block)[None, None, :]
-                    <= cursor[:, None, None])
-            scores = jnp.where(mask, scores, NEG_INF)
-            weights = jax.nn.softmax(scores, axis=-1)
-            return jnp.einsum('bhk,bkhd->bhd', weights.astype(compute),
-                              values)
+            with jax.named_scope('kv_read'):
+                mapped = jax.lax.slice_in_dim(table, 0, width, axis=1)
+                tokens = (mapped[:, :, None] * block
+                          + jnp.arange(block)[None, None, :]
+                          ).reshape(batch, width * block)
+                keys = jnp.take(key_pool, tokens, axis=0)  # [B, W*blk, H, hd]
+                values = jnp.take(value_pool, tokens, axis=0)
+            with jax.named_scope('attention'):
+                scores = jnp.einsum(
+                    'bhd,bkhd->bhk', query, keys,
+                    preferred_element_type=jnp.float32) * scale
+                mask = (jnp.arange(width * block)[None, None, :]
+                        <= cursor[:, None, None])
+                scores = jnp.where(mask, scores, NEG_INF)
+                weights = jax.nn.softmax(scores, axis=-1)
+                return jnp.einsum('bhk,bkhd->bhd', weights.astype(compute),
+                                  values)
         return run
 
     buckets = [min(max_blocks, max(1, 64 // block))]
@@ -208,7 +211,13 @@ def build_fused_paged_step(decoder):
     model-level ``position``); cursor leaves in the returned cache are
     the input's — the engine's post-step ``rewind`` owns advancement.
     Token-exact vs the flax paged step in window-length-invariant
-    arithmetic (the contiguous fused loop's contract)."""
+    arithmetic (the contiguous fused loop's contract).
+
+    The XLA work between the kernels carries ``jax.named_scope`` names a
+    device trace is read by (``embed``, ``ln``, ``kv_write``, ``kv_read``,
+    ``attention``, ``head``). No scope encloses ``decode_matmul`` or
+    ``decode_ffn``: the TPU compiler names a Mosaic call after its
+    innermost scope, and the kernels' trace name is the jitted step's."""
     reason = fused_paged_reason(decoder)
     if reason is not None:
         raise ValueError(f'fused paged step unsupported: {reason}')
@@ -224,10 +233,11 @@ def build_fused_paged_step(decoder):
         cursor = cache['h_0']['attn']['index']               # [rows]
         wte = params['wte']['embedding']
         wpe = params['wpe']['embedding']
-        embedded = (jnp.asarray(wte)[tokens].astype(jnp.float32)
-                    + jnp.asarray(wpe)[cache['position']].astype(
-                        jnp.float32))
-        hidden = embedded.astype(compute)
+        with jax.named_scope('embed'):
+            embedded = (jnp.asarray(wte)[tokens].astype(jnp.float32)
+                        + jnp.asarray(wpe)[cache['position']].astype(
+                            jnp.float32))
+            hidden = embedded.astype(compute)
         # physical token slot of this step's position through each row's
         # table — past-capacity clamps onto the last (trash) column,
         # exactly paged_attention's write discipline
@@ -235,8 +245,9 @@ def build_fused_paged_step(decoder):
         pools = {}                       # ('h_i', 'key'|'value') -> pool
         for index in range(layers):
             layer = params[f'h_{index}']
-            normed = _layernorm(hidden, layer['ln_1']['scale'],
-                                layer['ln_1']['bias']).astype(compute)
+            with jax.named_scope('ln'):
+                normed = _layernorm(hidden, layer['ln_1']['scale'],
+                                    layer['ln_1']['bias']).astype(compute)
             attn = layer['attn']
             qkv = decode_matmul(normed, attn['qkv']['kernel'],
                                 attn['qkv']['bias'])
@@ -245,13 +256,14 @@ def build_fused_paged_step(decoder):
             query = query.reshape(shape)
             entry = cache[f'h_{index}']['attn']
             table = entry['table']
-            physical = jnp.take_along_axis(table, logical[:, None],
-                                           axis=1)[:, 0]
-            slots = physical * block + cursor % block        # [rows]
-            key_pool = entry['key'].at[slots].set(
-                key.reshape(shape).astype(entry['key'].dtype))
-            value_pool = entry['value'].at[slots].set(
-                value.reshape(shape).astype(entry['value'].dtype))
+            with jax.named_scope('kv_write'):
+                physical = jnp.take_along_axis(table, logical[:, None],
+                                               axis=1)[:, 0]
+                slots = physical * block + cursor % block    # [rows]
+                key_pool = entry['key'].at[slots].set(
+                    key.reshape(shape).astype(entry['key'].dtype))
+                value_pool = entry['value'].at[slots].set(
+                    value.reshape(shape).astype(entry['value'].dtype))
             pools[(f'h_{index}', 'key')] = key_pool
             pools[(f'h_{index}', 'value')] = value_pool
             context = _paged_attention_fused(query, key_pool, value_pool,
@@ -260,16 +272,19 @@ def build_fused_paged_step(decoder):
                                      attn['out']['kernel'],
                                      attn['out']['bias'])
             hidden = hidden + attended
-            normed = _layernorm(hidden, layer['ln_2']['scale'],
-                                layer['ln_2']['bias']).astype(compute)
+            with jax.named_scope('ln'):
+                normed = _layernorm(hidden, layer['ln_2']['scale'],
+                                    layer['ln_2']['bias']).astype(compute)
             hidden = hidden + decode_ffn(
                 normed, layer['fc']['kernel'], layer['fc']['bias'],
                 layer['proj']['kernel'], layer['proj']['bias'],
                 activation=jax.nn.gelu)
-        final = _layernorm(hidden, params['ln_f']['scale'],
-                           params['ln_f']['bias'])
-        table = jnp.asarray(wte).astype(compute)
-        logits = head_logits(final.astype(compute), table, tied=True)
+        with jax.named_scope('ln'):
+            final = _layernorm(hidden, params['ln_f']['scale'],
+                               params['ln_f']['bias'])
+        with jax.named_scope('head'):
+            table = jnp.asarray(wte).astype(compute)
+            logits = head_logits(final.astype(compute), table, tied=True)
 
         def fix(path, leaf):
             if path[-1] in (jax.tree_util.DictKey('key'),

@@ -114,6 +114,10 @@ class ChunkedNextTokenLoss:
         self.tied = tied
 
     def __call__(self, outputs, tokens):
+        with jax.named_scope('loss_head'):
+            return self._chunked(outputs, tokens)
+
+    def _chunked(self, outputs, tokens):
         from tpusystem.ops.precision import head_logits
 
         features, table = outputs

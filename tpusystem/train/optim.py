@@ -52,6 +52,16 @@ def masked_update(transform, grads, opt_state, params, ok, *, scale=None):
             jax.tree.map(keep, new_opt_state, opt_state))
 
 
+def _scoped(name: str, inner: optax.GradientTransformation
+            ) -> optax.GradientTransformation:
+    """``inner`` with its update under ``jax.named_scope(name)``: the same
+    state and arithmetic, named in a device trace."""
+    def update(updates, state, params=None):
+        with jax.named_scope(name):
+            return inner.update(updates, state, params)
+    return optax.GradientTransformation(inner.init, update)
+
+
 class Optimizer:
     """Base: a named, hashable recipe producing an optax transform."""
 
@@ -114,7 +124,8 @@ class AdamW(Optimizer):
     def transform(self) -> optax.GradientTransformation:
         chain = []
         if self.grad_clip:
-            chain.append(optax.clip_by_global_norm(self.grad_clip))
+            chain.append(_scoped('clip',
+                                 optax.clip_by_global_norm(self.grad_clip)))
         chain.append(optax.adamw(self.schedule(), b1=self.b1, b2=self.b2,
                                  eps=self.eps, weight_decay=self.weight_decay))
         return optax.chain(*chain)

@@ -102,9 +102,16 @@ def build_train_step(apply_fn: ApplyFn, criterion: Criterion, optimizer,
     """
     transform = optimizer.transform() if hasattr(optimizer, 'transform') else optimizer
 
+    # jax.named_scope names a device trace is read by: `model`, `loss`,
+    # `optimizer` (the chunked LM head adds `loss_head`, the clipping norm
+    # `clip`). flax's own module scopes stay innermost inside `model`, so a
+    # Pallas kernel keeps the trace name its module gives it (`attn`)
     def objective(params, inputs, targets, dropout_rng):
-        outputs = apply_fn(params, inputs, dropout_rng, True)
-        return criterion(outputs, targets), outputs
+        with jax.named_scope('model'):
+            outputs = apply_fn(params, inputs, dropout_rng, True)
+        with jax.named_scope('loss'):
+            loss = criterion(outputs, targets)
+        return loss, outputs
 
     def step(state: TrainState, inputs, targets):
         state, dropout_rng = state.next_rng()
@@ -165,14 +172,17 @@ def build_train_step(apply_fn: ApplyFn, criterion: Criterion, optimizer,
                 'guard= needs health stats on the TrainState: arm it with '
                 'Guard.arm(state) before the first step')
             health, ok = guard.judge(state.health, loss, grads)
-            params, opt_state = masked_update(
-                transform, grads, state.opt_state, state.params, ok,
-                scale=health.lr_scale)
+            with jax.named_scope('optimizer'):
+                params, opt_state = masked_update(
+                    transform, grads, state.opt_state, state.params, ok,
+                    scale=health.lr_scale)
             state = state.replace(params=params, opt_state=opt_state,
                                   step=current, health=health)
             return state, (outputs, loss)
-        updates, opt_state = transform.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope('optimizer'):
+            updates, opt_state = transform.update(grads, state.opt_state,
+                                                  state.params)
+            params = optax.apply_updates(state.params, updates)
         state = state.replace(params=params, opt_state=opt_state, step=current)
         return state, (outputs, loss)
 
@@ -283,8 +293,10 @@ def build_eval_step(apply_fn: ApplyFn, criterion: Criterion, *, jit: bool = True
     deterministic forward) — the ``inference_mode`` analogue."""
 
     def step(state: TrainState, inputs, targets):
-        outputs = apply_fn(state.params, inputs, None, False)
-        return outputs, criterion(outputs, targets)
+        with jax.named_scope('model'):
+            outputs = apply_fn(state.params, inputs, None, False)
+        with jax.named_scope('loss'):
+            return outputs, criterion(outputs, targets)
 
     return jax.jit(step) if jit else step
 
