@@ -29,6 +29,7 @@ import numpy as np
 HERE = pathlib.Path(__file__).resolve().parent
 OUT = HERE / '.chip_smoke'          # the smoke's own output directory
 MOSAIC = 'tpu_custom_call'          # how a compiled Pallas kernel lowers
+PAGED_ATTENTION = 'kernel_name = "paged_decode_attention"'
 
 # the tolerances tests/test_attention.py and tests/test_decode_fused.py
 # already hold the same kernels to, by compute dtype
@@ -227,12 +228,14 @@ def serve(module) -> dict:
                 f'request {index} did not repeat')
     require(engine.trace_count == 1,
             f'the decode step traced {engine.trace_count} times')
+    lowered = engine.lowered_step()
     return {'requests': len(prompts),
             'prompt_lengths': [len(prompt) for prompt in prompts],
             'tokens': sum(BUDGETS), 'trace_count': engine.trace_count,
             'decode_impl': engine.decode_impl,
             'stream_dtype': engine.stream_dtype,
-            'mosaic_calls': engine.lowered_step().count(MOSAIC),
+            'mosaic_calls': lowered.count(MOSAIC),
+            'paged_attention_calls': lowered.count(PAGED_ATTENTION),
             'first_pass_seconds_with_compile': round(cold_seconds, 2),
             'second_pass_seconds': round(warm_seconds, 2)}
 
@@ -289,8 +292,11 @@ def main() -> None:
     report['serve'] = serve(network)
     require((report['serve']['decode_impl'], report['serve']['stream_dtype'])
             == ('fused', 'int8'), report['serve'])
-    require(report['serve']['mosaic_calls'] >= 3 * network.layers,
+    require(report['serve']['mosaic_calls'] >= 4 * network.layers,
             'the decode step took an einsum path')
+    require(report['serve']['paged_attention_calls'] == network.layers,
+            'the decode step does not read the pool through the '
+            'paged-attention kernel')
     print('serve', json.dumps(report['serve']), flush=True)
 
     report['trivial_dispatch_seconds'] = round(dispatch_seconds(), 6)

@@ -1,0 +1,273 @@
+"""The paged decode-attention kernel (interpret mode on the CPU) against a
+float32 ``jax.numpy`` reference written here: seeded pools, ragged depths,
+shared blocks, parked rows, and the in-place write of the step around it."""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpusystem.models import gpt2_tiny
+from tpusystem.models.gpt2 import GPT2
+from tpusystem.ops.pallas.paged_attention import (CHUNK_POSITIONS,
+                                                  paged_decode_attention,
+                                                  paged_plan)
+from tpusystem.train.decode_fused import fused_paged_reason
+
+HEAD_DIM = 64
+
+
+def reference(query, key_pool, value_pool, table, cursor, block: int):
+    """Each row attends positions ``0 … cursor`` of its own blocks: plain
+    float32 softmax attention, one row and one head at a time."""
+    rows, heads, head_dim = query.shape
+    keys = np.asarray(key_pool, np.float32).reshape(-1, heads, head_dim)
+    values = np.asarray(value_pool, np.float32).reshape(-1, heads, head_dim)
+    out = np.zeros((rows, heads, head_dim), np.float32)
+    for row in range(rows):
+        held = np.arange(int(cursor[row]) + 1)
+        slots = np.asarray(table)[row, held // block] * block + held % block
+        scores = np.einsum('hd,thd->ht', np.asarray(query[row], np.float32),
+                           keys[slots]) * head_dim ** -0.5
+        weights = np.exp(scores - scores.max(-1, keepdims=True))
+        weights /= weights.sum(-1, keepdims=True)
+        out[row] = np.einsum('ht,thd->hd', weights, values[slots])
+    return out
+
+
+def pools(seed: int, rows: int, heads: int, block: int, max_blocks: int,
+          dtype=jnp.float32):
+    """Seeded K and V pools (block 0 is trash, filled like the rest), a
+    table of distinct physical blocks per row, and a query."""
+    rng = np.random.default_rng(seed)
+    blocks = rows * max_blocks + 1
+    width = heads * HEAD_DIM
+    key_pool = jnp.asarray(rng.normal(size=(blocks * block, width)), dtype)
+    value_pool = jnp.asarray(rng.normal(size=(blocks * block, width)), dtype)
+    table = rng.permutation(np.arange(1, blocks)).reshape(
+        rows, max_blocks).astype(np.int32)
+    query = jnp.asarray(rng.normal(size=(rows, heads, HEAD_DIM)), dtype)
+    return query, key_pool, value_pool, table
+
+
+def attend(query, key_pool, value_pool, table, cursor, block):
+    return np.asarray(paged_decode_attention(
+        query, key_pool, value_pool, jnp.asarray(table),
+        jnp.asarray(cursor, jnp.int32), block=block, interpret=True))
+
+
+# depth 0, exactly one block, one past a block boundary, a chunk boundary
+# on each side, and the full max_seq; block 16 x 24 columns = 384 positions
+DEPTHS = [0, 15, 16, CHUNK_POSITIONS - 1, CHUNK_POSITIONS, 200, 383]
+
+
+@pytest.mark.parametrize('depth', DEPTHS)
+def test_one_row_at_every_kind_of_depth(depth):
+    query, key_pool, value_pool, table = pools(3, 1, 12, 16, 24)
+    cursor = np.array([depth], np.int32)
+    got = attend(query, key_pool, value_pool, table, cursor, 16)
+    want = reference(query, key_pool, value_pool, table, cursor, 16)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize('block', [8, 16])
+@pytest.mark.parametrize('heads', [12, 20])
+def test_ragged_rows_block_sizes_and_head_counts(block, heads):
+    """Rows of different depths in one call: each reads its own blocks at
+    its own depth whatever its neighbours hold (the double buffer hands
+    over between rows of unequal chunk counts)."""
+    max_blocks = 256 // block
+    query, key_pool, value_pool, table = pools(5, 6, heads, block, max_blocks)
+    cursor = np.array([0, block - 1, block, 255, 130, 77], np.int32)
+    got = attend(query, key_pool, value_pool, table, cursor, block)
+    want = reference(query, key_pool, value_pool, table, cursor, block)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_rows_whose_tables_share_physical_blocks():
+    """``share_prefix``: two rows name the same read-only blocks for their
+    common prefix and private ones after it."""
+    query, key_pool, value_pool, table = pools(7, 3, 12, 16, 8)
+    table[1, :3] = table[0, :3]
+    cursor = np.array([70, 55, 100], np.int32)
+    got = attend(query, key_pool, value_pool, table, cursor, 16)
+    want = reference(query, key_pool, value_pool, table, cursor, 16)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # the shared prefix really is shared: same query, same depth inside
+    # the common blocks -> the same context for both rows
+    query = query.at[1].set(query[0])
+    cursor = np.array([40, 40, 100], np.int32)
+    got = attend(query, key_pool, value_pool, table, cursor, 16)
+    np.testing.assert_array_equal(got[0], got[1])
+
+
+def test_a_parked_row_reads_one_position_of_the_trash_block():
+    query, key_pool, value_pool, table = pools(11, 3, 12, 16, 8)
+    table[1] = 0                                  # unmapped: all trash
+    cursor = np.array([90, 0, 17], np.int32)
+    got = attend(query, key_pool, value_pool, table, cursor, 16)
+    want = reference(query, key_pool, value_pool, table, cursor, 16)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # one position: its softmax weight is 1 and the context is that V row
+    np.testing.assert_allclose(
+        got[1].reshape(-1), np.asarray(value_pool[0]), atol=1e-6)
+
+
+def test_what_lies_past_the_cursor_is_never_read():
+    """NaN in every slot a row does not hold — the rest of its last block,
+    its later blocks, other rows' blocks — leaves the result finite and
+    unchanged: masked inside the last block, never fetched beyond it."""
+    query, key_pool, value_pool, table = pools(13, 2, 12, 16, 16)
+    cursor = np.array([37, 130], np.int32)
+    want = reference(query, key_pool, value_pool, table, cursor, 16)
+    held = np.zeros(key_pool.shape[0], bool)
+    for row in range(2):
+        positions = np.arange(cursor[row] + 1)
+        held[table[row, positions // 16] * 16 + positions % 16] = True
+    poison = jnp.where(jnp.asarray(held)[:, None], 0.0, jnp.nan)
+    got = attend(query, key_pool + poison, value_pool, table, cursor, 16)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # the value pool's stale tail inside a fetched block is multiplied by
+    # an exact zero, so there it must be finite (the engine's pools are)
+    got = attend(query, key_pool + poison,
+                 value_pool + jnp.where(jnp.isnan(poison), 1e4, 0.0),
+                 table, cursor, 16)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_bf16_pools_keep_float32_statistics():
+    """bf16 operands as on the chip: the result is the float32 reference's
+    on the same (rounded) pools to within one bf16 rounding of the output."""
+    query, key_pool, value_pool, table = pools(17, 4, 20, 16, 16,
+                                               jnp.bfloat16)
+    cursor = np.array([255, 3, 100, 16], np.int32)
+    got = attend(query, key_pool, value_pool, table, cursor, 16)
+    assert got.dtype == jnp.bfloat16
+    want = reference(query, key_pool, value_pool, table, cursor, 16)
+    np.testing.assert_allclose(got.astype(np.float32), want, atol=0.03)
+
+
+@pytest.mark.parametrize('heads,block,dtype,chunk', [
+    (20, 16, 'bfloat16', 8), (12, 16, 'bfloat16', 8), (12, 32, 'bfloat16', 4),
+    (12, 8, 'float32', 16), (12, 256, 'bfloat16', 1),
+    (12, 8, 'bfloat16', None),          # half a bf16 sublane tile a block
+    (3, 16, 'bfloat16', None)])         # 192 lanes: not whole lane tiles
+def test_the_plan_answers_from_shapes_alone(heads, block, dtype, chunk):
+    assert paged_plan(heads, HEAD_DIM, block, 1024 // block, dtype,
+                      interpret=False) == chunk
+    # interpret mode tiles anything, at the same chunk
+    assert paged_plan(heads, HEAD_DIM, block, 1024 // block, dtype,
+                      interpret=True) == max(1, CHUNK_POSITIONS // block)
+
+
+def test_the_chunk_never_passes_the_table():
+    assert paged_plan(12, HEAD_DIM, 8, 4, 'float32', interpret=True) == 4
+
+
+def test_a_refused_shape_raises_and_the_gate_names_it(monkeypatch):
+    query, key_pool, value_pool, table = pools(19, 1, 12, 8, 8, jnp.bfloat16)
+    with pytest.raises(ValueError, match='cannot tile'):
+        paged_decode_attention(query, key_pool, value_pool,
+                               jnp.asarray(table), jnp.zeros(1, jnp.int32),
+                               block=8, interpret=False)
+    with pytest.raises(ValueError, match='do not hold'):
+        paged_decode_attention(query, key_pool[:, :64], value_pool[:, :64],
+                               jnp.asarray(table), jnp.zeros(1, jnp.int32),
+                               block=8, interpret=True)
+    # on the chip the engine's gate names the refusal; off it (interpret)
+    # the same clone passes
+    decoder = GPT2(vocab_size=256, layers=2, dim=768, heads=12, max_seq=64,
+                   dtype='bfloat16', decode=True, per_row_decode=True,
+                   decode_pages=(9, 8))
+    assert fused_paged_reason(decoder) is None
+    monkeypatch.setattr('tpusystem.train.decode_fused.auto_interpret',
+                        lambda interpret: False)
+    assert 'paged-attention kernel cannot tile' in fused_paged_reason(decoder)
+
+
+@pytest.mark.parametrize('block', [8, 16])
+def test_the_step_writes_one_slot_a_row_and_no_other(block):
+    """The fused step around the kernel: each row's new K and V land at
+    its own slot (a parked row's at the trash block's first), every other
+    slot of every pool is bit for bit what it was, and the context the
+    layer attends with includes the token just written."""
+    from tpusystem.serve import Engine
+    module = gpt2_tiny(dtype='float32', max_seq=64)
+    params = module.init(jax.random.PRNGKey(0),
+                         jnp.zeros((1, 8), jnp.int32))['params']
+    engine = Engine(module, params, rows=3, block_size=block,
+                    decode_impl='fused')
+    rng = np.random.default_rng(23)
+    engine.admit([int(t) for t in rng.integers(0, 256, (block + 3,))],
+                 max_new=8)
+    engine.admit([int(t) for t in rng.integers(0, 256, (5,))], max_new=8)
+    before = jax.tree.map(np.asarray, engine._cache)
+    cursor = before['h_0']['attn']['index']
+    table = before['h_0']['attn']['table']
+    assert list(cursor) == [block + 3, 5, 0]              # row 2 is parked
+    engine.step()
+    after = jax.tree.map(np.asarray, engine._cache)
+    slots = table[np.arange(3), cursor // block] * block + cursor % block
+    assert slots[2] == 0                                   # trash
+    for layer in ('h_0', 'h_1'):
+        for name in ('key', 'value'):
+            was, now = before[layer]['attn'][name], after[layer]['attn'][name]
+            changed = np.flatnonzero((was != now).any(axis=1))
+            assert set(changed) <= set(slots)
+            assert set(slots[:2]) <= set(changed)          # live rows wrote
+
+
+# --- compiled for the chip, without the chip -----------------------------
+# The TPU's compiler is installed here and compiles for a described v5e:
+# what Mosaic refuses (a slice off the tiling, too much VMEM) fails here,
+# at no chip time. Only this file describes the topology, inside a fixture.
+
+@pytest.fixture(scope='module')
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topology = topologies.get_topology_desc(platform='tpu',
+                                                topology_name='v5e:2x2')
+    except Exception as error:
+        pytest.skip(f'no v5e:2x2 topology can be described here: {error}')
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.mark.parametrize('rows,heads', [(32, 20), (8, 12)],
+                         ids=['gpt2-large', 'gpt2-125m'])
+def test_the_kernel_compiles_for_a_v5e_at_serving_widths(one_chip, rows,
+                                                         heads):
+    """The cell's shapes (32 rows x 20 heads, block 16, 64 table columns)
+    and the smoke's: Mosaic takes the kernel, the pools go in as stored —
+    no copy, transpose or slice of a pool-shaped operand around it — and
+    the compiler names the call after the kernel, not after the jitted
+    step (``decode_chain_roofline`` sums ``step_fn [tpu_custom_call]``)."""
+    block, max_blocks = 16, 64
+    slots, width = (rows * max_blocks + 1) * block, heads * HEAD_DIM
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def step_fn(query, key_pool, value_pool, table, cursor):
+        with jax.named_scope('kv_read'):
+            return paged_decode_attention(query, key_pool, value_pool, table,
+                                          cursor, block=block,
+                                          interpret=False)
+
+    compiled = jax.jit(step_fn).lower(
+        shaped((rows, heads, HEAD_DIM), jnp.bfloat16),
+        shaped((slots, width), jnp.bfloat16),
+        shaped((slots, width), jnp.bfloat16),
+        shaped((rows, max_blocks), jnp.int32),
+        shaped((rows,), jnp.int32)).compile().as_text()
+    calls = [line for line in compiled.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert calls[0].strip().startswith('%paged_decode_attention')
+    pool = f'bf16[{slots},{width}]'
+    moved = [line for line in compiled.splitlines()
+             if pool in line.split(' = ')[-1].split('(')[0]
+             and 'parameter(' not in line]
+    assert not moved, moved

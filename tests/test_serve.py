@@ -713,6 +713,97 @@ class TestFusedDecodeImpl:
                 tokens = out
         assert tokens == expected
 
+    @staticmethod
+    def _drain(engine) -> dict:
+        tokens = {}
+        while engine.active_rows:
+            for row, _reason, out in engine.step().finished:
+                tokens[row] = out
+        return tokens
+
+    @pytest.mark.parametrize('levers', [
+        {}, {'stream_dtype': 'int8'}, {'share_prefix': True}],
+        ids=['greedy', 'int8', 'share_prefix'])
+    def test_fused_engine_emits_the_flax_engines_tokens(self, served, levers):
+        """Same requests, same levers, both step implementations: the
+        Pallas paged-attention read changes no token — plain greedy, int8
+        streaming, and rows whose tables share read-only prefix blocks."""
+        module, params = served
+        rng = np.random.default_rng(61)
+        common = [int(t) for t in rng.integers(0, 256, (17,))]
+        prompts = [common + [int(t) for t in rng.integers(0, 256, (k,))]
+                   for k in (3, 9)]
+        emitted = {}
+        for impl in ('flax', 'fused'):
+            engine = Engine(module, params, rows=2, block_size=8,
+                            decode_impl=impl, **levers)
+            for prompt in prompts:
+                engine.admit(prompt, max_new=7)
+            emitted[impl] = self._drain(engine)
+            assert engine.trace_count == 1
+            if levers.get('share_prefix'):
+                assert engine.sharing['prefix_hits'] == 1
+        assert emitted['fused'] == emitted['flax']
+
+    def test_fused_read_follows_each_rows_own_depth(self):
+        """Was: the bucket switch widening mid-stream. Now: a deep row
+        crosses a block boundary, then the kernel's chunk boundary (128
+        positions), beside a shallow row that stays inside its first
+        block — each token-exact against its own standalone decode, so a
+        row reads to its own cursor whatever the deepest row holds."""
+        module = gpt2_tiny(dtype='float32', max_seq=256)
+        rng = np.random.default_rng(67)
+        deep = [int(t) for t in rng.integers(0, 256, (121,))]
+        shallow = [int(t) for t in rng.integers(0, 256, (3,))]
+        params = module.init(jax.random.PRNGKey(1),
+                             jnp.asarray([deep[:8]]))['params']
+        engine = Engine(module, params, rows=2, block_size=16,
+                        decode_impl='fused')
+        engine.admit(deep, max_new=12)              # 121 -> 133
+        engine.admit(shallow, max_new=12)           # 3 -> 15
+        tokens = self._drain(engine)
+        assert tokens[0] == reference(module, params, deep, 12)
+        assert tokens[1] == reference(module, params, shallow, 12)
+        assert engine.trace_count == 1
+
+    def test_fused_step_holds_no_conditional_over_read_windows(self, served):
+        """The step as traced: one paged-attention kernel a layer beside
+        the chain's three, and — outside the kernels — no ``cond`` at all
+        (the five-way window switch is gone) and no gather out of a pool.
+        ``lowered_step()`` on the CPU holds the interpreter's own
+        conditionals, so the witness reads the jaxpr above the kernels."""
+        from tpusystem.train.decode_fused import build_fused_paged_step
+        module, params = served
+        engine = Engine(module, params, rows=2, block_size=8,
+                        decode_impl='fused')
+        jaxpr = jax.make_jaxpr(build_fused_paged_step(engine._decoder))(
+            engine._params, engine._cache, engine._tokens_dev)
+        pools = {leaf.shape for path, leaf
+                 in jax.tree_util.tree_leaves_with_path(engine._cache)
+                 if path[-1].key in ('key', 'value')}
+        kernels, seen = [], []
+
+        def walk(closed):
+            for eqn in closed.eqns:
+                if eqn.primitive.name == 'pallas_call':
+                    kernels.append(eqn.params['name'])
+                    continue                    # the kernel's own body
+                seen.append(eqn)
+                for value in eqn.params.values():
+                    inner = getattr(value, 'jaxpr', value)
+                    if hasattr(inner, 'eqns'):
+                        walk(inner)
+
+        walk(jaxpr.jaxpr)
+        assert kernels.count('paged_decode_attention') == module.layers
+        assert len(kernels) == 4 * module.layers
+        assert not [eqn for eqn in seen if eqn.primitive.name == 'cond']
+        assert not [eqn for eqn in seen if eqn.primitive.name == 'gather'
+                    and eqn.invars[0].aval.shape in pools]
+        # (lowering re-traces; the one-trace witness is put back)
+        assert 'jit_step_fn' in engine.lowered_step()
+        assert engine.trace_count == 0
+
     def test_fused_refuses_unsupported_and_auto_falls_back(self, served):
         module, params = served
         probe = jnp.zeros((1, 8), jnp.int32)

@@ -150,7 +150,7 @@ class TestShardedEngine:
         module, params = gpt2
         engine = Engine(module, params, rows=2, block_size=8)
         mesh = submesh(2, model=2)
-        specs = pool_shardings(engine._cache, mesh)
+        specs = pool_shardings(engine._cache, mesh, module.heads)
         leaves = jax.tree_util.tree_leaves_with_path(specs)
         kv = [s for path, s in leaves
               if path[-1] in (jax.tree_util.DictKey('key'),

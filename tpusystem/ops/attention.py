@@ -123,7 +123,11 @@ def paged_attention(module, query, key, value, max_seq: int,
     contiguous ``[max_seq, heads, head_dim]`` strip, the cache is one
     shared pool of ``num_blocks`` blocks of ``block_size`` tokens
     (``'key'``/``'value'`` cache variables, flattened to
-    ``[num_blocks * block_size, kv_heads, head_dim]``), and each row
+    ``[num_blocks * block_size, kv_heads * head_dim]`` — one position's
+    heads side by side on the minor dim, so a block is one contiguous,
+    lane-dense tile on the TPU: stored ``[..., kv_heads, head_dim]`` the
+    device keeps the *slot* dim minor and every program that scatters or
+    gathers rows first transposes the whole pool), and each row
     maps its *logical* block ``j`` (tokens ``j*block_size ...``) to a
     physical block through a ``'table'`` cache variable
     (``[batch, max_seq // block_size]`` int32). A sequence's cache can
@@ -156,7 +160,7 @@ def paged_attention(module, query, key, value, max_seq: int,
                          f'page block_size ({block})')
     batch, length, kv_heads, head_dim = key.shape
     max_blocks = max_seq // block
-    pool_shape = (num_blocks * block, kv_heads, head_dim)
+    pool_shape = (num_blocks * block, kv_heads * head_dim)
     cache_key = module.variable('cache', 'key', jnp.zeros, pool_shape,
                                 key.dtype)
     cache_value = module.variable('cache', 'value', jnp.zeros, pool_shape,
@@ -177,9 +181,10 @@ def paged_attention(module, query, key, value, max_seq: int,
     physical = jnp.take_along_axis(table.value, logical, axis=1)
     slots = (physical * block + positions % block).reshape(-1)  # [B*L]
     cache_key.value = cache_key.value.at[slots].set(
-        key.reshape(-1, kv_heads, head_dim).astype(cache_key.value.dtype))
+        key.reshape(-1, kv_heads * head_dim).astype(cache_key.value.dtype))
     cache_value.value = cache_value.value.at[slots].set(
-        value.reshape(-1, kv_heads, head_dim).astype(cache_value.value.dtype))
+        value.reshape(-1, kv_heads * head_dim).astype(
+            cache_value.value.dtype))
     index.value = cursor + length
 
     # bucketed block-window read: gather the first `width` table columns'
@@ -192,8 +197,10 @@ def paged_attention(module, query, key, value, max_seq: int,
             tokens = (mapped[:, :, None] * block
                       + jnp.arange(block)[None, None, :]
                       ).reshape(batch, width * block)
-            keys = jnp.take(cache_key.value, tokens, axis=0)
-            values = jnp.take(cache_value.value, tokens, axis=0)
+            window = (batch, width * block, kv_heads, head_dim)
+            keys = jnp.take(cache_key.value, tokens, axis=0).reshape(window)
+            values = jnp.take(cache_value.value, tokens,
+                              axis=0).reshape(window)
             mask = (jnp.arange(width * block)[None, None, :]
                     <= positions[:, :, None])                  # [B, L, W]
             return dot_product_attention(query, keys, values,

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpusystem.ops.pallas import auto_interpret
+from tpusystem.ops.pallas import auto_interpret, streamed_from_hbm
 from tpusystem.ops.precision import QuantizedLeaf, qdot
 
 LANES = 128   # lane tile; TPU block minor dims must be multiples
@@ -144,7 +144,7 @@ def decode_matmul(x, w, bias=None, *, activation=None, out_dtype=None,
         pl.BlockSpec((batch, inner), lambda n: (0, 0)),     # resident
         pl.BlockSpec((inner, block), lambda n: (0, n)),     # streamed
     ]
-    operands = [x, values]
+    operands = [x, streamed_from_hbm(values, interpret)]
     if scales is not None:
         in_specs.append(pl.BlockSpec((1, block), lambda n: (0, n)))
         operands.append(scales)
@@ -238,14 +238,14 @@ def decode_ffn(x, w1, b1, w2, b2, *, activation=jax.nn.gelu,
         pl.BlockSpec((batch, inner), lambda h: (0, 0)),      # resident
         pl.BlockSpec((inner, block), lambda h: (0, h)),      # fc stream
     ]
-    operands = [x, v1]
+    operands = [x, streamed_from_hbm(v1, interpret)]
     if s1 is not None:
         in_specs.append(pl.BlockSpec((1, block), lambda h: (0, h)))
         operands.append(s1.reshape(1, hidden))
     in_specs.append(pl.BlockSpec((1, block), lambda h: (0, h)))
     operands.append(_row(b1, hidden))
     in_specs.append(pl.BlockSpec((block, out_cols), lambda h: (h, 0)))
-    operands.append(v2)                                      # proj stream
+    operands.append(streamed_from_hbm(v2, interpret))        # proj stream
     if s2 is not None:
         in_specs.append(pl.BlockSpec((1, out_cols), lambda h: (0, 0)))
         operands.append(s2.reshape(1, out_cols))

@@ -250,9 +250,9 @@ def _build_resume(decoder, bucket: int):
         def seed_leaf(path, leaf):
             if _is_kv(path):
                 pool = source[jax.tree_util.keystr(path)]
-                strip = jnp.take(pool, slots, axis=0)    # [max_seq, h, d]
-                strip = jnp.where(keep[:, None, None], strip, 0)
-                return strip[None].astype(leaf.dtype)
+                strip = jnp.take(pool, slots, axis=0)    # [max_seq, h*d]
+                strip = jnp.where(keep[:, None], strip, 0)
+                return strip.reshape(leaf.shape).astype(leaf.dtype)
             if is_cursor(path):
                 return jnp.full(leaf.shape, cached_len, leaf.dtype)
             return jnp.zeros(leaf.shape, leaf.dtype)
@@ -480,7 +480,9 @@ class Engine:
             lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), shapes)
         if self.tp_plan.path == 'gspmd':
             self._cache = jax.device_put(
-                self._cache, pool_shardings(self._cache, self.mesh))
+                self._cache, pool_shardings(
+                    self._cache, self.mesh,
+                    getattr(module, 'kv_heads', module.heads)))
         # free seats: representative rows — every row when linear, the
         # first row of each fanout-wide adjacent group when speculative
         stride = self.tree_fanout if self._spec else 1

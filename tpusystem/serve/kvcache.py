@@ -324,7 +324,9 @@ def adopt_prefill(cache, prefill_cache, slots, row, length):
 
     ``prefill_cache`` is the contiguous decode cache a plain (non-paged)
     prefill apply left behind — per-layer KV strips ``[1, max_seq, heads,
-    head_dim]``; ``slots`` is the row's ``[max_seq]`` physical token-slot
+    head_dim]``, flattened here to the pool's ``[max_seq, heads *
+    head_dim]`` rows and scattered in place into the donated pool;
+    ``slots`` is the row's ``[max_seq]`` physical token-slot
     map (:meth:`PagedKVCache.slots`, trash-padded past the allocation, so
     pad-bucket junk beyond the prompt scatters into trash or into
     positions the decode write overwrites before the mask ever exposes
@@ -340,7 +342,8 @@ def adopt_prefill(cache, prefill_cache, slots, row, length):
     def fix(path, leaf):
         if _is_kv(path):
             strip = source[jax.tree_util.keystr(path)][0]  # [max_seq, h, d]
-            return leaf.at[slots].set(strip.astype(leaf.dtype))
+            return leaf.at[slots].set(
+                strip.reshape(strip.shape[0], -1).astype(leaf.dtype))
         if is_cursor(path):
             return leaf.at[row].set(jnp.asarray(length, leaf.dtype))
         return leaf
@@ -363,15 +366,17 @@ def write_tables(cache, tables):
     return jax.tree_util.tree_map_with_path(fix, cache)
 
 
-def pool_shardings(cache, mesh):
+def pool_shardings(cache, mesh, kv_heads: int):
     """Per-leaf :class:`~jax.sharding.NamedSharding` for a paged pool
     under a TP mesh — the mesh-aware half of the pool contract.
 
-    KV leaves ``[..., slots, heads, head_dim]`` shard over *heads* on the
-    ``model`` axis (each device holds its attention heads' blocks for
-    every slot — the same head split the TP matmuls already use, so
-    decode reads its KV locally). Heads that don't divide the axis fall
-    back replicated, the same divisibility discipline as
+    KV leaves ``[..., slots, kv_heads * head_dim]`` shard their minor dim
+    on the ``model`` axis: heads lie side by side there, so each device
+    holds whole attention heads' lanes for every slot — the same head
+    split the TP matmuls already use, so decode reads its KV locally.
+    ``kv_heads`` that don't divide the axis fall back replicated (a
+    split through the middle of a head serves nobody), the same
+    divisibility discipline as
     :meth:`~tpusystem.parallel.sharding.ShardingPolicy.spec`. Everything
     else — block tables, cursors, masks — replicates: the host-side
     :class:`PagedKVCache` stays the ONE block-table authority and
@@ -382,9 +387,9 @@ def pool_shardings(cache, mesh):
     model = dict(mesh.shape).get(MODEL, 1)
 
     def spec(path, leaf):
-        if _is_kv(path) and leaf.ndim >= 2 and leaf.shape[-2] % model == 0:
+        if _is_kv(path) and kv_heads % model == 0:
             axes = [None] * leaf.ndim
-            axes[-2] = MODEL
+            axes[-1] = MODEL
             return NamedSharding(mesh, PartitionSpec(*axes))
         return NamedSharding(mesh, PartitionSpec())
     return jax.tree_util.tree_map_with_path(spec, cache)

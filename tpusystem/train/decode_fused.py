@@ -25,6 +25,14 @@ are the flax path's own. Greedy decode is token-exact against
 platform's near-tie argmax tolerance at default MXU precision —
 the speculative-verify caveat, same cause.
 
+The serving engine's per-row step (:func:`build_fused_paged_step`) is
+the same chain over the PAGED pool, and its cache read is not bucketed:
+one Pallas kernel
+(:func:`tpusystem.ops.pallas.paged_attention.paged_decode_attention`)
+walks each row's own block-table columns up to its cursor and attends
+over the pool where it lies — no window gather, no ``lax.switch`` — and
+the step's K/V scatter updates the donated pool in place.
+
 Scope: the unrolled dense GPT-2 family (``fused_unsupported_reason``
 names the exact gate). Llama/MoE/scanned stacks fall back to the flax
 path under ``decode_impl='auto'`` and raise under an explicit
@@ -47,7 +55,10 @@ import jax
 import jax.numpy as jnp
 
 from tpusystem.ops.attention import NEG_INF
+from tpusystem.ops.pallas import auto_interpret
 from tpusystem.ops.pallas.decode_matmul import decode_ffn, decode_matmul
+from tpusystem.ops.pallas.paged_attention import (paged_decode_attention,
+                                                  paged_plan)
 from tpusystem.ops.precision import dequantize_streamed, head_logits
 
 
@@ -107,6 +118,15 @@ def fused_paged_reason(decoder) -> str | None:
     if not decoder.decode_pages:
         return ('no decode_pages on this clone — the paged step needs the '
                 "serving engine's block-pool cache layout")
+    block = decoder.decode_pages[1]
+    head_dim = decoder.dim // decoder.heads
+    if paged_plan(decoder.heads, head_dim, block, decoder.max_seq // block,
+                  decoder.dtype, auto_interpret(None)) is None:
+        return (f'the paged-attention kernel cannot tile heads='
+                f'{decoder.heads} head_dim={head_dim} block_size={block} '
+                f'{jnp.dtype(decoder.dtype).name} on the TPU (the pool\'s '
+                'minor dim must fill 128 lanes and a block whole sublane '
+                'tiles: 16 positions of bf16, 8 of f32)')
     return None
 
 
@@ -152,60 +172,18 @@ def _bucketed_attention(query, key_cache, value_cache, cursor, max_seq: int):
     return jax.lax.switch(bucket_index, [attend_over(w) for w in buckets])
 
 
-def _paged_attention_fused(query, key_pool, value_pool, table, cursor,
-                           max_seq: int, block: int):
-    """One-token bucketed attention over the serving engine's PAGED pool
-    — :func:`tpusystem.ops.attention.paged_attention`'s read path (same
-    block-window buckets, same gather, same mask, same f32 softmax) for
-    ``[B, H, hd]`` queries against ``[S, H, hd]`` pools through
-    ``[B, max_blocks]`` block tables at per-row depth ``cursor``. The
-    current token's KV must already be written at its slot (the write
-    happens before the read, exactly as in ``paged_attention``)."""
-    compute = query.dtype
-    batch = query.shape[0]
-    head_dim = query.shape[-1]
-    scale = head_dim ** -0.5
-    max_blocks = max_seq // block
-
-    def attend_over(width: int):
-        def run():
-            with jax.named_scope('kv_read'):
-                mapped = jax.lax.slice_in_dim(table, 0, width, axis=1)
-                tokens = (mapped[:, :, None] * block
-                          + jnp.arange(block)[None, None, :]
-                          ).reshape(batch, width * block)
-                keys = jnp.take(key_pool, tokens, axis=0)  # [B, W*blk, H, hd]
-                values = jnp.take(value_pool, tokens, axis=0)
-            with jax.named_scope('attention'):
-                scores = jnp.einsum(
-                    'bhd,bkhd->bhk', query, keys,
-                    preferred_element_type=jnp.float32) * scale
-                mask = (jnp.arange(width * block)[None, None, :]
-                        <= cursor[:, None, None])
-                scores = jnp.where(mask, scores, NEG_INF)
-                weights = jax.nn.softmax(scores, axis=-1)
-                return jnp.einsum('bhk,bkhd->bhd', weights.astype(compute),
-                                  values)
-        return run
-
-    buckets = [min(max_blocks, max(1, 64 // block))]
-    while buckets[-1] < max_blocks:
-        buckets.append(min(2 * buckets[-1], max_blocks))
-    if len(buckets) == 1:
-        return attend_over(max_blocks)()
-    filled_blocks = (jnp.max(cursor) + block) // block
-    bucket_index = sum((filled_blocks > width).astype(jnp.int32)
-                       for width in buckets[:-1])
-    return jax.lax.switch(bucket_index, [attend_over(w) for w in buckets])
-
-
 def build_fused_paged_step(decoder):
     """The serving engine's fused ``[rows, 1]`` token-step over the
     paged KV pool: the :func:`build_fused` step math (Pallas
     ``decode_matmul``/``decode_ffn``, in-kernel int8/fp8 dequant, f32
     layernorms, tied f32-logit head) with per-row cursors, the
-    block-table scatter write, and ``paged_attention``'s bucketed
-    block-window read. Returns ``step(params, cache, tokens) ->
+    block-table scatter write (in place on the donated pool), and the
+    Pallas paged-attention kernel
+    (:func:`tpusystem.ops.pallas.paged_attention.paged_decode_attention`)
+    that walks each row's own table columns ``0 … cursor // block`` over
+    the pool where it lies: no window is gathered, no bucket is switched
+    on, and a row pays for the positions it holds, not for the deepest
+    row's. Returns ``step(params, cache, tokens) ->
     (logits, new_cache)`` where ``cache`` is the engine's paged cache
     tree (per-layer ``key``/``value`` pools + ``table``/``index``,
     model-level ``position``); cursor leaves in the returned cache are
@@ -214,10 +192,14 @@ def build_fused_paged_step(decoder):
     arithmetic (the contiguous fused loop's contract).
 
     The XLA work between the kernels carries ``jax.named_scope`` names a
-    device trace is read by (``embed``, ``ln``, ``kv_write``, ``kv_read``,
-    ``attention``, ``head``). No scope encloses ``decode_matmul`` or
-    ``decode_ffn``: the TPU compiler names a Mosaic call after its
-    innermost scope, and the kernels' trace name is the jitted step's."""
+    device trace is read by (``embed``, ``ln``, ``kv_write``, ``head``),
+    and ``kv_read`` holds the paged-attention kernel, so the time the step
+    spends reading keys and values stays under the scope that always read
+    it. The TPU compiler names a Mosaic call after its innermost scope:
+    the kernel brings its own (``paged_decode_attention
+    [tpu_custom_call]`` in a trace), and no scope encloses
+    ``decode_matmul`` or ``decode_ffn`` — the weight chain's trace name
+    is the jitted step's."""
     reason = fused_paged_reason(decoder)
     if reason is not None:
         raise ValueError(f'fused paged step unsupported: {reason}')
@@ -252,8 +234,6 @@ def build_fused_paged_step(decoder):
             qkv = decode_matmul(normed, attn['qkv']['kernel'],
                                 attn['qkv']['bias'])
             query, key, value = jnp.split(qkv, 3, axis=-1)
-            shape = (rows, heads, head_dim)
-            query = query.reshape(shape)
             entry = cache[f'h_{index}']['attn']
             table = entry['table']
             with jax.named_scope('kv_write'):
@@ -261,13 +241,15 @@ def build_fused_paged_step(decoder):
                                                axis=1)[:, 0]
                 slots = physical * block + cursor % block    # [rows]
                 key_pool = entry['key'].at[slots].set(
-                    key.reshape(shape).astype(entry['key'].dtype))
+                    key.astype(entry['key'].dtype))
                 value_pool = entry['value'].at[slots].set(
-                    value.reshape(shape).astype(entry['value'].dtype))
+                    value.astype(entry['value'].dtype))
             pools[(f'h_{index}', 'key')] = key_pool
             pools[(f'h_{index}', 'value')] = value_pool
-            context = _paged_attention_fused(query, key_pool, value_pool,
-                                             table, cursor, max_seq, block)
+            with jax.named_scope('kv_read'):
+                context = paged_decode_attention(
+                    query.reshape(rows, heads, head_dim), key_pool,
+                    value_pool, table, cursor, block=block)
             attended = decode_matmul(context.reshape(rows, dim),
                                      attn['out']['kernel'],
                                      attn['out']['bias'])
