@@ -1,10 +1,12 @@
 """What decides ``correct``: the numbers compared and how each is taken.
 
-The drivers hand in what the timed path produced; this file runs the plain
-reference over the same inputs and measures the gaps. ``control.py`` runs
-the same functions with the reference computed in the precision below
-the configuration's, or with a fault planted, to read the upper ends that
-the limits in ``chipbench/limits/`` are set under.
+The drivers hand in what the timed path produced; the configuration's
+family (``chipbench/families/<family>.py``: ``reference_training``,
+``served_gap``) runs its plain reference over the same inputs, and this
+file, which names no model, measures the gaps. ``control.py`` takes the same
+readings with the reference computed in the precision below the
+configuration's, or with a fault planted, to read the upper ends that the
+limits in ``chipbench/limits/`` are set under.
 
 Training follows the program through its first dispatch: the loss of each
 of its steps, the first moment the optimizer holds after them (the
@@ -67,50 +69,6 @@ def compare_training(program: dict, reference: dict) -> tuple[dict, list]:
             'update_gap': moved}, notes
 
 
-def _model(config: dict) -> dict:
-    return dict(heads=config['n_head'],
-                eps=float(config['as_run']['layer_norm_epsilon']))
-
-
-def reference_training(config: dict, seed: int, batches, *,
-                       precision: str = 'float32') -> dict:
-    """The reference's reading of the same first steps: weights from the
-    seed, then one AdamW step per batch of ``batches`` (``[rows, seq]``
-    each), as ``config['as_run']['optimizer']`` states it."""
-    import jax
-    import jax.numpy as jnp
-    from chipbench import weights
-    from chipbench.reference import gpt2
-
-    optimizer = config['as_run']['optimizer']
-    params = weights.make(config, seed, stacked=True)   # donated, step by step
-    mu = jax.tree.map(jnp.zeros_like, params)
-    nu = jax.tree.map(jnp.zeros_like, params)
-    count = jnp.zeros((), jnp.int32)
-    rows = batches[0].shape[0]
-    block_rows = min(config['reference']['block_rows'], rows)
-    while rows % block_rows:
-        block_rows -= 1
-    losses = []
-    for batch in batches:
-        params, mu, nu, count, loss = gpt2.train_step(
-            params, mu, nu, count, jnp.asarray(batch), precision=precision,
-            block_rows=block_rows, lr=optimizer['lr'], b1=optimizer['b1'],
-            b2=optimizer['b2'], adam_eps=optimizer['eps'],
-            weight_decay=optimizer['weight_decay'],
-            grad_clip=optimizer['grad_clip'], **_model(config))
-        losses.append(loss)
-    moved = jax.jit(lambda new, old: weights.stacked_norms(
-        jax.tree.map(jnp.subtract, new, old)))(
-            params, weights.make(config, seed, stacked=True))
-    moment = jax.jit(weights.stacked_norms)(mu)
-    host = jax.device_get({'losses': losses, 'moment': moment,
-                           'moved': moved})
-    return {'losses': [float(x) for x in host['losses']],
-            'moment': {k: float(v) for k, v in host['moment'].items()},
-            'moved': {k: float(v) for k, v in host['moved'].items()}}
-
-
 def sample_requests(seed: int, finished: list, count: int) -> list:
     """A seeded sample of the finished requests with the longest in it;
     ``finished`` is ``[(prompt, tokens), ...]``."""
@@ -124,7 +82,7 @@ def sample_requests(seed: int, finished: list, count: int) -> list:
     return [finished[longest]] + [finished[rest[i]] for i in picked]
 
 
-def _sequence(prompt, tokens, length: int):
+def sequence(prompt, tokens, length: int):
     """Prompt and served tokens right-padded to ``length`` (padding sits
     after everything compared and is causally invisible), and the slice of
     next-token positions whose successor is a served token."""
@@ -132,31 +90,3 @@ def _sequence(prompt, tokens, length: int):
     padded = np.zeros(length, np.int32)
     padded[:len(ids)] = ids
     return padded, slice(len(prompt) - 1, len(ids) - 1)
-
-
-def served_gap(config: dict, seed: int, sample: list,
-               control_bits: int | None = None) -> tuple[float, int]:
-    """The widest gap over ``sample`` and how many served tokens it
-    covers. With ``control_bits`` the reading is the control's instead:
-    the gap of the token that the reference with its matrices at that many
-    bits puts first, at the same positions."""
-    import jax.numpy as jnp
-    from chipbench import weights
-    from chipbench.reference import gpt2
-
-    params = weights.make(config, seed, stacked=True)
-    lowered = (gpt2.quantize_matrices(params, control_bits)
-               if control_bits else None)
-    widest, covered = 0.0, 0
-    for prompt, tokens in sample:
-        padded, span = _sequence(prompt, tokens, config['n_positions'])
-        if lowered is None:
-            gaps = gpt2.served_gaps(params, jnp.asarray(padded),
-                                    **_model(config))
-        else:
-            gaps = gpt2.control_gaps(params, lowered, jnp.asarray(padded),
-                                     **_model(config))
-        gaps = np.asarray(gaps)[span]
-        widest = max(widest, float(gaps.max()))
-        covered += gaps.size
-    return widest, covered

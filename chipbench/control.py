@@ -3,15 +3,17 @@
 ``python chipbench/control.py --workload <cell> --seeds 1,2,3 [--controls 3]
 [--seconds 2]`` runs the cell's own driver once per seed with a short window
 (the lower reading: what sound runs of the program give) and, for the first
-``--controls`` seeds, the readings that have to come out as not correct:
+``--controls`` seeds, the readings that have to come out as not correct.
+The precision is the one below the configuration's own, which its file
+states under ``reference.control``; the reference is its family's:
 
 * training: the reference put in the program's place with both operands
-  of every matrix product rounded to fp8 (the precision below the
+  of every matrix product rounded to ``control.precision`` (fp8 under a
   configuration's bfloat16), and with half of each batch left out and the
   mean taken over the rest;
 * serving: at every compared position of the same prompts and served
-  tokens, the gap of the token that the reference with int4 matrices (the
-  precision below the configuration's int8) puts first.
+  tokens, the gap of the token that the reference with its matrices at
+  ``control.bits`` (4 under a configuration's int8) puts first.
 
 The benchmark's own runs never run this. It prints one JSON line per seed
 and a last line with the largest lower and smallest upper reading of each
@@ -32,22 +34,26 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def training_controls(config: dict, seed: int, records: dict) -> dict:
-    from chipbench import check
+    from chipbench import check, families
+    family = families.of(config)
     fed, reference = records['fed'], records['reference_reading']
     out = {}
-    lowered = check.reference_training(config, seed, fed, precision='fp8')
-    out['fp8'], _ = check.compare_training(lowered, reference)
+    precision = config['reference']['control']['precision']
+    lowered = family.reference_training(config, seed, fed,
+                                        precision=precision)
+    out[precision], _ = check.compare_training(lowered, reference)
     half = [batch[:batch.shape[0] // 2] for batch in fed]
-    halved = check.reference_training(config, seed, half)
+    halved = family.reference_training(config, seed, half)
     out['half_batch'], _ = check.compare_training(halved, reference)
     return out
 
 
 def serving_controls(config: dict, seed: int, records: dict) -> dict:
-    from chipbench import check
-    widest, _ = check.served_gap(config, seed, records['sample'],
-                                 control_bits=4)
-    return {'int4': {'logit_gap_max': widest}}
+    from chipbench import families
+    bits = config['reference']['control']['bits']
+    widest, _ = families.of(config).served_gap(
+        config, seed, records['sample'], control_bits=bits)
+    return {f'int{bits}': {'logit_gap_max': widest}}
 
 
 CONTROLS = {'train': training_controls, 'serve': serving_controls}

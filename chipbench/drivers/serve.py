@@ -88,28 +88,26 @@ class Clients:
 def run(run) -> dict:
     import jax
     import numpy as np
-    from chipbench import check, harness, weights
-    from tpusystem.models import GPT2
+    from chipbench import check, families, harness
     from tpusystem.observe.trace import Tracer
     from tpusystem.serve import InferenceService, Request
     from tpusystem.services.prodcon import Producer
 
     config, mix = run.cell.config, run.cell.traffic
-    as_run = config['as_run']
+    family, as_run = families.of(config), config['as_run']
     clock = time.perf_counter
-    module = GPT2(vocab_size=as_run['vocab_rows'], layers=config['n_layer'],
-                  dim=config['n_embd'], heads=config['n_head'],
-                  max_seq=config['n_positions'], dropout=as_run['dropout'])
+    module = family.serve_module(config)
     stages = [('start', clock() - run.started)]
     mark = lambda name: stages.append((name, clock() - run.started))
-    params = jax.block_until_ready(weights.make(config, run.seed))
+    params = jax.block_until_ready(family.make(config, run.seed))
     mark('weights')
     tracer = Tracer('serve', clock=clock) if run.trace else None
     producer = Producer()
     service = InferenceService(module, params, producer=producer,
                                rows=mix['rows'], block_size=mix['block_size'],
                                share_prefix=mix['share_prefix'],
-                               clock=clock, tracer=tracer)
+                               clock=clock, tracer=tracer,
+                               **as_run.get('levers', {}))
     del params                         # the engine keeps what it streams
     engine = service.engine
     resolved = {'stream_dtype': engine.stream_dtype,
@@ -121,7 +119,7 @@ def run(run) -> dict:
                 f'states {as_run[lever]!r}')
 
     mark('service built')
-    clients = Clients(run.seed, mix, config['vocab_size'])
+    clients = Clients(run.seed, mix, family.vocab_size(config))
     ticks: list = []                   # per InferenceService.step()
     bus = {'active': 0, 'queued': 0}
 
@@ -173,7 +171,7 @@ def run(run) -> dict:
     # has a request under way, so the window opens with every row full
     for index, length in enumerate(mix['warm_prompts']):
         prompt = np.random.default_rng([run.seed, 6, index]).integers(
-            0, config['vocab_size'], size=length).tolist()
+            0, family.vocab_size(config), size=length).tolist()
         service.service.handle('submit', Request(f'warm{index}', prompt, 4))
     service.run_until_idle()
     mark('buckets warm')
@@ -230,7 +228,7 @@ def run(run) -> dict:
     sample = check.sample_requests(run.seed, finished,
                                    config['reference']['sample_requests'])
     if sample:
-        widest, covered = check.served_gap(config, run.seed, sample)
+        widest, covered = family.served_gap(config, run.seed, sample)
     else:
         widest, covered = float('nan'), 0
     limits = run.cell.limits

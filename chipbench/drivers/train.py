@@ -80,13 +80,13 @@ class Recorder:
     def _norms(self):
         import jax
         import jax.numpy as jnp
-        from chipbench import weights
-        config = self.config
+        from chipbench import families, weights
+        config, family = self.config, families.of(self.config)
 
         def norms(params, mu, key):
             moved = jax.tree.map(jnp.subtract, params,
-                                 weights.from_key(config, key))
-            return (weights.unrolled_norms(mu), weights.unrolled_norms(moved))
+                                 family.from_key(config, key))
+            return family.norms(mu), family.norms(moved)
 
         state = self.model.state
         return jax.jit(norms)(state.params, adam_state(state.opt_state).mu,
@@ -156,11 +156,10 @@ def build_model(lm, config: dict, seed: int):
     """The aggregate through the program's own ``compiler`` pipeline, then
     its parameters replaced by the seeded ones, placed as it placed them."""
     import jax
-    from chipbench import weights
-    from tpusystem.models import GPT2
+    from chipbench import families
     from tpusystem.train import AdamW, ChunkedNextTokenLoss
 
-    as_run = config['as_run']
+    family, as_run = families.of(config), config['as_run']
     stated = as_run['optimizer']
     optimizer = AdamW(lr=stated['lr'], grad_clip=stated['grad_clip'])
     for field in ('b1', 'b2', 'eps', 'weight_decay'):
@@ -168,14 +167,10 @@ def build_model(lm, config: dict, seed: int):
             raise ValueError(
                 f'AdamW.{field} is {getattr(optimizer, field)}, the '
                 f'configuration states {stated[field]}')
-    network = GPT2(vocab_size=as_run['vocab_rows'], layers=config['n_layer'],
-                   dim=config['n_embd'], heads=config['n_head'],
-                   max_seq=config['n_positions'], dropout=as_run['dropout'],
-                   return_features=True, attention=as_run['attention'])
     model = lm.compiler.compile(
-        network, ChunkedNextTokenLoss(chunks=as_run['criterion']['chunks']),
-        optimizer)
-    seeded = weights.make(config, seed)
+        family.train_module(config),
+        ChunkedNextTokenLoss(chunks=as_run['criterion']['chunks']), optimizer)
+    seeded = family.make(config, seed)
     have = jax.tree.map(lambda leaf: (leaf.shape, leaf.dtype.name),
                         model.state.params)
     want = jax.tree.map(lambda leaf: (leaf.shape, leaf.dtype.name), seeded)
@@ -191,10 +186,11 @@ def build_model(lm, config: dict, seed: int):
 
 def run(run) -> dict:
     import jax
-    from chipbench import check, harness, traffic
+    from chipbench import check, families, harness, traffic
     from tpusystem.data import Loader
 
     config, mix = run.cell.config, run.cell.traffic
+    family = families.of(config)
     stages = [('start', time.perf_counter() - run.started)]
     mark = lambda name: stages.append((name,
                                        time.perf_counter() - run.started))
@@ -204,7 +200,7 @@ def run(run) -> dict:
         mark('composed')
         rows = FedRows(traffic.bigram_tokens(
             run.seed, samples=mix['epoch_batches'] * mix['batch'],
-            seq=mix['seq'], vocab=config['vocab_size'],
+            seq=mix['seq'], vocab=family.vocab_size(config),
             fanout=mix['bigram_fanout']))
         loader = Loader(rows, batch_size=mix['batch'],
                         shuffle=mix['shuffle'], seed=0)
@@ -250,7 +246,7 @@ def run(run) -> dict:
     gc.collect()
 
     began = time.perf_counter()
-    reference = check.reference_training(config, run.seed, fed)
+    reference = family.reference_training(config, run.seed, fed)
     numbers, notes = check.compare_training(program, reference)
     notes.append('set-up: ' + ', '.join(f'{name} {at:.1f} s'
                                         for name, at in stages))

@@ -26,7 +26,7 @@ def read(records, spec):
         seconds, bound = flops.roofline_seconds(ops, moved, peak)
         least += seconds
         bounds.append(bound)
-    least *= config['n_layer'] * records['traced']['steps']
+    least *= flops.flash_layers(config) * records['traced']['steps']
     print(f'flash_roofline: forward bound by {bounds[0]}, backward by '
           f'{bounds[1]}; least {least:.4f} s, kernels {spent:.4f} s',
           file=sys.stderr)

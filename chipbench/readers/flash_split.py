@@ -35,7 +35,7 @@ def read(records, spec):
                                            side)
     least, bound = flops.roofline_seconds(
         ops, moved, flops.peaks(records['device_kind']))
-    least *= config['n_layer'] * records['traced']['steps']
+    least *= flops.flash_layers(config) * records['traced']['steps']
     print(f'{spec["name"]}: bound by {bound}; least {least:.4f} s, kernels '
           f'{seconds[side]:.4f} s (forward {seconds[False]:.4f} + backward '
           f'{seconds[True]:.4f} = {seconds[False] + seconds[True]:.4f} s)',

@@ -1,8 +1,9 @@
 """The paged-attention kernel's share of its roofline: the bytes of keys
 and values the decode steps of the traced window had to read — for every
 token decoded inside it, the positions its row held (prompt + position, as
-``step_mfu`` walks the requests) x layers x K and V x ``n_embd`` x 2 bytes
-of bf16 — at the chip's HBM bytes/s, over the device time of the kernel's
+``step_mfu`` walks the requests) x the bytes one cached position holds
+(``flops.kv_bytes_per_position``: the family's layers, K and V, width and
+pool type) — at the chip's HBM bytes/s, over the device time of the kernel's
 events in the same window. Only positions a row holds count, never the
 reserved or the padded ones, so a kernel that skips what it need not read
 cannot pass 100 %. Silent where the trace holds no such kernel."""
@@ -10,8 +11,6 @@ cannot pass 100 %. Silent where the trace holds no such kernel."""
 import sys
 
 from chipbench import flops, trace_reduce
-
-KV_BYTES = 2.0     # the pool is bf16 (``as_run.kv_cache_dtype``)
 
 
 def read(records, spec):
@@ -31,7 +30,7 @@ def read(records, spec):
                     if position and lo <= moment < hi)
     if not positions:
         return None
-    moved = positions * config['n_layer'] * 2 * config['n_embd'] * KV_BYTES
+    moved = positions * flops.kv_bytes_per_position(config)
     least = moved / flops.peaks(records['device_kind'])['hbm_bytes_per_s']
     print(f'kv_read_roofline: bound by memory; {positions} positions '
           f'attended, {moved / 1e9:.3f} GB, least {least:.4f} s, kernels '

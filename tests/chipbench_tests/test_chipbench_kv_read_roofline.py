@@ -14,7 +14,7 @@ from chipbench.readers import kv_read_roofline
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / 'chipbench' / 'metrics'
                    / 'kv_read_roofline.json').read_text())
-CONFIG = {'n_layer': 2, 'n_embd': 128}
+CONFIG = {'family': 'gpt2', 'n_layer': 2, 'n_embd': 128}
 HBM = 819e9                                  # peaks.json, TPU v5 lite
 
 

@@ -3,17 +3,48 @@ float32, on the benchmark's own seeded weights; and the controls' levers."""
 
 from __future__ import annotations
 
+import hashlib
+import json
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from chipbench import check, weights
+from chipbench.families import gpt2 as family
 from chipbench.reference import gpt2
 from tests.chipbench_tests import tiny
 
 CONFIG = tiny.CONFIG
 MODEL = dict(heads=CONFIG['n_head'], eps=CONFIG['as_run']['layer_norm_epsilon'])
+
+
+def leaf_digests(tree: dict) -> dict:
+    """Sixteen hex digits of sha256 over each leaf's type, shape and bytes."""
+    out = {}
+    for name, leaf in weights.flatten(tree).items():
+        leaf = np.asarray(leaf)
+        out[name] = hashlib.sha256(
+            str(leaf.dtype).encode() + str(leaf.shape).encode()
+            + leaf.tobytes()).hexdigest()[:16]
+    return out
+
+
+PINNED = json.loads((pathlib.Path(__file__).parent
+                     / 'weight_digests.json').read_text())
+
+
+@pytest.mark.parametrize('case', sorted(PINNED))
+def test_every_seeded_leaf_is_bit_for_bit_what_pr_25_made(case):
+    """``weight_digests.json`` was recorded from ``chipbench/weights.py::
+    make`` at PR 25's tree, before the leaf tables moved into the family:
+    the same configuration and seed give the same bits, for the program's
+    tree and the reference's."""
+    seed, layout = case.split('/')
+    made = family.make(CONFIG, int(seed), stacked=layout == 'stacked')
+    assert leaf_digests(made) == PINNED[case]
 
 
 @pytest.fixture(scope='module')
@@ -29,26 +60,26 @@ def test_reference_logits_match_the_programs_gpt2_in_float32(tokens):
                   heads=CONFIG['n_head'], max_seq=CONFIG['n_positions'],
                   dropout=0.0, dtype='float32')
     with jax.default_matmul_precision('highest'):
-        got = module.apply({'params': weights.make(CONFIG, 11)}, tokens)
-    want = gpt2.logits(weights.make(CONFIG, 11, stacked=True),
+        got = module.apply({'params': family.make(CONFIG, 11)}, tokens)
+    want = gpt2.logits(family.make(CONFIG, 11, stacked=True),
                        jnp.asarray(tokens), **MODEL)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=0)
 
 
 def test_seeded_weights_repeat_and_both_layouts_hold_the_same_numbers():
-    flat = weights.flatten(weights.make(CONFIG, 2 ** 31 + 3))
-    again = weights.flatten(weights.make(CONFIG, 2 ** 31 + 3))
-    other = weights.flatten(weights.make(CONFIG, 4))
-    stacked = weights.flatten(weights.make(CONFIG, 2 ** 31 + 3, stacked=True))
+    flat = weights.flatten(family.make(CONFIG, 2 ** 31 + 3))
+    again = weights.flatten(family.make(CONFIG, 2 ** 31 + 3))
+    other = weights.flatten(family.make(CONFIG, 4))
+    stacked = weights.flatten(family.make(CONFIG, 2 ** 31 + 3, stacked=True))
     assert all((flat[k] == again[k]).all() for k in flat)
     assert any((flat[k] != other[k]).any() for k in flat)
     np.testing.assert_array_equal(flat['h_1/fc/kernel'],
                                   stacked['h/fc/kernel'][1])
     assert abs(float(flat['ln_f/scale'].mean()) - 1.0) < 0.02
-    unrolled = jax.device_get(weights.unrolled_norms(weights.make(CONFIG, 5)))
-    kept = jax.device_get(weights.stacked_norms(
-        weights.make(CONFIG, 5, stacked=True)))
+    unrolled = jax.device_get(family.norms(family.make(CONFIG, 5)))
+    kept = jax.device_get(family.stacked_norms(
+        family.make(CONFIG, 5, stacked=True)))
     assert unrolled.keys() == kept.keys()
     assert 'h_0/attn/qkv/bias.k' in kept        # the fused leaf is three
     for name in kept:
@@ -60,7 +91,7 @@ def test_reference_step_is_optax_adamw_with_a_clipped_gradient(tokens):
     import optax
     stated = CONFIG['as_run']['optimizer']
     batch = jnp.asarray(tokens[:2])
-    params = weights.make(CONFIG, 3, stacked=True)
+    params = family.make(CONFIG, 3, stacked=True)
     mean_loss = lambda p: (lambda s, n: s / n)(
         *gpt2.loss_sum(p, batch, **MODEL))
     transform = optax.chain(
@@ -73,7 +104,7 @@ def test_reference_step_is_optax_adamw_with_a_clipped_gradient(tokens):
         grads = jax.grad(mean_loss)(want)
         updates, state = transform.update(grads, state, want)
         want = optax.apply_updates(want, updates)
-    got = weights.make(CONFIG, 3, stacked=True)
+    got = family.make(CONFIG, 3, stacked=True)
     mu = jax.tree.map(jnp.zeros_like, got)
     nu = jax.tree.map(jnp.zeros_like, got)
     count = jnp.zeros((), jnp.int32)
@@ -97,7 +128,7 @@ def test_reference_step_is_optax_adamw_with_a_clipped_gradient(tokens):
 @pytest.mark.parametrize('precision, floor', [('bfloat16', 1e-8),
                                               ('fp8', 1e-5)])
 def test_lower_precisions_move_the_loss(tokens, precision, floor):
-    params = weights.make(CONFIG, 7, stacked=True)
+    params = family.make(CONFIG, 7, stacked=True)
     exact, count = gpt2.loss_sum(params, jnp.asarray(tokens), **MODEL)
     lowered, _ = gpt2.loss_sum(params, jnp.asarray(tokens),
                                precision=precision, **MODEL)
@@ -106,7 +137,7 @@ def test_lower_precisions_move_the_loss(tokens, precision, floor):
 
 
 def test_int4_matrices_read_wider_than_int8_and_leave_the_table_alone():
-    params = weights.make(CONFIG, 9, stacked=True)
+    params = family.make(CONFIG, 9, stacked=True)
     narrow = gpt2.quantize_matrices(params, 4)
     np.testing.assert_array_equal(narrow['wte']['embedding'],
                                   params['wte']['embedding'])
@@ -114,23 +145,23 @@ def test_int4_matrices_read_wider_than_int8_and_leave_the_table_alone():
                                   params['h']['fc']['bias'])
     assert len(np.unique(np.asarray(narrow['h']['fc']['kernel'][0, :, 0]))) <= 15
     sample = [(list(range(5, 25)), list(range(30, 42)))]
-    gap4, covered = check.served_gap(CONFIG, 9, sample, control_bits=4)
-    gap8, _ = check.served_gap(CONFIG, 9, sample, control_bits=8)
+    gap4, covered = family.served_gap(CONFIG, 9, sample, control_bits=4)
+    gap8, _ = family.served_gap(CONFIG, 9, sample, control_bits=8)
     assert covered == 12 and gap4 >= gap8 >= 0.0
 
 
 def test_served_gap_is_nought_for_the_references_own_greedy_tokens():
-    params = weights.make(CONFIG, 13, stacked=True)
+    params = family.make(CONFIG, 13, stacked=True)
     prompt = list(range(3, 20))
     ids = list(prompt)
     for _ in range(6):
         scores = gpt2.logits(params, jnp.asarray([ids]), **MODEL)
         ids.append(int(jnp.argmax(scores[0, -1])))
-    gap, covered = check.served_gap(CONFIG, 13, [(prompt, ids[len(prompt):])])
+    gap, covered = family.served_gap(CONFIG, 13, [(prompt, ids[len(prompt):])])
     assert covered == 6 and gap == 0.0
     altered = ids[len(prompt):]
     altered[2] = (altered[2] + 1) % CONFIG['vocab_size']
-    wrong, _ = check.served_gap(CONFIG, 13, [(prompt, altered)])
+    wrong, _ = family.served_gap(CONFIG, 13, [(prompt, altered)])
     assert wrong > 0.0
 
 

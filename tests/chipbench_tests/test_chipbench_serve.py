@@ -4,9 +4,7 @@ per-layer metric are added by files and entries alone."""
 
 from __future__ import annotations
 
-import hashlib
 import json
-import pathlib
 
 import pytest
 
@@ -88,18 +86,13 @@ def test_a_request_that_never_finishes_is_not_correct(root, monkeypatch):
 
 
 def test_int4_control_reads_wider_than_the_tiny_cells_limit(root):
-    from chipbench import check, harness
+    from chipbench import families, harness
     cell = harness.load_cell('tiny-serve', root)
     sample = [(list(range(7, 40)), list(range(50, 60)))]
-    control, _ = check.served_gap(cell.config, 5, sample, control_bits=4)
+    control, _ = families.of(cell.config).served_gap(
+        cell.config, 5, sample,
+        control_bits=cell.config['reference']['control']['bits'])
     assert control > cell.limits['logit_gap_max']['limit']
-
-
-def _digests(root: pathlib.Path) -> dict:
-    return {str(path.relative_to(root)): hashlib.sha256(
-        path.read_bytes()).hexdigest()
-        for path in sorted(root.rglob('*')) if path.is_file()
-        and path.name != 'BENCHMARK.json'}
 
 
 def test_a_cell_and_a_metric_are_added_by_files_alone(root, monkeypatch):
@@ -109,7 +102,7 @@ def test_a_cell_and_a_metric_are_added_by_files_alone(root, monkeypatch):
     import chipbench.readers
     from chipbench import harness, trace_reduce
     tiny.steer(monkeypatch)
-    before = _digests(root)
+    before = tiny.digests(root)
     bench_before = json.loads((root / 'BENCHMARK.json').read_text())
 
     config = dict(tiny.CONFIG, name='tiny-one', n_layer=1)
@@ -151,7 +144,7 @@ def test_a_cell_and_a_metric_are_added_by_files_alone(root, monkeypatch):
                                'workloads': ['one-pair']})
     (root / 'BENCHMARK.json').write_text(json.dumps(bench))
 
-    after = _digests(root)
+    after = tiny.digests(root)
     assert {path: after[path] for path in before} == before
     # the old entries of BENCHMARK.json stand as they were, up to the new
     # cell's name in the lists of cells that report an end-to-end metric

@@ -12,7 +12,8 @@ import sys
 import numpy as np
 import pytest
 
-from chipbench import flops, harness, trace_reduce, traffic
+from chipbench import families, flops, harness, trace_reduce, traffic
+from tests.chipbench_tests import tiny
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / 'BENCHMARK.json').read_text())
@@ -60,7 +61,11 @@ def test_bounds_are_inside_the_contract():
 def test_every_cell_finds_its_files_and_reports_enough(cell):
     loaded = harness.load_cell(cell)
     assert loaded.chips in (1, 4)
-    assert loaded.traffic['driver'] in ('train', 'serve')
+    # the seam: a driver and a family are modules found by name
+    assert (ROOT / 'chipbench' / 'drivers'
+            / f'{loaded.traffic["driver"]}.py').exists()
+    assert (ROOT / 'chipbench' / 'families'
+            / f'{loaded.config["family"]}.py').exists()
     reported = {metric['name'] for metric in loaded.end_to_end}
     assert 'setup_s' in reported and len(reported) >= 2
     assert loaded.per_layer, 'a cell reports at least one per-layer metric'
@@ -86,12 +91,60 @@ def test_per_layer_metric_moves_what_its_cells_report(metric):
         assert entry['unit'] == '%'
 
 
-def test_configurations_state_their_source_and_cuts():
-    for entry in BENCH['configs']:
-        config = _config(entry['name'])
-        assert config['source'] == entry['source']
-        assert config['reduced'] == entry['reduced'] == []
-        assert entry['file'].startswith('chipbench/')
+@pytest.mark.parametrize('name', [c['name'] for c in BENCH['configs']])
+def test_configurations_state_their_source_and_cuts(name):
+    entry = next(c for c in BENCH['configs'] if c['name'] == name)
+    config = json.loads((ROOT / entry['file']).read_text())
+    assert config['source'] == entry['source']
+    assert entry['file'].startswith('chipbench/')
+    tiny.check_cuts(config, entry)
+
+
+def test_a_cut_without_its_published_value_or_deployment_is_refused():
+    config = {'depth': 4, 'reduced': ['depth'], 'published': {'depth': 32},
+              'deployment': 'eight chips share each layer; this is one'}
+    tiny.check_cuts(config, {'reduced': ['depth']})
+    for broken in ({**config, 'published': {}},
+                   {**config, 'deployment': 'whole'},
+                   {**config, 'reduced': ['width']},
+                   {**config, 'published': {'depth': 4}}):
+        with pytest.raises((AssertionError, KeyError)):
+            tiny.check_cuts(broken, {'reduced': broken['reduced']})
+    with pytest.raises(AssertionError):
+        tiny.check_cuts(config, {'reduced': []})
+
+
+@pytest.mark.parametrize('family', sorted(
+    path.stem for path in (ROOT / 'chipbench' / 'families').glob('*.py')
+    if path.stem != '__init__'))
+def test_a_family_gives_every_name_the_readme_fixes(family):
+    module = families.of({'family': family})     # refuses a missing name
+    readme = (ROOT / 'chipbench' / 'README.md').read_text()
+    for name in families.INTERFACE:
+        assert callable(getattr(module, name)), name
+        assert f'`{name}(' in readme, f'the README does not fix {name}'
+
+
+def test_a_family_that_lacks_a_name_is_refused_when_it_is_found(monkeypatch):
+    from chipbench.families import gpt2
+    monkeypatch.delattr(gpt2, 'flash_layers')
+    with pytest.raises(AttributeError, match='flash_layers'):
+        families.of({'family': 'gpt2'})
+
+
+def test_nothing_outside_the_families_names_a_model():
+    """The acceptance grep of ISSUE 26, kept as a test."""
+    named = re.compile(r'GPT2|n_layer|n_embd|n_head|n_positions|'
+                       r'reference\.gpt2|reference import gpt2')
+    bench = ROOT / 'chipbench'
+    files = [bench / name for name in ('harness.py', 'run.py', 'check.py',
+                                       'control.py', 'traffic.py', 'flops.py',
+                                       'weights.py')]
+    files += sorted((bench / 'drivers').glob('*.py'))
+    files += [path for path in sorted((bench / 'readers').glob('*.py'))
+              if path.name != 'program_trace.py']   # its notes quote a trace
+    for path in files:
+        assert not named.search(path.read_text()), path.name
 
 
 def test_four_chip_cells_stay_within_a_quarter():
@@ -240,6 +293,60 @@ def test_gap_attribution_prefers_the_innermost_span():
     assert named['chipbench.submit'] == pytest.approx(0.8)
     assert named['unattributed'] == pytest.approx(1.5)
     assert 'chipbench.window' not in named
+
+
+def _attribute_gaps_by_every_span(gaps, host_spans, count=10):
+    """``attribute_gaps`` as PR 23 wrote it, every span shown to every gap:
+    the reference the quicker one has to equal, digit for digit."""
+    totals: dict = {}
+    spans = sorted(host_spans, key=lambda span: span[2] - span[1])
+    for start, end in gaps:
+        free = [(start, end)]
+        for name, a, b in spans:
+            if name == trace_reduce.WINDOW_SPAN:
+                continue
+            rest = []
+            for lo, hi in free:
+                cut_lo, cut_hi = max(lo, a), min(hi, b)
+                if cut_hi <= cut_lo:
+                    rest.append((lo, hi))
+                    continue
+                totals[name] = totals.get(name, 0.0) + (cut_hi - cut_lo)
+                if lo < cut_lo:
+                    rest.append((lo, cut_lo))
+                if cut_hi < hi:
+                    rest.append((cut_hi, hi))
+            free = rest
+        left = sum(hi - lo for lo, hi in free)
+        if left > 0:
+            totals['unattributed'] = totals.get('unattributed', 0.0) + left
+    ranked = sorted(totals.items(), key=lambda item: -item[1])
+    return [[name, seconds] for name, seconds in ranked[:count]]
+
+
+@pytest.mark.parametrize('seed', range(6))
+def test_gap_attribution_equals_the_loop_over_every_span(seed):
+    """Nested, touching, equal-length and far-reaching spans over many
+    gaps: the same names, the same seconds to the last bit, the same order."""
+    rng = np.random.default_rng(seed)
+    edges = np.sort(rng.uniform(0.0, 100.0, size=800)).tolist()
+    gaps = list(zip(edges[::2], edges[1::2]))
+    spans = [('chipbench.window', 0.0, 100.0), ('long', 10.0, 90.0)]
+    for index, start in enumerate(rng.uniform(0.0, 99.0, size=40)):
+        length = float(rng.choice([0.25, 0.25, 1.0, 3.0]))
+        spans.append((f'span{index % 7}', float(start), float(start) + length))
+        if index % 5 == 0:            # a span nested in the one before
+            spans.append((f'inner{index % 3}', float(start) + 0.05,
+                          float(start) + 0.05 + length / 3))
+    spans.append(('touching', gaps[7][1], gaps[8][0]))   # lies in no gap
+    order = rng.permutation(len(spans))
+    spans = [spans[i] for i in order]
+    want = _attribute_gaps_by_every_span(gaps, spans, count=50)
+    assert trace_reduce.attribute_gaps(gaps, spans, count=50) == want
+    assert 'touching' not in dict(want)
+    assert {'long', 'unattributed', 'inner0', 'span3'} <= set(dict(want))
+    assert trace_reduce.attribute_gaps(gaps, [], count=5) == \
+        _attribute_gaps_by_every_span(gaps, [], count=5)
 
 
 def test_a_trace_with_no_device_operation_is_an_error():

@@ -79,14 +79,17 @@ def test_the_fp8_control_fails_the_tiny_cells_limits(root):
     """The control of "How correct is decided": the reference put in the
     program's place, computed in fp8, must not pass."""
     import numpy as np
-    from chipbench import check, harness, traffic
+    from chipbench import check, families, harness, traffic
     cell = harness.load_cell('tiny-train', root)
+    family = families.of(cell.config)
     mix = cell.traffic
     rows = traffic.bigram_tokens(5, samples=mix['batch'] * 2, seq=mix['seq'],
                                  vocab=cell.config['vocab_size'])
     fed = list(np.split(rows, 2))
-    reference = check.reference_training(cell.config, 5, fed)
-    lowered = check.reference_training(cell.config, 5, fed, precision='fp8')
+    reference = family.reference_training(cell.config, 5, fed)
+    lowered = family.reference_training(
+        cell.config, 5, fed,
+        precision=cell.config['reference']['control']['precision'])
     numbers, _ = check.compare_training(lowered, reference)
     compared = [(name, numbers[name], cell.limits[name]['limit'])
                 for name in cell.limits]
