@@ -236,9 +236,22 @@ def run(run) -> dict:
         harness.percentile(samples, share) if samples else late)
     spread = lambda samples: ' '.join(
         f'p{int(100 * share)} {1e3 * harness.percentile(samples, share):.1f}'
-        for share in (0.5, 0.9, 0.95, 0.99, 1.0)) if samples else 'none'
+        for share in (0.5, 0.9, 0.93, 0.95, 0.97, 0.99, 1.0)) if samples else 'none'
+    # where a slow run is slow: the window's ticks with no admission against
+    # those with one, each split into the engine's own step and the rest
+    timed = [t for t in ticks[warm_ticks:] if 'drain' not in t]
+    plain = [t for t in timed if not t['prefill_s'] and not t['admit_s']]
+    busy = [t for t in timed if t['prefill_s'] or t['admit_s']]
+    median_ms = lambda values: (1e3 * harness.percentile(values, 0.5)
+                                if values else float('nan'))
+    wall = lambda group: median_ms([t['end'] - t['start'] for t in group])
+    part = lambda group, key: median_ms([t[key] for t in group])
     notes = [f'ttft ms over {len(ttft)}: {spread(ttft)}; token gap ms over '
              f'{len(gaps)}: {spread(gaps)}',
+             f'tick ms, medians: {len(plain)} with no admission {wall(plain):.2f} '
+             f'(engine step {part(plain, "decode_s"):.2f}); {len(busy)} with '
+             f'one {wall(busy):.2f} (step {part(busy, "decode_s"):.2f}, prefill '
+             f'{part(busy, "prefill_s"):.2f}, admit {part(busy, "admit_s"):.2f})',
              'set-up: ' + ', '.join(f'{name} {at:.1f} s'
                                     for name, at in stages),
              f'resolved {resolved}; warm-up {warm_ticks} ticks; window '

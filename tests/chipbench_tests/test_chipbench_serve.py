@@ -95,15 +95,26 @@ def test_int4_control_reads_wider_than_the_tiny_cells_limit(root):
     assert control > cell.limits['logit_gap_max']['limit']
 
 
-def test_a_cell_and_a_metric_are_added_by_files_alone(root, monkeypatch):
+@pytest.fixture(params=['tiny', 'checkout'])
+def grown(request, root, tmp_path):
+    """A root to add to, and the serving cell that is there: the tiny root,
+    and a copy of this checkout with its real ``BENCHMARK.json``."""
+    if request.param == 'tiny':
+        return root, 'tiny-serve'
+    return tiny.checkout(tmp_path / 'checkout'), 'serve-large-closed32'
+
+
+def test_a_cell_and_a_metric_are_added_by_files_alone(grown, monkeypatch):
     """A second configuration, traffic mix, cell, per-layer metric and its
     reader, written as new files (and new entries of BENCHMARK.json), are
     picked up with no edit to any file that was there."""
     import chipbench.readers
     from chipbench import harness, trace_reduce
+    root, serving = grown
     tiny.steer(monkeypatch)
     before = tiny.digests(root)
     bench_before = json.loads((root / 'BENCHMARK.json').read_text())
+    old_before = harness.load_cell(serving, root)
 
     config = dict(tiny.CONFIG, name='tiny-one', n_layer=1)
     (root / 'chipbench' / 'configs' / 'tiny-one.json').write_text(
@@ -112,9 +123,9 @@ def test_a_cell_and_a_metric_are_added_by_files_alone(root, monkeypatch):
     (root / 'chipbench' / 'traffic' / 'tiny-pair.json').write_text(
         json.dumps(mix))
     (root / 'chipbench' / 'limits' / 'one-pair.json').write_text(
-        (root / 'chipbench' / 'limits' / 'tiny-serve.json').read_text())
+        json.dumps(tiny.LIMITS['tiny-serve']))
     readers = root / 'chipbench' / 'readers'
-    readers.mkdir()
+    readers.mkdir(exist_ok=True)
     (readers / 'ticks_seen.py').write_text(
         'def read(records, spec):\n'
         '    ticks = records.get("ticks")\n'
@@ -125,7 +136,7 @@ def test_a_cell_and_a_metric_are_added_by_files_alone(root, monkeypatch):
                     'unit': 'ticks', 'better': 'higher',
                     'source': 'program_counter',
                     'moves': 'serve_tokens_per_s', 'reader': 'ticks_seen',
-                    'args': {'scale': 2}, 'workloads': ['one-pair']}))
+                    'args': {'scale': 2}}))
     bench = json.loads(json.dumps(bench_before))
     bench['configs'].append({'name': 'tiny-one', 'source': 'test',
                              'file': 'chipbench/configs/tiny-one.json',
@@ -134,7 +145,7 @@ def test_a_cell_and_a_metric_are_added_by_files_alone(root, monkeypatch):
                                'traffic': 'tiny-pair', 'chips': 1,
                                'why': 't'})
     for metric in bench['end_to_end']:
-        if 'tiny-serve' in metric.get('workloads', []):
+        if serving in metric.get('workloads', []):
             metric['workloads'].append('one-pair')
     bench['per_layer'].append({'name': 'ticks_seen', 'unit': 'ticks',
                                'better': 'higher',
@@ -164,7 +175,8 @@ def test_a_cell_and_a_metric_are_added_by_files_alone(root, monkeypatch):
     assert harness.read_per_layer(cell, records, root) == {
         'ticks_seen': {'value': 6.0, 'unit': 'ticks'}}
     assert harness.read_per_layer(cell, {'ticks': []}, root) == {}
-    # and the cells that were there still find their own metrics
-    old = harness.load_cell('tiny-serve', root)
+    # and the cell that was there still finds its own metrics and no other
+    old = harness.load_cell(serving, root)
     assert 'row_occupancy' in [m['name'] for m in old.per_layer]
-    assert 'ticks_seen' not in [m['name'] for m in old.per_layer]
+    assert [m['name'] for m in old.per_layer] == [
+        m['name'] for m in old_before.per_layer]

@@ -80,6 +80,8 @@ def test_per_layer_metric_moves_what_its_cells_report(metric):
                        / f'{metric}.json').read_text())
     for key in ('layer', 'unit', 'moves', 'source', 'better'):
         assert spec[key] == entry[key], key
+    # which cells report it is BENCHMARK.json's to say, and nothing else's
+    assert 'workloads' not in spec
     assert (ROOT / 'chipbench' / 'readers' / f'{spec["reader"]}.py').exists()
     moved = next(m for m in BENCH['end_to_end']
                  if m['name'] == entry['moves'])

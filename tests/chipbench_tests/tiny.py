@@ -55,31 +55,65 @@ CELLS = {'tiny-train': ('tiny-train', 'train-medium-seq1024'),
          'tiny-serve': ('tiny-serve', 'serve-large-closed32')}
 
 
-def build(dest) -> pathlib.Path:
-    """Write the tiny root under ``dest`` and return it."""
+def build(dest, source: pathlib.Path = ROOT) -> pathlib.Path:
+    """Write the tiny root under ``dest`` and return it.
+
+    It follows ``source``'s ``BENCHMARK.json`` and does not know it: ``CELLS``
+    alone says which real cell a tiny cell stands for. A name in a metric's
+    ``workloads`` that stands for no tiny cell is left out of the tiny
+    root's list, and a metric left with no cell is left out of the tiny root
+    with its file, so a cell or a metric a later PR adds changes nothing
+    here."""
     dest = pathlib.Path(dest)
     bench_dir = dest / 'chipbench'
-    shutil.copytree(ROOT / 'chipbench' / 'metrics', bench_dir / 'metrics')
-    for sub in ('configs', 'traffic', 'limits'):
-        (bench_dir / sub).mkdir()
+    for sub in ('metrics', 'configs', 'traffic', 'limits'):
+        (bench_dir / sub).mkdir(parents=True)
     (bench_dir / 'configs' / 'tiny.json').write_text(json.dumps(CONFIG))
     (bench_dir / 'traffic' / 'tiny-train.json').write_text(json.dumps(TRAIN))
     (bench_dir / 'traffic' / 'tiny-serve.json').write_text(json.dumps(SERVE))
-    bench = json.loads((ROOT / 'BENCHMARK.json').read_text())
+    bench = json.loads((source / 'BENCHMARK.json').read_text())
     stands_for = {real: tiny for tiny, (_, real) in CELLS.items()}
     bench['configs'] = [{'name': 'tiny', 'source': 'test', 'reduced': [],
                          'file': 'chipbench/configs/tiny.json', 'why': 't'}]
     bench['workloads'] = [
         {'name': name, 'config': 'tiny', 'traffic': mix, 'chips': 1,
          'why': 't'} for name, (mix, _) in CELLS.items()]
-    for metric in bench['end_to_end'] + bench['per_layer']:
-        if 'workloads' in metric:
-            metric['workloads'] = [stands_for[name]
-                                   for name in metric['workloads']]
+    for group in ('end_to_end', 'per_layer'):
+        for metric in bench[group]:
+            if 'workloads' in metric:
+                metric['workloads'] = [stands_for[name]
+                                       for name in metric['workloads']
+                                       if name in stands_for]
+        bench[group] = [metric for metric in bench[group]
+                        if metric.get('workloads', True)]
+    for metric in bench['per_layer']:
+        shutil.copy(source / 'chipbench' / 'metrics' / f'{metric["name"]}.json',
+                    bench_dir / 'metrics')
     (dest / 'BENCHMARK.json').write_text(json.dumps(bench))
     for name in CELLS:
         (bench_dir / 'limits' / f'{name}.json').write_text(
             json.dumps(LIMITS[name]))
+    return dest
+
+
+def checkout(dest) -> pathlib.Path:
+    """A copy of the checkout's benchmark under ``dest``: ``BENCHMARK.json``
+    and the directories it names under ``paths``, copied, so that a test may
+    add to them; the program the benchmark measures is linked, not copied.
+    ``python -m pytest`` run from there imports the copy's ``chipbench`` and
+    ``tests.chipbench_tests``."""
+    dest = pathlib.Path(dest)
+    bench = json.loads((ROOT / 'BENCHMARK.json').read_text())
+    for path in ['BENCHMARK.json', 'pyproject.toml', 'tests/__init__.py',
+                 'tests/conftest.py'] + bench['paths']:
+        (dest / path).parent.mkdir(parents=True, exist_ok=True)
+        if (ROOT / path).is_dir():
+            shutil.copytree(ROOT / path, dest / path,
+                            ignore=shutil.ignore_patterns('__pycache__'))
+        else:
+            shutil.copy(ROOT / path, dest / path)
+    for program in ('tpusystem', 'examples'):
+        (dest / program).symlink_to(ROOT / program)
     return dest
 
 
