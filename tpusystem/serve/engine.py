@@ -65,7 +65,10 @@ The decode-roofline levers compose on top of that contract:
   so the journal's emitted prefix is the ONLY replay state: a replayed,
   rerouted, or hedged row reproduces the identical sample stream
   bitwise on any engine. ``temperature == 0`` (the default) is the
-  plain greedy argmax, bitwise-unchanged.
+  plain greedy argmax, bitwise-unchanged — and a dispatch none of whose
+  rows samples runs that argmax alone
+  (:func:`tpusystem.train.generate.select_tokens`: one ``cond`` on the
+  device per program call; ``selection`` counts each side's ticks).
 """
 
 from __future__ import annotations
@@ -86,7 +89,7 @@ from tpusystem.train.cursors import gather_rows, is_cursor, read_cursor, rewind
 from tpusystem.train.decode_fused import (build_fused_paged_step,
                                           fused_paged_reason)
 from tpusystem.train.generate import (_decoder, _dequant, _stream_params,
-                                      sample_token)
+                                      select_tokens)
 
 
 class Saturated(RuntimeError):
@@ -210,8 +213,8 @@ def _build_prefill(decoder, bucket: int):
             mutable=['cache'])
         # the first token samples at the row's own (seed, position)
         # counter — greedy defaults reproduce the classic argmax bitwise
-        first = sample_token(logits[0, length - 1], seed, position, temp,
-                             topk, topp, mask)
+        first = select_tokens(logits[0, length - 1], seed, position, temp,
+                              topk, topp, mask)
         return first, state['cache']
 
     return run
@@ -261,8 +264,8 @@ def _build_resume(decoder, bucket: int):
         logits, state = decoder.apply(
             {'params': _dequant(params, decoder), 'cache': resumed},
             padded, mutable=['cache'])
-        first = sample_token(logits[0, suffix_len - 1], seed, position,
-                             temp, topk, topp, mask)
+        first = select_tokens(logits[0, suffix_len - 1], seed, position,
+                              temp, topk, topp, mask)
         return first, state['cache']
 
     return run
@@ -354,6 +357,10 @@ class _RowState:
     prior: tuple = ()                # tokens emitted in a previous life
     #                                  (replay prefix) — position and
     #                                  mask_fn both see prior + tokens
+
+    @property
+    def sampled(self) -> bool:
+        return self.sampling is not None and self.sampling.sampled
 
 
 class Engine:
@@ -525,6 +532,13 @@ class Engine:
         self.sharing = {'admissions': 0, 'prefix_hits': 0,
                         'prompt_tokens': 0, 'shared_tokens': 0,
                         'resumed_prefills': 0}
+        # which side of select_tokens' cond each decode dispatch took,
+        # counted on the host from the seated rows' SamplingParams (the
+        # device decides from the same temperatures, never read back)
+        self.selection = {'greedy_ticks': 0, 'sampled_ticks': 0}
+        # seated requests decoding with temperature > 0, kept at register
+        # and evict (the observability plane's sampled-traffic gauge)
+        self.sampled_rows = 0
         # wall seconds of the most recent decode dispatch (admission and
         # prefill excluded) — the decode-only probe for a custom serving
         # loop that wants to feed failover.StepWatchdog.observe the step
@@ -548,10 +562,8 @@ class Engine:
             self._step = None
             return
 
-        # every row samples at its own (seed, position) counter; greedy
-        # rows (temp 0) take the argmax branch of the same program
-        sample_rows = jax.vmap(sample_token)
-
+        # every row samples at its own (seed, position) counter; a tick
+        # with no sampled row takes select_tokens' argmax side alone
         if self.decode_impl == 'fused':
             fused = build_fused_paged_step(self._decoder)
 
@@ -560,8 +572,8 @@ class Engine:
                 self.trace_count += 1        # runs at trace time only
                 logits, updated = fused(params, cache, tokens)
                 with jax.named_scope('select'):
-                    token = sample_rows(logits, seed, pos, temp, topk, topp,
-                                        mask)
+                    token = select_tokens(logits, seed, pos, temp, topk,
+                                          topp, mask)
                 cursor = read_cursor(cache)
                 return (token,
                         rewind(updated, jnp.where(active, cursor + 1, 0)),
@@ -575,8 +587,8 @@ class Engine:
                      'cache': cache},
                     tokens[:, None], mutable=['cache'])
                 with jax.named_scope('select'):
-                    token = sample_rows(logits[:, -1], seed, pos, temp, topk,
-                                        topp, mask)
+                    token = select_tokens(logits[:, -1], seed, pos, temp,
+                                          topk, topp, mask)
                 # park retired rows' cursors at 0 so their dead writes
                 # stay in the trash block's first slots instead of
                 # walking off the table; active rows keep the cursor
@@ -683,17 +695,12 @@ class Engine:
             # exactly the sequential sampled stream — a greedy draft
             # token is accepted iff it equals the sampled target choice,
             # so mismatched drafts cost speed, never the stream. Greedy
-            # rows (temp 0) reduce to the classic argmax verify bitwise.
-            def sample_window(logits_w, seed_r, pos_r, temp_r, topk_r,
-                              topp_r, mask_r):
-                offsets = pos_r + jnp.arange(K + 1)
-                return jax.vmap(
-                    lambda logits_j, pos_j: sample_token(
-                        logits_j, seed_r, pos_j, temp_r, topk_r, topp_r,
-                        mask_r))(logits_w, offsets)
-
-            candidates = jax.vmap(sample_window)(vlogits, seed, pos, temp,
-                                                 topk, topp, mask)
+            # rows (temp 0) reduce to the classic argmax verify bitwise,
+            # and a tick of greedy rows alone runs that argmax and no more.
+            with jax.named_scope('select'):
+                candidates = select_tokens(
+                    vlogits, seed, pos[:, None] + jnp.arange(K + 1), temp,
+                    topk, topp, mask)
             matches = (drafts == candidates[:, :K]).astype(jnp.int32)
             accepted = jnp.sum(jnp.cumprod(matches, axis=1), axis=1)
 
@@ -746,13 +753,6 @@ class Engine:
     @property
     def active_rows(self) -> int:
         return int(self._active.sum())
-
-    @property
-    def sampled_rows(self) -> int:
-        """Seated requests currently decoding with ``temperature > 0``
-        (the observability plane's sampled-traffic gauge)."""
-        return sum(1 for state in self._rowstate.values()
-                   if state.sampling is not None and state.sampling.sampled)
 
     def can_admit(self, prompt_len: int, max_new: int,
                   prompt=None) -> bool:
@@ -983,6 +983,7 @@ class Engine:
                                         stop=stop_token, tag=tag,
                                         sampling=sampling,
                                         prior=tuple(emitted))
+        self.sampled_rows += self._rowstate[rep].sampled
         reason = self._finish_reason(rep)
         if reason is not None:
             self.evict(rep)
@@ -1164,7 +1165,8 @@ class Engine:
         if self._spec:
             return self._spec_tick()
         started = time.perf_counter()
-        with annotate('tpusystem.engine.dispatch'):
+        with annotate('tpusystem.engine.dispatch',
+                      select=self._count_selection()):
             token_dev, self._cache, self._pos_dev = self._step(
                 self._params, self._cache, self._tokens_dev,
                 self._active_dev, self._seed_dev, self._pos_dev,
@@ -1200,24 +1202,34 @@ class Engine:
                     self._mask_dev = self._mask_dev.at[row].set(mask)
         return StepReport(emitted, finished)
 
-    def lowered_step(self) -> str:
+    def _count_selection(self) -> str:
+        """Count the decode dispatch about to run on the side of
+        ``select_tokens``' cond it will take, and name that side (the
+        ``select`` stat of the ``tpusystem.engine.dispatch`` span)."""
+        kind = 'sampled' if self.sampled_rows else 'greedy'
+        self.selection[f'{kind}_ticks'] += 1
+        return kind
+
+    def lowered_step(self, debug_info: bool = False) -> str:
         """The plain decode step as lowered text on the engine's own
         operands — what ``chip_smoke.py`` greps for the Mosaic custom
-        call. Lowering re-traces, so the ``trace_count`` witness is put
-        back."""
+        call; with ``debug_info`` every operation carries its
+        ``named_scope`` path as a location. Lowering re-traces, so the
+        ``trace_count`` witness is put back."""
         traces = self.trace_count
         try:
             return self._step.lower(
                 self._params, self._cache, self._tokens_dev,
                 self._active_dev, self._seed_dev, self._pos_dev,
                 self._temp_dev, self._topk_dev, self._topp_dev,
-                self._mask_dev).as_text()
+                self._mask_dev).as_text(debug_info=debug_info)
         finally:
             self.trace_count = traces
 
     def _spec_tick(self) -> StepReport:
         started = time.perf_counter()
-        with annotate('tpusystem.engine.dispatch'):
+        with annotate('tpusystem.engine.dispatch',
+                      select=self._count_selection()):
             emitted_dev, accepted_dev, self._tokens_dev, self._cache, \
                 self._dcache, self._pos_dev = self._spec_step(
                     self._params, self._dparams, self._cache, self._dcache,
@@ -1277,6 +1289,7 @@ class Engine:
                 self._temp_dev = self._temp_dev.at[member].set(0.0)
                 if state.sampling.mask_fn is not None:
                     self._mask_dev = self._mask_dev.at[member].set(True)
+        self.sampled_rows -= state.sampled
         self._cache = write_tables(self._cache, self.pool.table)
         self._free_rows.append(row)
         return self._rowstate.pop(row)

@@ -157,7 +157,8 @@ def sample_token(logits, seed, position, temperature, top_k, top_p, mask):
     """Deterministically sample ONE token from one ``[vocab]`` logits row.
 
     The single sampling primitive shared by the serving engine's jitted
-    decode step, its prefill programs, and the speculative verify — so a
+    decode step, its prefill programs, and the speculative verify (all
+    through :func:`select_tokens`, its batched entry) — so a
     token's identity is a pure function of
     ``(logits, seed, position, temperature, top_k, top_p, mask)`` and
     nothing else. Contract pins:
@@ -195,6 +196,49 @@ def sample_token(logits, seed, position, temperature, top_k, top_p, mask):
     sampled = jax.random.categorical(
         sampling_key(seed, position), filtered).astype(jnp.int32)
     return jnp.where(temperature > 0.0, sampled, greedy)
+
+
+def select_tokens(logits, seed, position, temperature, top_k, top_p, mask):
+    """:func:`sample_token` over a batch, paying for sampling only when
+    some row of the batch samples.
+
+    One ``lax.cond`` on ``any(temperature > 0)`` — a device value, decided
+    per program call, nothing read on the host — stands OUTSIDE the
+    ``vmap`` (a ``cond`` on a batched predicate under ``vmap`` lowers to a
+    ``select`` and runs both sides). Its sampled side is
+    ``vmap(sample_token)`` itself, so a batch holding one sampled row
+    gives every row, greedy ones too, ``sample_token``'s token bit for
+    bit; its greedy side is the masked argmax ``sample_token`` computes
+    for ``greedy``, without the sort / softmax / cumsum / scatter /
+    categorical chain over the vocabulary.
+
+    Shapes: ``temperature`` (with ``seed`` / ``top_k`` / ``top_p``) has
+    one entry per row — ``[]`` for the one-row prefill, ``[rows]`` for
+    the decode step; ``mask`` is ``temperature.shape + [vocab]``;
+    ``logits`` is ``position.shape + [vocab]``. Leading dims of
+    ``logits`` past the rows' (the speculative verify's ``[rows, K+1]``
+    window) share their row's params and sample at their own position."""
+    rows = jnp.ndim(temperature)
+    slots = jnp.ndim(logits) - 1 - rows
+
+    def sampled_rows():
+        sample = sample_token
+        for _ in range(slots):
+            sample = jax.vmap(sample,
+                              in_axes=(0, None, 0, None, None, None, None))
+        for _ in range(rows):
+            sample = jax.vmap(sample)
+        return sample(logits, seed, position, temperature, top_k, top_p,
+                      mask)
+
+    def greedy_rows():
+        allowed = jnp.expand_dims(mask, tuple(range(rows, rows + slots)))
+        return jnp.argmax(
+            jnp.where(allowed, logits.astype(jnp.float32), -jnp.inf),
+            axis=-1).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(temperature > 0.0), sampled_rows,
+                        greedy_rows)
 
 
 def generate(module, params, prompt, *, steps: int,
