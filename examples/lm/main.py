@@ -67,8 +67,8 @@ class LanguageModel(Aggregate):
                                             self.optimizer,
                                             accumulate=self.accumulate)
         self._eval_step = build_eval_step(apply_fn, self.criterion)
-        # N steps per host dispatch: one lax.scan amortizes the per-dispatch
-        # Python cost the same way bench.py's compiled loop does
+        # N steps per host dispatch: one lax.scan pays the per-dispatch
+        # Python cost once for the N batches
         self._train_many = build_multi_step(
             build_train_step(apply_fn, self.criterion, self.optimizer,
                              accumulate=self.accumulate, jit=False))

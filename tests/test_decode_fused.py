@@ -1,22 +1,19 @@
 """Serving-path decode: quantized weight streaming + the fused Pallas
 decode chain.
 
-Three layers of parity, mirroring the test_moe kernel discipline:
+Two layers of parity, mirroring the test_moe kernel discipline:
 the quantize/dequantize pair's error bounds and leaf rule
-(`ops/precision.py`), the Pallas kernels directly against their einsum
-references in interpret mode (`ops/pallas/decode_matmul.py`), and the
-whole fused decode loop token-for-token against the flax reference path
-(`train/decode_fused.py`).
+(`ops/precision.py`) and the Pallas kernels directly against their einsum
+references in interpret mode (`ops/pallas/decode_matmul.py`). The third,
+the engine's whole fused step (`train/decode_fused.py`) token for token
+against its flax step, is `tests/test_serve.py::TestFusedDecodeImpl`.
 """
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpusystem.models import gpt2_tiny, llama_tiny
 from tpusystem.ops.pallas.decode_matmul import (decode_ffn, decode_matmul,
                                                 decode_plan)
 from tpusystem.ops.precision import (QuantizedLeaf, dequantize_leaf,
@@ -206,57 +203,3 @@ def test_decode_matmul_rejects_mismatched_shapes():
     with pytest.raises(ValueError, match='compose'):
         decode_ffn(jnp.ones((2, 4)), jnp.ones((4, 8)), jnp.zeros(8),
                    jnp.ones((9, 4)), jnp.zeros(4))
-
-
-# --- the fused decode loop vs the flax reference -------------------------
-
-@pytest.fixture(scope='module')
-def prompt():
-    return jnp.asarray(
-        np.random.default_rng(7).integers(0, 256, (2, 8)), jnp.int32)
-
-
-def test_fused_decode_matches_flax_token_exact(prompt):
-    from tpusystem.train import generate
-    module = gpt2_tiny(dtype='float32')
-    params = module.init(jax.random.PRNGKey(0), prompt)['params']
-    flax = generate(module, params, prompt, steps=12, decode_impl='flax')
-    fused = generate(module, params, prompt, steps=12, decode_impl='fused')
-    np.testing.assert_array_equal(np.asarray(fused), np.asarray(flax))
-
-
-@pytest.mark.slow
-def test_fused_decode_matches_flax_under_quantized_streaming(prompt):
-    """stream_dtype='int8' composes with decode_impl='fused': the
-    in-kernel dequantize must reproduce the flax loop's
-    dequantize-then-apply math token for token."""
-    from tpusystem.train import generate
-    module = gpt2_tiny(dtype='float32')
-    params = module.init(jax.random.PRNGKey(0), prompt)['params']
-    flax = generate(module, params, prompt, steps=10, stream_dtype='int8')
-    fused = generate(module, params, prompt, steps=10, stream_dtype='int8',
-                     decode_impl='fused')
-    np.testing.assert_array_equal(np.asarray(fused), np.asarray(flax))
-
-
-def test_fused_decode_impl_names_its_scope(prompt):
-    from tpusystem.train import generate
-    from tpusystem.train.decode_fused import fused_unsupported_reason
-
-    llama = llama_tiny(dtype='float32')
-    params = llama.init(jax.random.PRNGKey(0), prompt)['params']
-    with pytest.raises(ValueError, match='GPT2'):
-        generate(llama, params, prompt, steps=2, decode_impl='fused')
-    # 'auto' silently falls back to the flax loop for the same module
-    out = generate(llama, params, prompt, steps=2, decode_impl='auto')
-    assert out.shape == (2, 10)
-
-    scanned = dataclasses.replace(gpt2_tiny(dtype='float32'),
-                                  decode=True, scan_layers=True)
-    assert 'scan_layers' in fused_unsupported_reason(scanned)
-    moe = dataclasses.replace(gpt2_tiny(dtype='float32'), decode=True,
-                              moe_experts=2)
-    assert 'MoE' in fused_unsupported_reason(moe)
-
-    with pytest.raises(ValueError, match='decode_impl'):
-        generate(llama, params, prompt, steps=2, decode_impl='vectorized')

@@ -1,8 +1,10 @@
 """Docs-site consistency: mkdocs.yml nav targets exist and every
 mkdocstrings directive names an importable module — so the CI docs job
 (`mkdocs build --strict`) cannot fail on references this environment
-can't check (mkdocs itself is not installed here)."""
+can't check (mkdocs itself is not installed here) — and every file or
+directory a page or a docstring cites is in the checkout."""
 
+import functools
 import importlib
 import os
 import pathlib
@@ -49,6 +51,63 @@ def test_api_pages_cover_every_module_and_import():
     assert modules == directives, (
         f'API pages out of sync: missing {modules - directives}, '
         f'stale {directives - modules}')
+
+
+# A citation is a back-quoted token that reads as a path (a file suffix or
+# a trailing ``/``; a ``::test`` tail is dropped), or a root document named
+# bare in capitals, as this repository names them (``PERF.md``,
+# ``BENCHMARK.json``). One that carries line numbers (``compiler.py:164``)
+# is not this repository's: that is SURVEY.md's way of citing the fixed
+# sources of the project this one was modelled on, and lines move here.
+QUOTED = re.compile(r'`+([^`\s]+?)(?:::[^`]*)?`+')
+PATH = re.compile(r'[\w./-]*\w(?:/|\.(?:py|md|json|jsonl|yml|toml|txt|cpp))')
+ROOT_DOCUMENT = re.compile(r'\b[A-Z][A-Z_]+\.(?:md|json|jsonl)\b')
+# that project's files where they are cited without lines; it ships an
+# ``examples/tinysys`` of its own
+REFERENCE = ('torchsystem/', 'examples/tinysys/')
+# what running leaves in a checkout (.gitignore): never walked
+UNTRACKED = {'__pycache__', 'chiprun_out'}
+PAGES = sorted(DOCS.glob('*.md')) + [REPO / 'README.md', REPO / 'COVERAGE.md']
+
+
+@functools.cache
+def _citable() -> frozenset:
+    """Every way to cite a file or a directory (with its trailing ``/``)
+    of the checkout: each tail of its path (``parallel/overlap.py`` cites
+    ``tpusystem/parallel/overlap.py``). Hidden directories other than
+    ``.github`` and ``.claude`` are what runs leave behind."""
+    tails = set()
+    for root, names, leaves in os.walk(REPO):
+        names[:] = [name for name in names if name not in UNTRACKED
+                    and (not name.startswith('.')
+                         or name in ('.github', '.claude'))]
+        parts = pathlib.Path(root).relative_to(REPO).parts
+        for last in [*leaves, *(f'{name}/' for name in names)]:
+            tails.update('/'.join((*parts[start:], last))
+                         for start in range(len(parts) + 1))
+    return frozenset(tails)
+
+
+def _missing(text: str) -> list[str]:
+    """The paths ``text`` cites that are not in the checkout."""
+    cited = {token for token in QUOTED.findall(text)
+             if PATH.fullmatch(token)} | set(ROOT_DOCUMENT.findall(text))
+    return sorted(token for token in cited - _citable()
+                  if not token.startswith(REFERENCE))
+
+
+@pytest.mark.parametrize('page', PAGES,
+                         ids=lambda page: page.relative_to(REPO).as_posix())
+def test_every_path_a_page_cites_exists(page):
+    missing = _missing(page.read_text())
+    assert not missing, f'{page.name} cites what is not there: {missing}'
+
+
+def test_every_path_the_package_cites_exists():
+    missing = {module.relative_to(REPO).as_posix(): found
+               for module in sorted((REPO / 'tpusystem').rglob('*.py'))
+               if (found := _missing(module.read_text()))}
+    assert not missing, f'the package cites what is not there: {missing}'
 
 
 @pytest.mark.slow

@@ -1,6 +1,6 @@
 """Integration test: the LM pretraining example end to end, twice (resume).
 
-The BASELINE ladder-4 architecture — GPT-2 aggregate, FSDP policy on the
+The architecture — GPT-2 aggregate, FSDP policy on the
 job mesh, fused chunked LM loss — driven through the full message stack:
 compiler pipeline, service handlers, tracking/checkpoint consumers,
 resume-by-identity.

@@ -300,7 +300,7 @@ def test_stream_dtype_auto_matches_f32_streaming_exactly():
     (stream_dtype='auto') must produce bit-identical generations to
     streaming the f32 masters: the model casts weights to bf16 at every
     use anyway, so only the HBM bytes change (the decode bandwidth
-    optimization — see BASELINE.md decode roofline)."""
+    optimization)."""
     module = gpt2_tiny(dtype='bfloat16')
     prompt = jnp.asarray(
         np.random.default_rng(23).integers(0, 256, (2, 8)), jnp.int32)

@@ -6,8 +6,9 @@ match the GSPMD reference (a plain global matmul — what the partitioner
 computes via its monolithic collectives) in forward AND gradients, f32 at
 tight tolerance and bf16 bounded (f32 accumulation, different summation
 order), with the one-shot fallback taken exactly where chunk shapes
-cannot tile. Model-level: ``tp_impl='overlap'`` is a pure implementation
-knob for GPT-2 and Llama — identical param trees, matching logits/grads.
+cannot tile. Model-level: ``OverlapSchedule(tp='overlap')`` is a pure
+implementation knob for GPT-2 and Llama — identical param trees, matching
+logits/grads.
 """
 
 import functools
@@ -20,9 +21,10 @@ from jax.sharding import PartitionSpec as P
 
 from tpusystem.models import GPT2
 from tpusystem.models.llama import llama_tiny
-from tpusystem.parallel import (MeshSpec, ShardingPolicy, batch_sharding,
-                                allgather_matmul, allgather_plan,
-                                matmul_reducescatter, reducescatter_plan)
+from tpusystem.parallel import (MeshSpec, OverlapSchedule, ShardingPolicy,
+                                batch_sharding, allgather_matmul,
+                                allgather_plan, matmul_reducescatter,
+                                reducescatter_plan)
 from tpusystem.parallel.mesh import MODEL
 
 RING = 4           # >= 4-device virtual mesh (conftest forces 8 devices)
@@ -180,7 +182,7 @@ def test_one_shot_fallback_still_matches_reference():
 
 
 # ---------------------------------------------------------------------------
-# model-level: the tp_impl knob
+# model-level: the schedule's tp= knob
 # ---------------------------------------------------------------------------
 
 
@@ -204,7 +206,7 @@ def _run_model(model, rules, tokens, mesh):
 
 
 @pytest.mark.parametrize('family', ['gpt2', 'llama'])
-def test_tp_impl_overlap_matches_gspmd_model_level(family):
+def test_tp_overlap_matches_gspmd_model_level(family):
     """Same params, logits and grads either way: 'overlap' is purely an
     implementation knob for the TP FFN projections."""
     mesh = _model_mesh()
@@ -215,10 +217,11 @@ def test_tp_impl_overlap_matches_gspmd_model_level(family):
         if family == 'gpt2':
             model = GPT2(vocab_size=256, layers=2, dim=64, heads=4,
                          max_seq=128, dropout=0.0, dtype='float32',
-                         mesh=mesh, tp_impl=impl, tp_chunks=2)
+                         mesh=mesh,
+                         schedule=OverlapSchedule(tp=impl, chunks=2))
             return model, GPT2.partition_rules()
-        model = llama_tiny(dtype='float32', mesh=mesh, tp_impl=impl,
-                           tp_chunks=2)
+        model = llama_tiny(dtype='float32', mesh=mesh,
+                           schedule=OverlapSchedule(tp=impl, chunks=2))
         return model, type(model).partition_rules()
 
     v_ref, out_ref, grads_ref = _run_model(*build('gspmd'),
@@ -238,7 +241,7 @@ def test_tp_impl_overlap_matches_gspmd_model_level(family):
                                    rtol=2e-4, atol=2e-5)
 
 
-def test_tp_impl_overlap_falls_back_on_non_tiling_sequence():
+def test_tp_overlap_falls_back_on_non_tiling_sequence():
     """seq=15 cannot shard over the model axis -> the Dense/GSPMD path
     runs under the same params and the forward still matches."""
     mesh = _model_mesh()
@@ -246,18 +249,10 @@ def test_tp_impl_overlap_falls_back_on_non_tiling_sequence():
         np.random.default_rng(1).integers(0, 256, (4, 15)), jnp.int32)
     common = dict(vocab_size=256, layers=2, dim=64, heads=4, max_seq=128,
                   dropout=0.0, dtype='float32', mesh=mesh)
-    reference = GPT2(**common, tp_impl='gspmd')
-    model = GPT2(**common, tp_impl='overlap')
+    reference = GPT2(**common, schedule=OverlapSchedule(tp='gspmd'))
+    model = GPT2(**common, schedule=OverlapSchedule(tp='overlap'))
     variables = reference.init(jax.random.PRNGKey(0), tokens[:1, :8])
     out_ref = jax.jit(lambda v, t: reference.apply(v, t))(variables, tokens)
     out_ovl = jax.jit(lambda v, t: model.apply(v, t))(variables, tokens)
     np.testing.assert_allclose(np.asarray(out_ref), np.asarray(out_ovl),
                                rtol=1e-6, atol=1e-6)
-
-
-def test_tp_impl_rejects_unknown_value():
-    model = GPT2(vocab_size=64, layers=1, dim=32, heads=4, max_seq=32,
-                 dropout=0.0, dtype='float32', tp_impl='magic')
-    tokens = jnp.zeros((1, 8), jnp.int32)
-    with pytest.raises(ValueError, match='tp_impl'):
-        model.init(jax.random.PRNGKey(0), tokens)

@@ -166,25 +166,17 @@ def test_policy_fsdp_placement_tie_break_is_deterministic():
 
 
 # ---------------------------------------------------------------------------
-# the schedule object and the legacy-knob seam
+# the schedule object and the models' seam
 # ---------------------------------------------------------------------------
 
 
-def test_resolve_schedule_folds_legacy_knobs():
-    schedule = resolve_schedule(None, 'overlap', 2)
-    assert schedule == OverlapSchedule(tp='overlap', fsdp='gspmd', chunks=2)
+def test_resolve_schedule_defaults_and_passes_through():
     assert resolve_schedule(None) == OverlapSchedule()
     passed = OverlapSchedule(tp='overlap', fsdp='prefetch', chunks=4)
     assert resolve_schedule(passed) is passed
 
 
-def test_resolve_schedule_rejects_conflicting_knobs():
-    with pytest.raises(ValueError, match='not both'):
-        resolve_schedule(OverlapSchedule(), 'overlap', 1)
-    with pytest.raises(ValueError, match='not both'):
-        resolve_schedule(OverlapSchedule(), 'gspmd', 2)
-    with pytest.raises(ValueError, match='tp_impl'):
-        resolve_schedule(None, 'magic', 1)
+def test_resolve_schedule_rejects_another_type():
     with pytest.raises(TypeError, match='OverlapSchedule'):
         resolve_schedule('overlap')
 
@@ -461,8 +453,7 @@ def test_scan_path_accepts_the_schedule():
     threefry's bits depend on the sharding the manual region imposes
     inside the scanned init program, so on a composed fsdp x model mesh
     a schedule-on init that ran the scheduled branch drew different
-    kernels than schedule-off (PR-2's tp_impl knob had the same latent
-    bug); init must always take the nn.Dense path."""
+    kernels than schedule-off; init must always take the nn.Dense path."""
     mesh = composed_mesh()
     tokens = jnp.asarray(
         np.random.default_rng(2).integers(0, 256, (4, 16)), jnp.int32)
@@ -489,15 +480,6 @@ def test_schedule_rejects_unknown_values_at_model_level():
                  dropout=0.0, dtype='float32', schedule='overlap')
     tokens = jnp.zeros((1, 8), jnp.int32)
     with pytest.raises(TypeError, match='OverlapSchedule'):
-        model.init(jax.random.PRNGKey(0), tokens)
-
-
-def test_schedule_with_legacy_knobs_raises_at_model_level():
-    model = GPT2(vocab_size=64, layers=1, dim=32, heads=4, max_seq=32,
-                 dropout=0.0, dtype='float32', tp_impl='overlap',
-                 schedule=OverlapSchedule())
-    tokens = jnp.zeros((1, 8), jnp.int32)
-    with pytest.raises(ValueError, match='not both'):
         model.init(jax.random.PRNGKey(0), tokens)
 
 
@@ -611,9 +593,9 @@ def test_overlap_schedule_validates_the_new_arms():
                                         moe='overlap')
     assert (paired.pp, paired.moe) == ('overlap', 'overlap')
     assert paired.fsdp_min_size == 64
-    # the legacy-knob fold keeps both new arms on gspmd (old behavior)
-    legacy = resolve_schedule(None, 'overlap', 2)
-    assert (legacy.pp, legacy.moe) == ('gspmd', 'gspmd')
+    # the models' default keeps both new arms on gspmd
+    default = resolve_schedule(None)
+    assert (default.pp, default.moe) == ('gspmd', 'gspmd')
 
 
 def test_pp_plan_pins_paths():

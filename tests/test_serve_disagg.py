@@ -42,8 +42,7 @@ from tpusystem.serve import (Engine, HandoffCorrupt, KVHandoff, KVStripStore,
                              kv_namespace, pack_handoff, pool_shardings,
                              unpack_handoff)
 from tpusystem.services.prodcon import Producer
-from tpusystem.train.decode_fused import (fused_paged_reason,
-                                          fused_unsupported_reason)
+from tpusystem.train.decode_fused import fused_paged_reason
 
 
 def submesh(count=2, **axes):
@@ -213,12 +212,6 @@ class TestReasonMatrix:
         assert 'full-capacity' in moe
         assert 'leading layer dim' in fused_paged_reason(
             gpt2_tiny(scan_layers=True))
-
-    def test_fused_generate_gate_points_at_the_paged_step(self):
-        assert 'flax paged step serves MoE' in fused_unsupported_reason(
-            gpt2_tiny(moe_experts=4))
-        assert 'build_fused_paged_step' in fused_unsupported_reason(
-            gpt2_tiny(per_row_decode=True))
 
     def test_tp_mesh_rejection_reason_is_the_planner_text(self, gpt2):
         with pytest.raises(ValueError) as err:

@@ -331,8 +331,7 @@ class MemStore:
 
     Also a valid in-process ``client`` for :func:`hot_resume` (it has the
     same ``fetch`` surface as :class:`MemStoreClient`), which is how the
-    single-process drills and ``bench.py``'s ``recovery_seconds`` probe
-    use it.
+    single-process drills use it.
     """
 
     def __init__(self) -> None:

@@ -1,5 +1,5 @@
-"""GPT-2 language model (flagship of the BASELINE.md workload ladder:
-"GPT-2 125M LM aggregate, GSPMD FSDP" — BASELINE.json configs[3]).
+"""GPT-2 language model (the flagship: the LM aggregate under GSPMD
+FSDP; both cells of ``BENCHMARK.json`` run it).
 
 TPU-first choices: bfloat16 activations with float32 layernorm/softmax/loss,
 weights kept float32 (master copies) and cast per-use; attention through
@@ -23,7 +23,7 @@ from tpusystem.registry import register
 # Megatron TP splits for one transformer block's leaf paths: qkv/fc split
 # columns on `model`, out/proj split rows (their all-reduce rides ICI).
 # Single source for every layout: GPT2.partition_rules uses them plain and
-# shifted past the `hs/` scan dim; GPT2Pipelined feeds them to
+# shifted past the `hs` scan dim; GPT2Pipelined feeds them to
 # PipelineParallel(stacked_rules=...) shifted past the stage dim(s).
 BLOCK_TP_RULES = (
     (r'attn/qkv/kernel$', P(None, 'model')),
@@ -115,20 +115,16 @@ class Block(nn.Module):
     moe_exchange: str = 'quota'
     moe_sparse_impl: str = 'gather'  # single-shard row movement:
     # 'gather' | 'scatter' | 'fused' (Pallas grouped gather-matmul)
-    tp_impl: str = 'gspmd'  # dense-FFN TP collectives: 'gspmd' (monolithic
-    # all-gather/reduce-scatter inserted by the partitioner) | 'overlap'
-    # (decomposed latency-hiding ring matmuls, parallel/overlap.py)
-    tp_chunks: int = 1  # ppermute payload split per overlap ring hop
-    schedule: object = None  # OverlapSchedule composing the TP rings with
-    # FSDP param-prefetch/grad-scatter under one knob
-    # (parallel/schedule.py); None -> built from the legacy
-    # tp_impl=/tp_chunks= pair (fsdp stays on the GSPMD path)
+    schedule: object = None  # OverlapSchedule composing the dense-FFN TP
+    # collectives ('gspmd': monolithic all-gather/reduce-scatter inserted
+    # by the partitioner | 'overlap': decomposed latency-hiding ring
+    # matmuls, parallel/overlap.py) with FSDP param-prefetch/grad-scatter
+    # under one knob (parallel/schedule.py); None -> every axis on GSPMD
 
     @nn.compact
     def __call__(self, hidden, train: bool = False):
         from tpusystem.parallel.schedule import resolve_schedule
-        schedule = resolve_schedule(self.schedule, self.tp_impl,
-                                    self.tp_chunks)
+        schedule = resolve_schedule(self.schedule)
         dim = hidden.shape[-1]
         normed = nn.LayerNorm(dtype=jnp.float32, name='ln_1')(hidden)
         attended = SelfAttention(self.heads, self.dropout, self.dtype,
@@ -241,8 +237,6 @@ class BlockSpan(nn.Module):
     moe_exchange: str = 'quota'
     moe_sparse_impl: str = 'gather'  # single-shard row movement:
     # 'gather' | 'scatter' | 'fused' (Pallas grouped gather-matmul)
-    tp_impl: str = 'gspmd'  # dense-FFN TP collectives: 'gspmd' | 'overlap'
-    tp_chunks: int = 1
     schedule: object = None  # OverlapSchedule (see Block.schedule)
 
     @nn.compact
@@ -252,7 +246,6 @@ class BlockSpan(nn.Module):
                       max_seq=self.max_seq,
                       per_row_decode=self.per_row_decode,
                       decode_pages=self.decode_pages,
-                      tp_impl=self.tp_impl, tp_chunks=self.tp_chunks,
                       schedule=self.schedule)
         if self.moe_experts and self.span % self.moe_every:
             raise ValueError(f'span ({self.span}) must be a multiple of '
@@ -330,17 +323,15 @@ class GPT2(nn.Module):
     # | 'ragged-emulated' (see tpusystem.ops.moe.MoEMLP)
     moe_sparse_impl: str = 'gather'  # single-shard row movement:
     # 'gather' | 'scatter' | 'fused' (Pallas grouped gather-matmul)
-    tp_impl: str = 'gspmd'  # dense-FFN TP collectives: 'gspmd' (monolithic
-    # partitioner-inserted all-gather/reduce-scatter) | 'overlap'
-    # (decomposed latency-hiding ring matmuls — parallel/overlap.py;
-    # needs a mesh with model > 1, falls back per-shape otherwise)
-    tp_chunks: int = 1  # ppermute payload split per overlap ring hop
     schedule: object = None  # parallel.OverlapSchedule: ONE knob composing
-    # the TP rings (tp='overlap') with FSDP param-prefetch/grad-scatter
-    # hiding (fsdp='prefetch') and their shared ppermute chunking; None
-    # keeps the legacy tp_impl=/tp_chunks= behavior (fsdp on GSPMD).
-    # Purely an implementation schedule — param trees and checkpoints are
-    # bitwise knob-invariant
+    # the dense-FFN TP collectives (tp='gspmd': monolithic
+    # partitioner-inserted all-gather/reduce-scatter | tp='overlap':
+    # decomposed latency-hiding ring matmuls — parallel/overlap.py; needs
+    # a mesh with model > 1, falls back per-shape otherwise) with FSDP
+    # param-prefetch/grad-scatter hiding (fsdp='prefetch') and their
+    # shared ppermute chunking; None keeps every axis on GSPMD. Purely an
+    # implementation schedule — param trees and checkpoints are bitwise
+    # knob-invariant
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
@@ -379,7 +370,6 @@ class GPT2(nn.Module):
                           decode=self.decode, max_seq=self.max_seq,
                           per_row_decode=self.per_row_decode,
                           decode_pages=self.decode_pages,
-                          tp_impl=self.tp_impl, tp_chunks=self.tp_chunks,
                           schedule=self.schedule)
             from tpusystem.parallel.mesh import scan_carry_constraint
             constrain = scan_carry_constraint(self.mesh)
@@ -458,8 +448,6 @@ class GPT2(nn.Module):
                                   moe_capacity_factor=self.moe_capacity_factor,
                                   moe_exchange=self.moe_exchange,
                                   moe_sparse_impl=self.moe_sparse_impl,
-                                  tp_impl=self.tp_impl,
-                                  tp_chunks=self.tp_chunks,
                                   schedule=self.schedule,
                                   name=f'h_{index}')
                 result = block(hidden, train)
@@ -507,7 +495,7 @@ class GPT2(nn.Module):
 
         qkv/fc split columns on ``model``; out/proj split rows (their
         all-reduce rides ICI); embeddings split the vocab/position table.
-        The ``hs/`` rules cover the ``scan_layers`` stacked variant (same
+        The ``hs`` rules cover the ``scan_layers`` stacked variant (same
         splits shifted one dim right past the leading layer axis).
         """
         from tpusystem.ops.moe import moe_partition_rules

@@ -1,5 +1,5 @@
-"""Llama-3 language model family (BASELINE.md workload ladder #5:
-"Llama-3 8B aggregate, sharded Service.handler" — BASELINE.json configs[4]).
+"""Llama-3 language model family (the 8B aggregate behind a sharded
+``Service.handler``).
 
 The reference framework ships only an MNIST MLP (SURVEY.md §2.2,
 ``examples/tinysys/modules/mlp.py``); the 8B-scale decoder family is part of
@@ -162,18 +162,16 @@ class LlamaBlock(nn.Module):
     max_seq: int = 8192
     per_row_decode: bool = False
     decode_pages: tuple | None = None  # paged KV pool (see LlamaAttention)
-    tp_impl: str = 'gspmd'  # SwiGLU TP collectives: 'gspmd' | 'overlap'
-    tp_chunks: int = 1
-    schedule: object = None  # parallel.OverlapSchedule composing TP rings
-    # with FSDP prefetch (see gpt2.Block.schedule); None -> legacy knobs
+    schedule: object = None  # parallel.OverlapSchedule composing the
+    # SwiGLU TP collectives ('gspmd' | 'overlap' rings) with FSDP prefetch
+    # (see gpt2.Block.schedule); None -> every axis on GSPMD
 
     @nn.compact
     def __call__(self, hidden, train: bool = False):
         from tpusystem.parallel.schedule import (resolve_schedule,
                                                  schedule_applicable,
                                                  scheduled_swiglu)
-        schedule = resolve_schedule(self.schedule, self.tp_impl,
-                                    self.tp_chunks)
+        schedule = resolve_schedule(self.schedule)
         dim = hidden.shape[-1]
         normed = RMSNorm(name='attn_norm')(hidden)
         hidden = hidden + LlamaAttention(
@@ -237,8 +235,6 @@ class LlamaBlockSpan(nn.Module):
     max_seq: int = 8192
     per_row_decode: bool = False
     decode_pages: tuple | None = None  # paged KV pool (see LlamaAttention)
-    tp_impl: str = 'gspmd'
-    tp_chunks: int = 1
     schedule: object = None  # OverlapSchedule (see LlamaBlock.schedule)
 
     @nn.compact
@@ -250,8 +246,6 @@ class LlamaBlockSpan(nn.Module):
                                 decode=self.decode, max_seq=self.max_seq,
                                 per_row_decode=self.per_row_decode,
                                 decode_pages=self.decode_pages,
-                                tp_impl=self.tp_impl,
-                                tp_chunks=self.tp_chunks,
                                 schedule=self.schedule,
                                 name=f'd_{index}')(hidden, train)
         return hidden
@@ -296,17 +290,16 @@ class Llama(nn.Module):
     decode_pages: tuple | None = None  # (num_blocks, block_size): paged
     # block-pool KV cache with per-row block tables — the serving
     # engine's layout (tpusystem.serve; ops.attention.paged_attention)
-    tp_impl: str = 'gspmd'  # SwiGLU TP collectives: 'gspmd' (monolithic
-    # partitioner-inserted all-gather/reduce-scatter) | 'overlap'
-    # (decomposed latency-hiding ring matmuls — parallel/overlap.py;
-    # needs a mesh with model > 1, falls back per-shape otherwise)
-    tp_chunks: int = 1  # ppermute payload split per overlap ring hop
     schedule: object = None  # parallel.OverlapSchedule: ONE knob composing
-    # the TP rings with FSDP param-prefetch/grad-scatter hiding (see
-    # gpt2.GPT2.schedule); None keeps the legacy tp_impl=/tp_chunks=
-    # behavior. The pp=/moe= arms ride the same object but are inert in
-    # this family (no pipelined/MoE Llama variant yet — pass the one
-    # schedule everywhere and each model consumes the arms it has).
+    # the SwiGLU TP collectives (tp='gspmd': monolithic
+    # partitioner-inserted all-gather/reduce-scatter | tp='overlap':
+    # decomposed latency-hiding ring matmuls — parallel/overlap.py; needs
+    # a mesh with model > 1, falls back per-shape otherwise) with FSDP
+    # param-prefetch/grad-scatter hiding (see gpt2.GPT2.schedule); None
+    # keeps every axis on GSPMD. The pp=/moe= arms ride the same object
+    # but are inert in this family (no pipelined/MoE Llama variant yet —
+    # pass the one schedule everywhere and each model consumes the arms
+    # it has).
     # Param trees and checkpoints are bitwise knob-invariant
 
     @nn.compact
@@ -339,8 +332,6 @@ class Llama(nn.Module):
                                     max_seq=self.max_seq,
                                     per_row_decode=self.per_row_decode,
                                     decode_pages=self.decode_pages,
-                                    tp_impl=self.tp_impl,
-                                    tp_chunks=self.tp_chunks,
                                     schedule=self.schedule,
                                     name='blocks')
                 length = self.layers // self.scan_unit
@@ -353,8 +344,6 @@ class Llama(nn.Module):
                                      max_seq=self.max_seq,
                                      per_row_decode=self.per_row_decode,
                                      decode_pages=self.decode_pages,
-                                     tp_impl=self.tp_impl,
-                                     tp_chunks=self.tp_chunks,
                                      schedule=self.schedule,
                                      name='blocks')
                 length = self.layers
@@ -375,8 +364,6 @@ class Llama(nn.Module):
                                    decode=self.decode, max_seq=self.max_seq,
                                    per_row_decode=self.per_row_decode,
                                    decode_pages=self.decode_pages,
-                                   tp_impl=self.tp_impl,
-                                   tp_chunks=self.tp_chunks,
                                    schedule=self.schedule,
                                    name=f'layer_{index}')(hidden, train)
         hidden = RMSNorm(name='final_norm')(hidden)
@@ -394,7 +381,7 @@ class Llama(nn.Module):
     def partition_rules():
         """Megatron-style TP rules: q/k/v/gate/up split columns on ``model``;
         out/down split rows (their all-reduce rides ICI); embedding and head
-        split the vocab dimension. The ``blocks/`` rules cover the
+        split the vocab dimension. The ``blocks`` rules cover the
         ``scan_layers`` stacked variant (same splits shifted one dim right
         past the leading layer axis)."""
         return (

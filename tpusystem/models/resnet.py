@@ -1,5 +1,4 @@
-"""ResNet family (BASELINE.md workload ladder #3: "ResNet-50/ImageNet
-aggregate via compiler→XLA" — BASELINE.json configs[2]).
+"""ResNet family (the ResNet-50/ImageNet aggregate, compiler → XLA).
 
 The reference ships only an MNIST MLP (``examples/tinysys/modules/mlp.py``,
 SURVEY.md §2.2); the CNN family is part of the capability ladder this

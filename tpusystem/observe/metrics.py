@@ -23,8 +23,7 @@ histogram style):
 :func:`serve_metrics_consumer` feeds the three headline distributions —
 TTFT, per-token decode seconds, recovery seconds — from the events the
 serving/fleet/supervisor layers already dispatch, and charts
-p50/p95/p99 to TensorBoard. ``bench.py`` prints the same percentiles as
-the ``serve_ttft_p50_p99`` row.
+p50/p95/p99 to TensorBoard.
 """
 
 from __future__ import annotations

@@ -16,8 +16,7 @@ Design rules, inherited from the rest of the framework:
   on fake clocks with zero real sleeps.
 * **Off by default, zero cost off** — every instrumented subsystem takes
   ``tracer=None`` and guards with one ``is not None`` check; a disabled
-  tracer adds no per-tick host sync and no allocation (the
-  ``trace_overhead`` bench row pins the budget).
+  tracer adds no per-tick host sync and no allocation.
 * **Causal identity travels with the work** — a :class:`TraceContext`
   ``(trace_id, parent span id)`` rides the :class:`~tpusystem.serve.
   Request` itself, so the journal packs it for free and a replayed or

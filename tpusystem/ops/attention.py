@@ -326,8 +326,7 @@ def cached_attention(module, query, key, value, max_seq: int,
     # attend causally over the filled prefix, per row (key position <=
     # row cursor + query offset). The cache is allocated max_seq wide,
     # but reading all of it every step makes decode cost scale with
-    # *capacity*, not fill: at 125M/batch 8 the full-width read is ~2.3
-    # of the 3.4 ms step at max_seq 1024 (benchmarks/decode_roofline.py).
+    # *capacity*, not fill.
     # Bucketed attention reads only the smallest power-of-2 window
     # covering the filled prefix — lax.switch over static slice widths,
     # so shapes stay static per branch inside one compiled program.

@@ -235,9 +235,7 @@ def _invert_seating(slots, k: int, tokens: int, buffer_rows: int):
     row -> assignment (``slot_asg``, ``k*tokens`` sentinel for empty),
     buffer row -> token (``slot_token``, ``tokens`` sentinel;
     ``token_ids[a] = a % tokens`` by route_top_k_sparse's choice-major
-    layout), and the per-choice ``[k, tokens]`` view of ``slots``. Shared
-    with benchmarks/moe_ceiling.py so the benchmark measures exactly the
-    dispatch MoEMLP executes."""
+    layout), and the per-choice ``[k, tokens]`` view of ``slots``."""
     assignments = k * tokens
     slot_asg = jnp.full((buffer_rows,), assignments,
                         jnp.int32).at[slots].set(
@@ -498,7 +496,7 @@ class MoEMLP(nn.Module):
     # through the scatter-free custom_vjp pair (_gather_dispatch /
     # _gather_combine — gathers + k-way sums in both directions, one tiny
     # int scatter to invert the seating); 'scatter' is the row-scatter
-    # formulation (the A/B reference; benchmarks/moe_ceiling.py); 'fused'
+    # formulation (the A/B reference); 'fused'
     # folds dispatch into the up-projection's loads and the weighted
     # combine into the down-projection's epilogue with the Pallas grouped
     # gather-matmul kernels (_fused_moe — megablocks-style; bitwise

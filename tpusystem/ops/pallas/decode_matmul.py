@@ -2,8 +2,7 @@
 
 One greedy-decode token-step at small batch is weight-streaming bound:
 the ``[B, dim]`` activation is a few KB while every matrix param crosses
-HBM once per step (benchmarks/decode_roofline.py: the 125M chain streams
-~250 MB/step at f32). These kernels attack the two byte levers at once:
+HBM once per step. These kernels attack the two byte levers at once:
 
 * :func:`decode_matmul` — one fused dequantize-matmul. The activation
   block is **resident in VMEM for the whole weight sweep** (its index

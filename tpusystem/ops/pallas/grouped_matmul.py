@@ -1,10 +1,9 @@
 """Grouped gather-matmul — Pallas TPU kernels for fused MoE dispatch/combine.
 
 The megablocks insight (Gale et al., 2022) applied to this repo's MoE
-decomposition: expert matmuls run at 0.806 MFU while dispatch/combine are
-pure HBM row traffic the MXU idles through (BASELINE.md round-5 phase
-table). These kernels make the data movement ride the matmuls instead of
-preceding/following them:
+decomposition: the expert matmuls keep the MXU busy while dispatch/combine
+are pure HBM row traffic the MXU idles through. These kernels make the
+data movement ride the matmuls instead of preceding/following them:
 
 * :func:`gather_rows_matmul` — the **dispatch direction**. For each expert
   the kernel walks that expert's seating indices (scalar-prefetched) and

@@ -9,9 +9,9 @@ Two rule sets live here:
   untied head, and the fused chunked loss stay numerically in lockstep.
 
 * **Streamed quantization** (decode): small-batch decode is weight-
-  STREAMING bound (benchmarks/decode_roofline.py), so the lever is HBM
-  bytes per step. :func:`quantize_streamed` rounds the decoder's matrix
-  params to int8/fp8 with **per-output-channel symmetric scales**
+  STREAMING bound, so the lever is HBM bytes per step.
+  :func:`quantize_streamed` rounds the decoder's matrix params to
+  int8/fp8 with **per-output-channel symmetric scales**
   computed once at stream time; :func:`qdot` is the matching matmul —
   the scale is a per-column constant, so it factors out of the
   contraction exactly (``x @ (q * s) == (x @ q) * s``) and is applied

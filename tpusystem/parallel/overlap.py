@@ -39,7 +39,8 @@ helpers so tests can pin which path a shape takes.
 Model wiring: :func:`tp_ffn` (bias + activation, GPT-2) and
 :func:`tp_swiglu` (gate/up fused into ONE ring, Llama) shard_map a whole
 sequence-sharded FFN over the mesh; the model families expose them behind
-``tp_impl='overlap' | 'gspmd'`` (threaded like ``moe_sparse_impl``).
+``schedule=OverlapSchedule(tp='overlap' | 'gspmd')``
+(:mod:`tpusystem.parallel.schedule`).
 Everything here is called *inside* ``shard_map`` except those two
 wrappers, which build it.
 """
@@ -287,7 +288,7 @@ def matmul_reducescatter(x, w, axis: str = MODEL, *, chunks: int = 1):
 
 
 # ---------------------------------------------------------------------------
-# Model wiring: sequence-sharded FFN behind the ``tp_impl`` knob
+# Model wiring: sequence-sharded FFN behind ``OverlapSchedule(tp=)``
 # ---------------------------------------------------------------------------
 
 
@@ -305,7 +306,7 @@ def _define_dense_params():
         initializers), but retrievable so the overlap path can run the
         matmul through the decomposed collectives. A model may init
         through ``nn.Dense`` and apply through this holder (or vice
-        versa): the param trees are identical, so ``tp_impl`` never
+        versa): the param trees are identical, so the schedule never
         changes a checkpoint."""
 
         features: int

@@ -10,8 +10,7 @@ implements its first two big clients:
 * **TP rings** (``tp='overlap'``): the existing
   :func:`~tpusystem.parallel.overlap.allgather_matmul` /
   :func:`~tpusystem.parallel.overlap.matmul_reducescatter` decompositions,
-  unchanged semantics, now selected by the schedule instead of a
-  per-model ``tp_impl=`` string.
+  unchanged semantics, selected by the schedule.
 * **FSDP prefetch** (``fsdp='prefetch'``): GSPMD lowers a ZeRO-3 layer to
   a *monolithic* parameter all-gather on the critical path of every block
   and a *monolithic* gradient reduce-scatter on its backward. Here the
@@ -78,9 +77,8 @@ Model wiring: GPT-2 and Llama accept ``schedule=OverlapSchedule(...)``
 (threaded through ``Block``/``BlockSpan`` and the Llama twins, scan and
 unrolled paths; ``GPT2Pipelined`` threads ``pp=`` into the GPipe loop
 and ``moe=`` reaches :class:`~tpusystem.ops.moe.MoEMLP` through the
-block plumbing); :func:`resolve_schedule` folds the legacy
-``tp_impl=``/``tp_chunks=`` pair into the same object so existing
-configs keep working. Param trees are built from the same
+block plumbing); :func:`resolve_schedule` turns ``schedule=None`` into
+the all-GSPMD default. Param trees are built from the same
 ``DenseParams`` holders either way — the knob never changes a
 checkpoint.
 """
@@ -199,27 +197,15 @@ class OverlapSchedule:
                    fsdp_min_size=policy.fsdp_min_size, pp=pp, moe=moe)
 
 
-def resolve_schedule(schedule, tp_impl: str = 'gspmd',
-                     tp_chunks: int = 1) -> OverlapSchedule:
-    """The models' knob seam: one :class:`OverlapSchedule` from either the
-    ``schedule=`` object or the legacy ``tp_impl=``/``tp_chunks=`` pair.
-
-    ``schedule=None`` folds the legacy pair into an equivalent schedule
-    (``fsdp='gspmd'`` — exactly the old behavior); passing both a
-    schedule and non-default legacy knobs raises, so a config can never
-    silently say two different things.
-    """
-    if tp_impl not in ('gspmd', 'overlap'):
-        raise ValueError(f'unknown tp_impl {tp_impl!r}; '
-                         "expected 'gspmd' or 'overlap'")
+def resolve_schedule(schedule) -> OverlapSchedule:
+    """The models' knob seam: ``schedule=None`` is the default
+    :class:`OverlapSchedule` (every axis on GSPMD); anything else must
+    be an :class:`OverlapSchedule`."""
     if schedule is None:
-        return OverlapSchedule(tp=tp_impl, chunks=tp_chunks)
+        return OverlapSchedule()
     if not isinstance(schedule, OverlapSchedule):
         raise TypeError('schedule= expects an OverlapSchedule, got '
                         f'{type(schedule).__name__}')
-    if tp_impl != 'gspmd' or tp_chunks != 1:
-        raise ValueError('pass schedule= or the legacy tp_impl=/tp_chunks= '
-                         'knobs, not both')
     return schedule
 
 
@@ -528,9 +514,9 @@ def schedule_applicable(schedule: OverlapSchedule, mesh, hidden_shape,
 
     True when the schedule decomposes at least one collective family the
     shape supports: TP rings per
-    :func:`~tpusystem.parallel.overlap.overlap_applicable` (unchanged
-    from the ``tp_impl`` era), or FSDP prefetch when the fsdp axis is
-    non-trivial AND the batch genuinely shards over ``(data, fsdp)``.
+    :func:`~tpusystem.parallel.overlap.overlap_applicable`, or FSDP
+    prefetch when the fsdp axis is non-trivial AND the batch genuinely
+    shards over ``(data, fsdp)``.
     Shapes that qualify for neither fall back to the GSPMD Dense path
     per call site — same params, so the fallback never changes a tree.
     """

@@ -22,8 +22,7 @@ the transfer is end-to-end verified even on transports that do not
 chunk-verify (the in-process :class:`~tpusystem.parallel.multihost.Loopback`);
 :exc:`HandoffCorrupt` is the typed failure.
 
-docs/serving.md "Disaggregated prefill/decode" records the protocol and
-the head-of-line-blocking measurement (``benchmarks/serve_disagg.py``).
+docs/serving.md "Disaggregated prefill/decode" records the protocol.
 """
 
 from __future__ import annotations

@@ -161,7 +161,7 @@ class SamplingParams:
 
 def engine_unsupported_reason(module) -> str | None:
     """None when the paged engine can serve this module, else why not
-    (the ``fused_unsupported_reason`` capability-gate discipline).
+    (the ``fused_paged_reason`` capability-gate discipline).
 
     Served today: both family LMs (GPT2 / Llama, unrolled), including
     **MoE** stacks — decode-mode expert dispatch runs at full capacity
@@ -527,8 +527,8 @@ class Engine:
         self._resumes: dict[int, object] = {}
         self.trace_count = 0
         self.timings = {'prefill': 0.0, 'admit': 0.0, 'step': 0.0}
-        # prefix-sharing effectiveness counters (the bench's
-        # prefix_hit_rate reads these)
+        # prefix-sharing effectiveness counters (chipbench's serving
+        # driver reads these)
         self.sharing = {'admissions': 0, 'prefix_hits': 0,
                         'prompt_tokens': 0, 'shared_tokens': 0,
                         'resumed_prefills': 0}

@@ -268,7 +268,7 @@ class RequestJournal:
             if push is not None:
                 ok = bool(push(journal_identity(self.identity), self.tick,
                                packed))
-            else:             # bare MemStore (in-process drills, bench)
+            else:             # bare MemStore (in-process drills)
                 self.client.put(journal_identity(self.identity), self.tick,
                                 packed)
                 ok = True
@@ -411,7 +411,7 @@ class RouterJournal:
             push = getattr(self.client, 'push', None)
             if push is not None:
                 ok = bool(push(self.identity, step, packed))
-            else:             # bare MemStore (in-process drills, bench)
+            else:             # bare MemStore (in-process drills)
                 self.client.put(self.identity, step, packed)
                 ok = True
         except (OSError, ValueError) as error:

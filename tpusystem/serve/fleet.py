@@ -218,7 +218,7 @@ class RouterLease:
             push = getattr(self.client, 'push', None)
             if push is not None:
                 ok = bool(push(self.identity, step, self._pack()))
-            else:             # bare MemStore (in-process drills, bench)
+            else:             # bare MemStore (in-process drills)
                 self.client.put(self.identity, step, self._pack())
                 ok = True
         except (OSError, ValueError):
@@ -580,7 +580,7 @@ class FleetTick:
     # request ids whose KV strips moved prefill -> decode this tick
     emitted: dict = dataclasses.field(default_factory=dict)
     # request id -> list of tokens, merged across the replicas' ticks —
-    # what the fleet delivered this step (the recovery bench watches it
+    # what the fleet delivered this step (a recovery drill watches it
     # for the first post-handoff token; speculative replicas can land
     # several tokens per request per tick)
 

@@ -44,7 +44,7 @@ from tpusystem.serve.failover import RequestJournal, Watermarks  # noqa: F401
 def serve_levers() -> dict:
     """The default engine levers for serving on this backend: int8
     weight streaming on TPU (decode there is weight-streaming bound —
-    half the bytes per step vs bf16, ``benchmarks/decode_roofline.py``),
+    half the bytes per step vs bf16),
     'auto' elsewhere (CPU decode is compute-bound and f32 keeps the
     engine token-exact against the f32 reference). The engine now
     carries the whole PR-7 lever set natively: ``decode_impl='auto'``

@@ -1,46 +1,47 @@
-"""The fused decode loop — ``generate(decode_impl='fused')``.
+"""The serving engine's fused decode step over the paged KV pool.
 
 The flax decode path dispatches ~6 XLA ops per matrix param per token
-step and streams every weight at the tree's storage width. This module
-is the serving-path alternative: one hand-rolled GPT-2 token-step whose
-four per-layer matmuls run through the Pallas decode kernels
-(:mod:`tpusystem.ops.pallas.decode_matmul`) — the ``[B, dim]``
+step and streams every weight at the tree's storage width.
+:func:`build_fused_paged_step` is the alternative
+:class:`tpusystem.serve.Engine` runs under ``decode_impl='fused'``: one
+hand-rolled GPT-2 ``[rows, 1]`` token-step whose four per-layer matmuls
+run through the Pallas decode kernels
+(:mod:`tpusystem.ops.pallas.decode_matmul`) — the ``[rows, dim]``
 activation resident in VMEM, weights streamed tile-by-tile, int8/fp8
 tiles dequantized in-kernel against their per-channel scales (so
 ``stream_dtype='int8'|'fp8'`` keeps its narrow HBM traffic inside the
-compiled loop instead of being hoisted into a wide copy), and the
-fc→gelu→proj pair fused into ONE kernel whose hidden activation never
-exists in HBM.
-
-Contract: **the same tokens as the flax path.** The step math mirrors
-``GPT2.__call__`` in decode mode op for op — f32 layernorms (flax
-fast-variance form), the bucketed cache read of
-:func:`tpusystem.ops.attention.cached_attention` (smallest power-of-2
-window covering the filled prefix, ``lax.switch`` over static widths),
-f32-accumulated matmuls, the tied f32-logit head — and prefill runs
-through the flax module itself, so the cache layout and prompt logits
-are the flax path's own. Greedy decode is token-exact against
-``decode_impl='flax'`` in window-invariant arithmetic (CPU f32; TPU at
-``jax_default_matmul_precision='highest'``) and matches within the
-platform's near-tie argmax tolerance at default MXU precision —
-the speculative-verify caveat, same cause.
-
-The serving engine's per-row step (:func:`build_fused_paged_step`) is
-the same chain over the PAGED pool, and its cache read is not bucketed:
-one Pallas kernel
+step instead of being hoisted into a wide copy), and the fc→gelu→proj
+pair fused into ONE kernel whose hidden activation never exists in HBM.
+Its cache read is one Pallas kernel
 (:func:`tpusystem.ops.pallas.paged_attention.paged_decode_attention`)
-walks each row's own block-table columns up to its cursor and attends
-over the pool where it lies — no window gather, no ``lax.switch`` — and
-the step's K/V scatter updates the donated pool in place.
+that walks each row's own block-table columns up to its cursor and
+attends over the pool where it lies — no window gather, no
+``lax.switch`` — and the step's K/V scatter updates the donated pool in
+place.
 
-Scope: the unrolled dense GPT-2 family (``fused_unsupported_reason``
-names the exact gate). Llama/MoE/scanned stacks fall back to the flax
-path under ``decode_impl='auto'`` and raise under an explicit
-``'fused'``.
+Contract: **the same tokens as the engine's flax paged step.** The step
+math mirrors ``GPT2.__call__`` in decode mode op for op — f32 layernorms
+(flax fast-variance form), f32-accumulated matmuls, the tied f32-logit
+head — and prefill runs through the flax module itself, so the cache
+layout and prompt logits are the flax path's own. Greedy decode is
+token-exact against ``Engine(decode_impl='flax')`` in window-invariant
+arithmetic (CPU f32; TPU at ``jax_default_matmul_precision='highest'``)
+and matches within the platform's near-tie argmax tolerance at default
+MXU precision — the speculative-verify caveat, same cause.
+
+Scopes: the XLA work between the kernels runs under ``embed``, ``ln``,
+``kv_write``, ``kv_read`` and ``head`` (``jax.named_scope``), the names
+a device trace is read by.
+
+Gate: the unrolled dense GPT-2 family on one device, with a pool the
+paged-attention kernel can tile (:func:`fused_paged_reason` names the
+exact refusal). Llama/MoE/scanned stacks and TP meshes serve through
+the flax paged step under ``Engine(decode_impl='auto')`` and raise under
+an explicit ``'fused'``.
 
 Sampling: the fused step's contract ends at the logits it exposes —
 :class:`tpusystem.serve.Engine` applies
-:func:`tpusystem.train.generate.sample_token` (seeded counter-based
+:func:`tpusystem.train.generate.select_tokens` (seeded counter-based
 sampling, temperature/top-k/top-p, grammar masks) to those logits
 inside the SAME jitted program, so sampled decode through the fused
 chain needs no gate here and stays bitwise-identical to the flax step's
@@ -49,54 +50,23 @@ sampled stream wherever greedy is token-exact.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
-from tpusystem.ops.attention import NEG_INF
 from tpusystem.ops.pallas import auto_interpret
 from tpusystem.ops.pallas.decode_matmul import decode_ffn, decode_matmul
 from tpusystem.ops.pallas.paged_attention import (paged_decode_attention,
                                                   paged_plan)
-from tpusystem.ops.precision import dequantize_streamed, head_logits
-
-
-def fused_unsupported_reason(decoder) -> str | None:
-    """Why ``decode_impl='fused'`` cannot run this decode clone, or
-    ``None`` when it can. The fused step re-implements the GPT-2 dense
-    token-step; anything whose step math differs falls back. Scope as of
-    the serving-engine integration: unrolled dense GPT-2 runs fused here
-    AND inside :class:`tpusystem.serve.Engine` (whose paged per-row step
-    is :func:`build_fused_paged_step` — ``fused_paged_reason`` is its
-    gate); MoE now serves through the engine's flax paged step (full-
-    capacity decode dispatch), just not through this FFN chain."""
-    from tpusystem.models.gpt2 import GPT2
-    if not isinstance(decoder, GPT2):
-        return ("the fused decode step implements the GPT2 family only "
-                f"(got {type(decoder).__name__})")
-    if decoder.scan_layers:
-        return ('scan_layers stacks params under a leading layer dim the '
-                'fused per-layer sweep does not walk')
-    if decoder.moe_experts:
-        return ('MoE blocks route through expert dispatch, not the FFN '
-                "chain — the serving engine's flax paged step serves MoE; "
-                "this fused chain does not")
-    if decoder.per_row_decode:
-        return ('per-row cache cursors need the scatter cache write — '
-                "generate()'s fused loop is shared-cursor only; the "
-                "serving engine's fused PAGED step (build_fused_paged_step) "
-                'is the per-row implementation')
-    return None
+from tpusystem.ops.precision import head_logits
 
 
 def fused_paged_reason(decoder) -> str | None:
     """Why the serving engine's fused PAGED token-step
     (:func:`build_fused_paged_step`) cannot run this decode clone, or
-    ``None`` when it can. Unlike :func:`fused_unsupported_reason`, the
-    paged step OWNS per-row cursors and the block-table scatter write —
-    the gates left are the step-math ones (GPT-2 dense, unrolled) and
-    the TP mesh (no ring arms yet)."""
+    ``None`` when it can. The paged step owns per-row cursors and the
+    block-table scatter write — the gates are the step-math ones (GPT-2
+    dense, unrolled), the TP mesh (no ring arms yet) and the pool shapes
+    the paged-attention kernel can tile."""
     from tpusystem.models.gpt2 import GPT2
     mesh = getattr(decoder, 'mesh', None)
     if mesh is not None and dict(getattr(mesh, 'shape', {})).get(
@@ -140,41 +110,9 @@ def _layernorm(x, scale, bias):
     return (x - mean) * inv * scale + bias
 
 
-def _bucketed_attention(query, key_cache, value_cache, cursor, max_seq: int):
-    """One-token bucketed cache attention — ``cached_attention``'s read
-    path (same buckets, same mask, same f32 softmax) for ``[B, H, hd]``
-    queries against ``[B, S, H, hd]`` caches at per-row depth ``cursor``."""
-    compute = query.dtype
-    head_dim = query.shape[-1]
-    scale = head_dim ** -0.5
-
-    def attend_over(width: int):
-        def run():
-            keys = jax.lax.slice_in_dim(key_cache, 0, width, axis=1)
-            values = jax.lax.slice_in_dim(value_cache, 0, width, axis=1)
-            scores = jnp.einsum('bhd,bwhd->bhw', query, keys,
-                                preferred_element_type=jnp.float32) * scale
-            mask = jnp.arange(width)[None, None, :] <= cursor[:, None, None]
-            scores = jnp.where(mask, scores, NEG_INF)
-            weights = jax.nn.softmax(scores, axis=-1)
-            return jnp.einsum('bhw,bwhd->bhd', weights.astype(compute),
-                              values)
-        return run
-
-    buckets = [256]
-    while buckets[-1] < max_seq:
-        buckets.append(min(2 * buckets[-1], max_seq))
-    if len(buckets) == 1:
-        return attend_over(max_seq)()
-    filled = jnp.max(cursor) + 1
-    bucket_index = sum((filled > width).astype(jnp.int32)
-                       for width in buckets[:-1])
-    return jax.lax.switch(bucket_index, [attend_over(w) for w in buckets])
-
-
 def build_fused_paged_step(decoder):
     """The serving engine's fused ``[rows, 1]`` token-step over the
-    paged KV pool: the :func:`build_fused` step math (Pallas
+    paged KV pool: the GPT-2 layer chain (Pallas
     ``decode_matmul``/``decode_ffn``, in-kernel int8/fp8 dequant, f32
     layernorms, tied f32-logit head) with per-row cursors, the
     block-table scatter write (in place on the donated pool), and the
@@ -189,7 +127,7 @@ def build_fused_paged_step(decoder):
     model-level ``position``); cursor leaves in the returned cache are
     the input's — the engine's post-step ``rewind`` owns advancement.
     Token-exact vs the flax paged step in window-length-invariant
-    arithmetic (the contiguous fused loop's contract).
+    arithmetic.
 
     The XLA work between the kernels carries ``jax.named_scope`` names a
     device trace is read by (``embed``, ``ln``, ``kv_write``, ``head``),
@@ -276,99 +214,3 @@ def build_fused_paged_step(decoder):
         return logits, jax.tree_util.tree_map_with_path(fix, cache)
 
     return step
-
-
-@functools.cache
-def compiled_fused(decoder, steps: int, temperature: float):
-    return build_fused(decoder, steps, temperature)
-
-
-def build_fused(decoder, steps: int, temperature: float):
-    """The fused greedy/sampling decode runner: flax prefill, then
-    ``steps - 1`` fused token-steps under ``lax.scan``. Accepts plain,
-    pre-cast, or quantized param trees (the flax prefill consumes a
-    dequantized view; the scan streams the tree as passed)."""
-    from tpusystem.train.generate import _sample
-    layers, heads = decoder.layers, decoder.heads
-    dim, max_seq = decoder.dim, decoder.max_seq
-    head_dim = dim // heads
-    compute = jnp.dtype(decoder.dtype)
-
-    def token_step(params, k_caches, v_caches, cursor, token):
-        wide = token.shape[0]
-        start = cursor[0]      # ordinary decode: uniform cursor contract
-        wte = params['wte']['embedding']
-        wpe = params['wpe']['embedding']
-        embedded = (jnp.asarray(wte)[token].astype(jnp.float32)
-                    + jnp.asarray(wpe)[cursor].astype(jnp.float32))
-        hidden = embedded.astype(compute)
-        new_k, new_v = [], []
-        for index in range(layers):
-            block = params[f'h_{index}']
-            normed = _layernorm(hidden, block['ln_1']['scale'],
-                                block['ln_1']['bias']).astype(compute)
-            attn = block['attn']
-            qkv = decode_matmul(normed, attn['qkv']['kernel'],
-                                attn['qkv']['bias'])
-            query, key, value = jnp.split(qkv, 3, axis=-1)
-            shape = (wide, heads, head_dim)
-            query = query.reshape(shape)
-            key_cache = jax.lax.dynamic_update_slice(
-                k_caches[index],
-                key.reshape((wide, 1) + shape[1:]).astype(
-                    k_caches[index].dtype), (0, start, 0, 0))
-            value_cache = jax.lax.dynamic_update_slice(
-                v_caches[index],
-                value.reshape((wide, 1) + shape[1:]).astype(
-                    v_caches[index].dtype), (0, start, 0, 0))
-            new_k.append(key_cache)
-            new_v.append(value_cache)
-            context = _bucketed_attention(query, key_cache, value_cache,
-                                          cursor, max_seq)
-            attended = decode_matmul(context.reshape(wide, dim),
-                                     attn['out']['kernel'],
-                                     attn['out']['bias'])
-            hidden = hidden + attended
-            normed = _layernorm(hidden, block['ln_2']['scale'],
-                                block['ln_2']['bias']).astype(compute)
-            hidden = hidden + decode_ffn(
-                normed, block['fc']['kernel'], block['fc']['bias'],
-                block['proj']['kernel'], block['proj']['bias'],
-                activation=jax.nn.gelu)
-        final = _layernorm(hidden, params['ln_f']['scale'],
-                           params['ln_f']['bias'])
-        table = jnp.asarray(wte).astype(compute)
-        logits = head_logits(final.astype(compute), table, tied=True)
-        return logits, tuple(new_k), tuple(new_v)
-
-    @jax.jit
-    def run(params, prompt, rng):
-        # prefill through the flax module itself: identical prompt
-        # logits and cache layout, flash long-prompt routing included
-        plain = dequantize_streamed(params, compute)
-        logits, state = decoder.apply({'params': plain}, prompt,
-                                      mutable=['cache'])
-        cache = state['cache']
-        k_caches = tuple(cache[f'h_{i}']['attn']['key']
-                         for i in range(layers))
-        v_caches = tuple(cache[f'h_{i}']['attn']['value']
-                         for i in range(layers))
-        cursor = cache['position']                       # [B], uniform
-        rng, key = jax.random.split(rng)
-        token = _sample(logits[:, -1], temperature, key)
-
-        def step(carry, _):
-            k_caches, v_caches, cursor, token, rng = carry
-            logits, k_caches, v_caches = token_step(
-                params, k_caches, v_caches, cursor, token)
-            rng, key = jax.random.split(rng)
-            next_token = _sample(logits, temperature, key)
-            return (k_caches, v_caches, cursor + 1, next_token, rng), token
-
-        (_, _, _, last, _), generated = jax.lax.scan(
-            step, (k_caches, v_caches, cursor, token, rng), None,
-            length=steps - 1)
-        generated = jnp.moveaxis(generated, 0, 1)        # [B, steps-1]
-        return jnp.concatenate([prompt, generated, last[:, None]], axis=1)
-
-    return run

@@ -18,7 +18,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from tpusystem.parallel.mesh import on_tpu
 from tpusystem.train.cursors import gather_rows as _gather_rows
 from tpusystem.train.cursors import rewind as _rewind
 
@@ -81,8 +80,8 @@ def _stream_params(decoder, params, stream_dtype: str):
 @functools.cache
 def _caster(compute_name: str):
     """One cached jitted cast program per target dtype: per-leaf eager
-    casts would pay a host dispatch each (~60 per generate() call), and an uncached jit would *retrace and recompile*
-    the cast every call (measured 8x slower decode)."""
+    casts would pay a host dispatch each (~60 per generate() call), and
+    an uncached jit would *retrace and recompile* the cast every call."""
     compute = jnp.dtype(compute_name)
 
     def cast(path, leaf):
@@ -106,23 +105,11 @@ def _caster(compute_name: str):
 def _quantizer(mode: str):
     """One cached jitted quantize program per narrow mode — the same
     retrace trap ``_caster`` pins (an uncached jit would retrace the
-    whole-tree quantization on every ``generate`` call; measured 8x
-    slower decode for the caster's version of this mistake). The leaf
+    whole-tree quantization on every ``generate`` call). The leaf
     rule (matrices only, embedding/router excluded) lives in
     :func:`tpusystem.ops.precision.quantize_streamed`."""
     from tpusystem.ops.precision import quantize_streamed
     return jax.jit(functools.partial(quantize_streamed, mode=mode))
-
-
-def streamed_bytes(module, params, stream_dtype: str) -> int:
-    """Per-step streamed bytes of :func:`generate`'s param tree under one
-    ``stream_dtype`` — the decode roofline quantity (weight bytes
-    crossing HBM per token step; quantized modes count narrow values
-    plus their per-channel scales, and embeddings/routers/vectors stay
-    f32 per the leaf rule). The one accounting shared by ``bench.py``,
-    ``benchmarks/decode_roofline.py``, and the dryrun decode stage."""
-    streamed = _stream_params(_decoder(module), params, stream_dtype)
-    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(streamed))
 
 
 def _dequant(params, decoder):
@@ -243,7 +230,7 @@ def select_tokens(logits, seed, position, temperature, top_k, top_p, mask):
 
 def generate(module, params, prompt, *, steps: int,
              temperature: float = 0.0, rng=None,
-             stream_dtype: str = 'auto', decode_impl: str = 'auto'):
+             stream_dtype: str = 'auto'):
     """Generate ``steps`` tokens after ``prompt``.
 
     Args:
@@ -255,21 +242,20 @@ def generate(module, params, prompt, *, steps: int,
         rng: ``jax.random`` key (required when ``temperature > 0``).
         stream_dtype: what the decode loop streams from HBM each step —
             decode at small batch is weight-STREAMING bound, so this is
-            the tokens/sec lever (benchmarks/decode_roofline.py).
-            ``'auto'`` (default) pre-casts float32 matrix kernels
-            (ndim >= 2) to the module's compute dtype when that dtype is
-            narrower: a bf16-compute model casts its f32 kernels to bf16
-            at every use anyway, so the cast changes which bytes stay
-            resident, not the matmul numerics. ``'bfloat16'`` forces
-            that cast regardless of the compute dtype (identical program
+            the tokens/sec lever. ``'auto'`` (default) pre-casts float32
+            matrix kernels (ndim >= 2) to the module's compute dtype when
+            that dtype is narrower: a bf16-compute model casts its f32
+            kernels to bf16 at every use anyway, so the cast changes which
+            bytes stay resident, not the matmul numerics. ``'bfloat16'``
+            forces that cast regardless of the compute dtype (identical program
             to ``'auto'`` on bf16 modules; bf16-rounds the weights of
             f32 modules). ``'int8'`` / ``'fp8'`` quantize the same
             leaves with per-output-channel symmetric scales
             (:func:`tpusystem.ops.precision.quantize_streamed`) —
-            2x/2x fewer weight bytes than bf16, dequantized per use
-            inside the loop body (or in-kernel under the fused impl),
-            greedy tokens equal up to the bounded quantization error;
-            ``'fp8'`` needs the capability probe
+            half the weight bytes of bf16, dequantized per use
+            inside the loop body (in-kernel under the serving engine's
+            fused step), greedy tokens equal up to the bounded
+            quantization error; ``'fp8'`` needs the capability probe
             (:func:`~tpusystem.ops.precision.fp8_unsupported_reason`)
             to pass. In every mode, leaves the model consumes at f32
             are untouched: embedding tables (the embed step adds
@@ -278,14 +264,6 @@ def generate(module, params, prompt, *, steps: int,
             logits), and vector leaves (biases, layernorm scales).
             ``'float32'`` streams the masters untouched (the training
             layout).
-        decode_impl: which token-step runs the decode loop. ``'flax'``
-            is the module's own apply (the reference path);
-            ``'fused'`` the Pallas fused decode chain
-            (:mod:`tpusystem.train.decode_fused`: activation resident
-            in VMEM, weights — quantized or not — streamed tile-by-tile,
-            fc→gelu→proj in one kernel), raising when the module is
-            outside its scope; ``'auto'`` (default) picks ``'fused'``
-            on TPU where supported and ``'flax'`` elsewhere.
 
     Returns:
         int32 ``[batch, prompt_len + steps]`` — prompt plus generation.
@@ -301,14 +279,6 @@ def generate(module, params, prompt, *, steps: int,
         raise ValueError(
             f'prompt ({prompt.shape[1]}) + steps ({steps}) exceeds the '
             f'cache capacity max_seq={decoder.max_seq}')
-    impl = _resolve_impl(decode_impl, decoder)
-    if impl == 'fused':
-        from tpusystem.train import decode_fused
-        try:
-            run = decode_fused.compiled_fused(decoder, steps, temperature)
-        except TypeError:   # unhashable module field (e.g. a live mesh)
-            run = decode_fused.build_fused(decoder, steps, temperature)
-        return run(params, prompt, rng)
     try:
         # jit caches key on function identity: reuse one compiled program
         # per (decoder config, steps, temperature) across generate() calls
@@ -316,28 +286,6 @@ def generate(module, params, prompt, *, steps: int,
     except TypeError:       # unhashable module field (e.g. a live mesh)
         run = _build(decoder, steps, temperature)
     return run(params, prompt, rng)
-
-
-def _resolve_impl(decode_impl: str, decoder) -> str:
-    """'flax' | 'fused' for this decode clone. 'auto' is conservative:
-    fused only on TPU backends (where the Pallas kernels compile to real
-    streaming; elsewhere they would run interpreted) and only for
-    modules inside the fused step's scope."""
-    if decode_impl not in ('auto', 'flax', 'fused'):
-        raise ValueError(f'unknown decode_impl {decode_impl!r}; '
-                         "expected 'auto', 'flax' or 'fused'")
-    if decode_impl == 'flax':
-        return 'flax'
-    from tpusystem.train.decode_fused import fused_unsupported_reason
-    reason = fused_unsupported_reason(decoder)
-    if decode_impl == 'fused':
-        if reason is not None:
-            raise ValueError(
-                f"decode_impl='fused' cannot run this module: {reason}")
-        return 'fused'
-    if reason is None and on_tpu():
-        return 'fused'
-    return 'flax'
 
 
 def speculative_generate(module, params, prompt, *, steps: int,

@@ -198,9 +198,7 @@ def build_multi_step(step, *, jit: bool = True, outputs_fn=None,
     ``losses`` is the per-step ``[N]`` float32 vector. One ``lax.scan``
     runs the N steps in a single compiled program, so per-dispatch host
     overhead (one Python round trip) is paid once per N batches instead
-    of per batch — the
-    amortization ``bench.py`` applies that the training service otherwise
-    never gets. Each distinct ``N`` compiles its own program (a
+    of per batch. Each distinct ``N`` compiles its own program (a
     :func:`grouped_batches` tail group shorter than ``size`` costs one
     extra compile, cached thereafter). Per-phase metrics stay exact: feed
     the whole loss vector
