@@ -77,6 +77,7 @@ def serve(served, tracer=None, clock=time.perf_counter):
 
 SERVE_SPANS = ['tpusystem.serve.tick', 'tpusystem.scheduler.admit',
                'tpusystem.engine.prefill', 'tpusystem.engine.adopt',
+               'tpusystem.engine.seat',
                'tpusystem.engine.dispatch', 'tpusystem.engine.read',
                'tpusystem.engine.rows', 'tpusystem.service.narrate']
 

@@ -324,6 +324,15 @@ def _copy_winner_windows(cache, win_rows_wide, cursor, speculate: int,
     return walk(cache)
 
 
+def _greedy_operands(vocab: int) -> tuple:
+    """The greedy-default sampling operands ``(seed, position, temperature,
+    top_k, top_p, mask)`` on the device: what an unsampled prefill passes
+    so its first-token choice is bitwise the classic argmax. An engine
+    builds them once; no admission makes a ``[vocab]`` mask of its own."""
+    return (jnp.uint32(0), jnp.int32(0), jnp.float32(0.0), jnp.int32(0),
+            jnp.float32(1.0), jnp.ones(vocab, bool))
+
+
 @dataclasses.dataclass
 class Admission:
     """What :meth:`Engine.admit` hands back: the row the request landed
@@ -419,7 +428,8 @@ class Engine:
 
     The decode step traces exactly once per engine (``trace_count`` is
     the witness); admissions and evictions are host-side table edits
-    plus fixed-shape device writes.
+    plus fixed-shape device writes, the per-row state among them in one
+    compiled program each (``membership_traces`` is their witness).
     """
 
     def __init__(self, module, params, *, rows: int = 4,
@@ -502,8 +512,9 @@ class Engine:
         self._tokens_dev = jnp.zeros(rows, jnp.int32)
         self._active_dev = jnp.zeros(rows, bool)
         # per-row sampling params as batched device arrays: the one
-        # compiled step reads them, admission/eviction edit them with
-        # fixed-shape .at[] writes — param churn never retraces. Greedy
+        # compiled step reads them; a membership change rewrites them in
+        # one compiled program (seat at admission, clear at eviction —
+        # _build_membership), so param churn retraces neither. Greedy
         # defaults (temp 0, no filters, all-True mask) make an idle or
         # unsampled row bitwise the classic argmax path.
         self.vocab = module.vocab_size
@@ -513,15 +524,23 @@ class Engine:
         self._topk_dev = jnp.zeros(rows, jnp.int32)
         self._topp_dev = jnp.ones(rows, jnp.float32)
         self._mask_dev = jnp.ones((rows, self.vocab), bool)
+        everywhere = None
         if self.tp_plan.path == 'gspmd':
             from jax.sharding import NamedSharding, PartitionSpec
             everywhere = NamedSharding(self.mesh, PartitionSpec())
-            self._tokens_dev = jax.device_put(self._tokens_dev, everywhere)
-            self._active_dev = jax.device_put(self._active_dev, everywhere)
-            for name in ('_seed_dev', '_pos_dev', '_temp_dev', '_topk_dev',
-                         '_topp_dev', '_mask_dev'):
+            for name in ('_tokens_dev', '_active_dev', '_seed_dev',
+                         '_pos_dev', '_temp_dev', '_topk_dev', '_topp_dev',
+                         '_mask_dev'):
                 setattr(self, name,
                         jax.device_put(getattr(self, name), everywhere))
+        # how often each membership program was traced: 1 each, whatever
+        # was admitted (trace_count stays the decode step's alone)
+        self.membership_traces = {'seat': 0, 'clear': 0}
+        self._seat_rows, self._clear_rows = self._build_membership(
+            everywhere)
+        # the operands of an unsampled prefill, by vocabulary (the draft's
+        # joins below when it differs)
+        self._greedy = {self.vocab: _greedy_operands(self.vocab)}
         self._rowstate: dict[int, _RowState] = {}
         self._prefills: dict[object, object] = {}  # unhashable-module path
         self._resumes: dict[int, object] = {}
@@ -551,6 +570,9 @@ class Engine:
             self._draft_prefiller = _decoder(draft_module)
             self._dparams = _stream_params(self._drafter, draft_params,
                                            stream_dtype)
+            if draft_module.vocab_size not in self._greedy:
+                self._greedy[draft_module.vocab_size] = _greedy_operands(
+                    draft_module.vocab_size)
             dshapes = jax.eval_shape(
                 functools.partial(self._drafter.init,
                                   jax.random.PRNGKey(0)),
@@ -638,6 +660,47 @@ class Engine:
         if self._spec or reason is not None:
             return 'flax'
         return 'fused' if on_tpu() else 'flax'
+
+    # ----------------------------------------------------------- membership
+
+    def _build_membership(self, placed):
+        """The two programs a membership change runs on the per-row
+        arrays. ``seat`` writes an admission's token, active flag, seed,
+        stream position, temperature, top-k and top-p into its adjacent
+        rows (one, or ``tree_fanout`` when speculative); ``clear`` returns
+        an evicted group to the idle greedy default: inactive,
+        temperature 0, all-True mask (a row that was neither sampled nor
+        masked already holds both; stale seed, position, top-k and top-p
+        are inert under temperature 0). The arrays are donated and come
+        back under ``placed`` (the TP engine's replicated sharding, or
+        None), so the decode step sees the operands it was traced on. The
+        request's values arrive as host-typed numpy, ``uint32 [5]`` (row,
+        token, seed, position, top-k) and ``float32 [2]`` (temperature,
+        top-p), so each program traces once per engine whatever is
+        admitted."""
+        fanout = self.tree_fanout if self._spec else 1
+
+        def put(array, row, value):
+            block = jnp.full((fanout,) + array.shape[1:], value, array.dtype)
+            return jax.lax.dynamic_update_slice(
+                array, block, (row,) + (0,) * (array.ndim - 1))
+
+        def seat(tokens, active, seed, pos, temp, topk, topp, ints, floats):
+            self.membership_traces['seat'] += 1      # runs at trace time only
+            row, token, _, position, top_k = ints.astype(jnp.int32)
+            return (put(tokens, row, token), put(active, row, True),
+                    put(seed, row, ints[2]), put(pos, row, position),
+                    put(temp, row, floats[0]), put(topk, row, top_k),
+                    put(topp, row, floats[1]))
+
+        def clear(active, temp, mask, row):
+            self.membership_traces['clear'] += 1     # runs at trace time only
+            return (put(active, row, False), put(temp, row, 0.0),
+                    put(mask, row, True))
+
+        where = {} if placed is None else {'out_shardings': placed}
+        return (jax.jit(seat, donate_argnums=tuple(range(7)), **where),
+                jax.jit(clear, donate_argnums=(0, 1, 2), **where))
 
     # ---------------------------------------------------------- speculative
 
@@ -801,18 +864,17 @@ class Engine:
         return self.bucket(suffix)
 
     def _greedy_ops(self, vocab: int):
-        """The greedy-default sampling operands: what an unsampled (or
-        draft) prefill passes so its first-token choice is bitwise the
-        classic argmax."""
-        return (jnp.uint32(0), jnp.int32(0), jnp.float32(0.0),
-                jnp.int32(0), jnp.float32(1.0), jnp.ones(vocab, bool))
+        """The greedy-default sampling operands of a ``vocab``-wide
+        prefill (the target's or the draft's), built at construction."""
+        return self._greedy[vocab]
 
     def _grammar_mask(self, sampling, stream: list):
         """Evaluate ``mask_fn`` over the emitted stream so far and
         validate its contract (bool ``[vocab]``, at least one token
-        allowed) — all-True when the request has no mask."""
+        allowed) — the engine's one all-True mask when the request has
+        none."""
         if sampling is None or sampling.mask_fn is None:
-            return jnp.ones(self.vocab, bool)
+            return self._greedy_ops(self.vocab)[-1]
         mask = np.asarray(sampling.mask_fn(list(stream)), bool).reshape(-1)
         if mask.shape[0] != self.vocab:
             raise ValueError(
@@ -826,18 +888,20 @@ class Engine:
         return jnp.asarray(mask)
 
     def _sampling_ops(self, sampling, emitted):
-        """jnp-typed per-request sampling operands for the FIRST token —
-        position ``len(emitted)`` (the stream slots already journaled in
-        a previous life), scalars typed so jitted programs never retrace
-        on Python weak types."""
-        position = len(emitted)
+        """Per-request sampling operands for the FIRST token — position
+        ``len(emitted)`` (the stream slots already journaled in a
+        previous life). An unsampled request takes the construction-time
+        greedy operands and brings only its position; a sampled one types
+        its scalars on the host, so jitted programs never retrace on
+        Python weak types and nothing is built on the device but a
+        grammar mask."""
+        seed, _, temp, topk, topp, unmasked = self._greedy_ops(self.vocab)
+        position = np.int32(len(emitted))
         if sampling is None:
-            return (jnp.uint32(0), jnp.int32(position), jnp.float32(0.0),
-                    jnp.int32(0), jnp.float32(1.0),
-                    jnp.ones(self.vocab, bool))
-        return (jnp.uint32(sampling.seed or 0), jnp.int32(position),
-                jnp.float32(sampling.temperature),
-                jnp.int32(sampling.top_k), jnp.float32(sampling.top_p),
+            return (seed, position, temp, topk, topp, unmasked)
+        return (np.uint32(sampling.seed or 0), position,
+                np.float32(sampling.temperature), np.int32(sampling.top_k),
+                np.float32(sampling.top_p),
                 self._grammar_mask(sampling, list(emitted)))
 
     def _run_prefill(self, decoder, bucket: int, padded, length: int,
@@ -952,8 +1016,8 @@ class Engine:
                   max_new: int, stop_token: int | None, tag,
                   sampling=None, emitted=()) -> Admission:
         """The host-side admission tail: sharing counters, row state,
-        sampling device arrays, token/active mirrors, and the
-        admitted-already-finished check."""
+        token/active mirrors, the seat program over the per-row device
+        arrays, and the admitted-already-finished check."""
         fanout = self.tree_fanout if self._spec else 1
         self.sharing['admissions'] += 1
         self.sharing['prompt_tokens'] += int(prompt.size) * fanout
@@ -969,16 +1033,17 @@ class Engine:
         # the NEXT token's stream position: `first` just landed at
         # position len(emitted), so the step samples at len(emitted) + 1
         start = len(emitted) + 1
-        for row in rows:
-            self._tokens[row] = first
-            self._active[row] = True
-            self._tokens_dev = self._tokens_dev.at[row].set(first)
-            self._active_dev = self._active_dev.at[row].set(True)
-            self._seed_dev = self._seed_dev.at[row].set(np.uint32(seed))
-            self._pos_dev = self._pos_dev.at[row].set(start)
-            self._temp_dev = self._temp_dev.at[row].set(temp)
-            self._topk_dev = self._topk_dev.at[row].set(topk)
-            self._topp_dev = self._topp_dev.at[row].set(topp)
+        self._tokens[rows] = first
+        self._active[rows] = True
+        with annotate('tpusystem.engine.seat'):
+            (self._tokens_dev, self._active_dev, self._seed_dev,
+             self._pos_dev, self._temp_dev, self._topk_dev,
+             self._topp_dev) = self._seat_rows(
+                self._tokens_dev, self._active_dev, self._seed_dev,
+                self._pos_dev, self._temp_dev, self._topk_dev,
+                self._topp_dev,
+                np.array([rep, first, seed, start, topk], np.uint32),
+                np.array([temp, topp], np.float32))
         self._rowstate[rep] = _RowState(tokens=[first], max_new=max_new,
                                         stop=stop_token, tag=tag,
                                         sampling=sampling,
@@ -1272,23 +1337,19 @@ class Engine:
     def evict(self, row: int) -> _RowState:
         """Retire ``row`` (finished or cancelled; the representative row
         when speculative — its whole branch group retires): its blocks
-        return to the free list, its table resets to trash — a host-side
-        edit plus one fixed-shape table write, never a retrace."""
+        return to the free list, its table resets to trash, its rows go
+        back to the idle greedy default — a host-side edit, the clear
+        program and one fixed-shape table write, never a retrace."""
         if row not in self._rowstate:
             raise ValueError(f'row {row} is not seated')
         fanout = self.tree_fanout if self._spec else 1
         state = self._rowstate[row]
         for member in range(row, row + fanout):
             self.pool.evict(member)
-            self._active[member] = False
-            self._tokens[member] = 0
-            self._active_dev = self._active_dev.at[member].set(False)
-            # temp 0 + all-True mask return the row to the greedy
-            # default; stale seed/pos/topk/topp are inert under temp 0
-            if state.sampling is not None:
-                self._temp_dev = self._temp_dev.at[member].set(0.0)
-                if state.sampling.mask_fn is not None:
-                    self._mask_dev = self._mask_dev.at[member].set(True)
+        self._active[row:row + fanout] = False
+        self._tokens[row:row + fanout] = 0
+        self._active_dev, self._temp_dev, self._mask_dev = self._clear_rows(
+            self._active_dev, self._temp_dev, self._mask_dev, np.int32(row))
         self.sampled_rows -= state.sampled
         self._cache = write_tables(self._cache, self.pool.table)
         self._free_rows.append(row)
