@@ -271,3 +271,135 @@ def test_the_kernel_compiles_for_a_v5e_at_serving_widths(one_chip, rows,
              if pool in line.split(' = ')[-1].split('(')[0]
              and 'parameter(' not in line]
     assert not moved, moved
+
+
+# --------------------------------------------- the latent pool's kernel
+
+def latent_reference(query, pool, table, cursor, block, rank, width, scale):
+    """Each row attends positions ``0 … cursor`` of its own blocks' latent
+    rows, every head the same rows: float32, one row at a time."""
+    rows, heads, _ = query.shape
+    stored = np.asarray(pool, np.float32)[:, :width]
+    out = np.zeros((rows, heads, rank), np.float32)
+    for row in range(rows):
+        held = np.arange(int(cursor[row]) + 1)
+        slots = np.asarray(table)[row, held // block] * block + held % block
+        scores = np.asarray(query[row], np.float32) @ stored[slots].T * scale
+        weights = np.exp(scores - scores.max(-1, keepdims=True))
+        weights /= weights.sum(-1, keepdims=True)
+        out[row] = weights @ stored[slots][:, :rank]
+    return out
+
+
+@pytest.mark.parametrize('dtype, tolerance', [(jnp.float32, 2e-5),
+                                              (jnp.bfloat16, 2e-2)])
+def test_latent_kernel_reads_each_row_to_its_own_depth(dtype, tolerance):
+    """Ragged depths over shuffled blocks, two rows sharing a block, a
+    parked row on the trash block, a row that fills its whole table: the
+    chunk walk (two chunks of four blocks here) skips what a row does not
+    hold and masks inside its last chunk. The pool's rows are padded to
+    whole lanes, and the padding is never attended."""
+    from tpusystem.ops.pallas import latent_attention as kernel_module
+    rows, heads, rank, rope, block, max_blocks = 5, 8, 32, 8, 4, 8
+    width, lanes = rank + rope, 128
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    pool = jnp.pad(jax.random.normal(keys[0], (41 * block, width)),
+                   ((0, 0), (0, lanes - width)), constant_values=7.0)
+    pool = pool.astype(dtype)
+    query = jax.random.normal(keys[1], (rows, heads, width)).astype(dtype)
+    table = np.asarray(jax.random.permutation(keys[2], 40)[:rows * max_blocks]
+                       ).reshape(rows, max_blocks).astype(np.int32) + 1
+    table[1, :2] = table[0, :2]               # a shared prefix
+    table[3] = 0                              # parked on the trash block
+    cursor = np.array([13, 9, 31, 0, 4], np.int32)
+    got = kernel_module.paged_latent_attention(
+        query, pool, jnp.asarray(table), jnp.asarray(cursor), rank=rank,
+        width=width, block=block, scale=0.2, interpret=True)
+    assert got.shape == (rows, heads, rank) and got.dtype == dtype
+    want = latent_reference(query, pool, table, cursor, block, rank, width,
+                            0.2)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               atol=tolerance, rtol=tolerance)
+
+
+def test_latent_plan_refuses_what_the_tpu_cannot_tile():
+    from tpusystem.ops.pallas.latent_attention import (latent_plan,
+                                                       paged_latent_attention)
+    assert latent_plan(128, 512, 16, 320, jnp.bfloat16, False) == 16
+    assert latent_plan(128, 512, 16, 4, jnp.bfloat16, False) == 4
+    assert latent_plan(4, 16, 16, 8, jnp.float32, True) == 8   # interpreted
+    for heads, rank, block in ((128, 500, 16), (12, 512, 16), (128, 512, 8)):
+        assert latent_plan(heads, rank, block, 320, jnp.bfloat16,
+                           False) is None
+    with pytest.raises(ValueError, match='cannot tile'):
+        paged_latent_attention(
+            jnp.zeros((2, 12, 576), jnp.bfloat16),
+            jnp.zeros((64, 640), jnp.bfloat16), jnp.zeros((2, 4), jnp.int32),
+            jnp.zeros((2,), jnp.int32), rank=512, width=576, block=16,
+            scale=0.1, interpret=False)
+
+
+def test_the_engine_reads_the_latent_pool_through_the_kernel(monkeypatch):
+    """On the TPU one decoded token a row goes through the kernel; steered
+    here (the look for a TPU says yes, the kernel runs interpreted), the
+    engine's tokens are the XLA read's, which are ``generate``'s."""
+    from tpusystem.models import deepseek_tiny
+    from tpusystem.ops import attention
+    from tpusystem.ops.pallas import latent_attention as kernel_module
+    from tpusystem.serve import Engine
+    from tpusystem.train.generate import generate
+    module = deepseek_tiny(layers=2, heads=8, kv_rank=128, max_seq=64,
+                           held=(0, 8))
+    params = module.init(jax.random.PRNGKey(2),
+                         jnp.zeros((1, 4), jnp.int32))['params']
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (21, 6)]
+    calls = []
+    real = kernel_module.paged_latent_attention
+    monkeypatch.setattr(kernel_module, 'paged_latent_attention',
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    monkeypatch.setattr(attention, 'on_tpu', lambda: True)
+    engine = Engine(module, params, rows=2, block_size=8, decode_impl='flax')
+    rows = [engine.admit(prompt, max_new=5).row for prompt in prompts]
+    tokens = {}
+    while engine.active_rows:
+        for row, _reason, out in engine.step().finished:
+            tokens[row] = out
+    monkeypatch.setattr(attention, 'on_tpu', lambda: False)
+    assert len(calls) == 2 and engine.trace_count == 1    # once a layer
+    for row, prompt in zip(rows, prompts):
+        want = generate(module, params, jnp.asarray([prompt]), steps=5)
+        assert tokens[row] == np.asarray(want)[0, len(prompt):].tolist()
+
+
+def test_the_latent_kernel_compiles_for_a_v5e_at_serving_widths(one_chip):
+    """DeepSeek-V2's share as the cell serves it: 64 rows x 128 heads over
+    rows of 576 stored on 640 lanes, block 16, 320 table columns. Mosaic
+    takes it, and the pool goes in as stored: no copy or transpose of a
+    pool-shaped operand (a 576-wide pool is kept slot-minor by the TPU and
+    transposed whole around every use: why the rows are padded)."""
+    from tpusystem.ops.pallas.latent_attention import paged_latent_attention
+    rows, heads, block, max_blocks = 64, 128, 16, 320
+    slots = (rows * max_blocks + 1) * block
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def step_fn(query, pool, table, cursor):
+        with jax.named_scope('kv_read'):
+            return paged_latent_attention(query, pool, table, cursor,
+                                          rank=512, width=576, block=block,
+                                          scale=0.11472, interpret=False)
+
+    compiled = jax.jit(step_fn).lower(
+        shaped((rows, heads, 576), jnp.bfloat16),
+        shaped((slots, 640), jnp.bfloat16),
+        shaped((rows, max_blocks), jnp.int32),
+        shaped((rows,), jnp.int32)).compile().as_text()
+    calls = [line for line in compiled.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert '%paged_latent_attention' in calls[0].split(' = ')[0]
+    moved = [line for line in compiled.splitlines()
+             if f'bf16[{slots},640]' in line.split(' = ')[-1].split('(')[0]
+             and 'parameter(' not in line]
+    assert not moved, moved

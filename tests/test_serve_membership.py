@@ -8,8 +8,9 @@ have left (the expectation is kept on the host, by the rules the eager
 writes followed); each program traces once per engine whatever is
 admitted — greedy, sampled, grammar-masked, resumed through ``emitted=``,
 finished at admission, cancelled — under speculative ``tree_fanout``
-groups and under a TP mesh alike, where the arrays also stay replicated
-so the decode step is not retraced; and an unsampled admission brings no
+groups, under a TP mesh (where the arrays also stay replicated so the
+decode step is not retraced) and over a latent pool with one KV leaf a
+layer alike; and an unsampled admission brings no
 ``[vocab]`` operand of its own.
 """
 
@@ -19,14 +20,14 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec
 
-from tpusystem.models import gpt2_tiny
+from tpusystem.models import deepseek_tiny, gpt2_tiny
 from tpusystem.parallel import MeshSpec
 from tpusystem.serve import Engine, SamplingParams
 from tpusystem.serve import engine as engine_module
 
 ARRAYS = ('_tokens_dev', '_active_dev', '_seed_dev', '_pos_dev',
           '_temp_dev', '_topk_dev', '_topp_dev', '_mask_dev')
-KINDS = ('plain', 'speculative', 'sharded')
+KINDS = ('plain', 'speculative', 'sharded', 'latent')
 VOCAB = 256
 
 
@@ -41,6 +42,12 @@ def served():
 
 def build(kind, served) -> Engine:
     module, params = served
+    if kind == 'latent':     # a pool whose row is one latent, one KV leaf
+        module = deepseek_tiny(held=(4, 8))
+        assert module.vocab_size == VOCAB
+        params = module.init(jax.random.PRNGKey(0),
+                             jnp.zeros((1, 8), jnp.int32))['params']
+        return Engine(module, params, rows=4, block_size=8)
     if kind == 'speculative':
         return Engine(module, params, rows=4, block_size=8,
                       draft_module=module, draft_params=params,

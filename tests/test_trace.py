@@ -60,6 +60,7 @@ class _Admission:
 class _Report:
     emitted: dict
     finished: list
+    expert_load: dict | None = None
 
 
 @dataclasses.dataclass

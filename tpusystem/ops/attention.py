@@ -12,6 +12,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from tpusystem.parallel.mesh import on_tpu
+
 NEG_INF = -1e30
 
 
@@ -211,15 +213,9 @@ def paged_attention(module, query, key, value, max_seq: int,
     # nearly free, so fine-grained switching buys little); the paged
     # read is a GATHER whose cost is proportional to the window, so it
     # starts at 64 tokens — shallow rows read 4x less pool
-    buckets = [min(max_blocks, max(1, 64 // block))]
-    while buckets[-1] < max_blocks:
-        buckets.append(min(2 * buckets[-1], max_blocks))
-    if len(buckets) == 1:
-        return attend_over(max_blocks)()
-    filled_blocks = (jnp.max(positions) + block) // block
-    bucket_index = sum((filled_blocks > width).astype(jnp.int32)
-                       for width in buckets[:-1])
-    return jax.lax.switch(bucket_index, [attend_over(w) for w in buckets])
+    return _switch_on_depth(_read_buckets(max(1, 64 // block), max_blocks),
+                            (jnp.max(positions) + block) // block,
+                            attend_over)
 
 
 def cached_attention(module, query, key, value, max_seq: int,
@@ -340,17 +336,214 @@ def cached_attention(module, query, key, value, max_seq: int,
                                          causal=False, mask=mask[:, None])
         return run
 
-    buckets = [256]
-    while buckets[-1] < max_seq:
-        buckets.append(min(2 * buckets[-1], max_seq))
+    return _switch_on_depth(_read_buckets(256, max_seq),
+                            jnp.max(positions) + 1, attend_over)
+
+
+def _read_buckets(smallest: int, largest: int) -> list[int]:
+    """Window widths doubling from ``smallest`` up to ``largest``."""
+    buckets = [min(smallest, largest)]
+    while buckets[-1] < largest:
+        buckets.append(min(2 * buckets[-1], largest))
+    return buckets
+
+
+def _switch_on_depth(buckets: list[int], filled, attend_over):
+    """``attend_over(width)()`` for the smallest bucket covering ``filled``
+    (``lax.switch`` over static widths: one compiled program)."""
     if len(buckets) == 1:
-        return attend_over(max_seq)()
-    filled = jnp.max(positions) + 1
-    # NOT named `index`: that would shadow the flax cache variable of the
-    # same name assigned above and invite silent misuse of the cursor
-    bucket_index = sum((filled > width).astype(jnp.int32)
-                       for width in buckets[:-1])
-    return jax.lax.switch(bucket_index, [attend_over(w) for w in buckets])
+        return attend_over(buckets[0])()
+    chosen = sum((filled > width).astype(jnp.int32) for width in buckets[:-1])
+    return jax.lax.switch(chosen, [attend_over(w) for w in buckets])
+
+
+# the float32 scores of one block of queries may take this many bytes
+# (128 heads x 256 queries x 4096 keys is 512 MiB)
+_EXPANDED_SCORE_BYTES = 5 << 27
+
+
+def expanded_latent_attention(query, key_rope, latent, w_key, w_value,
+                               scale: float):
+    """Causal attention with keys and values expanded per head from the
+    latent rows (the prefill path of :func:`latent_attention`): ``k =
+    [c·W_k ; k_rope]`` (the rope part shared by every head), ``v = c·W_v``.
+    Queries go through in blocks, each over the keys up to its own last
+    position, so the float32 scores never hold the whole square and the
+    masked half above the diagonal is skipped block by block."""
+    batch, length, heads, _ = query.shape
+    rank = w_key.shape[0]
+    content = latent[..., :rank]
+    key = jnp.concatenate(
+        [jnp.einsum('blc,chd->blhd', content, w_key),
+         jnp.broadcast_to(key_rope[:, :, None, :],
+                          (batch, length, heads, key_rope.shape[-1]))],
+        axis=-1)
+    value = jnp.einsum('blc,chd->blhd', content, w_value)
+    block = length
+    while block > 128 and batch * heads * block * length * 4 \
+            > _EXPANDED_SCORE_BYTES:
+        block //= 2
+    if block >= length or length % block:
+        return dot_product_attention(query, key, value, causal=True,
+                                     scale=scale)
+    out = []
+    for start in range(0, length, block):
+        stop = start + block
+        mask = causal_mask(block, stop, offset=start)
+        out.append(dot_product_attention(
+            query[:, start:stop], key[:, :stop], value[:, :stop],
+            causal=False, mask=mask[None, None], scale=scale))
+    return jnp.concatenate(out, axis=1)
+
+
+def latent_attention(module, q_content, q_rope, latent, w_key, w_value, *,
+                     scale: float, max_seq: int, per_row: bool = False,
+                     pages: tuple | None = None):
+    """Incremental attention over a **latent** cache (multi-head latent
+    attention, DeepSeek-V2): what is cached per position is one row
+    ``[c_kv ; k_rope]`` — the normalised compressed key-value vector and the
+    rotated positional key that every head shares — and not a key and a
+    value per head.
+
+    ``q_content [batch, len, heads, nope]`` and ``q_rope [batch, len, heads,
+    rope]`` (rotated) are the two parts of the query; ``latent [batch, len,
+    rank + rope]`` this call's rows; ``w_key [rank, heads, nope]`` and
+    ``w_value [rank, heads, v]`` the two halves of the up-projection.
+    Returns ``[batch, len, heads, v]``. Two paths, the same mathematics:
+
+    * **prefill** (the call that creates the cache): keys and values are
+      expanded per head from the latent and attended causally
+      (:func:`expanded_latent_attention`);
+    * **decode** (the cache exists): the up-projection is *absorbed* —
+      ``q' = q_content · W_k^T`` per head (``nope -> rank``), scores ``=
+      (q' · c + q_rope · k_rope) · scale`` straight against the cached rows,
+      ``o' = softmax · c`` and ``o = o' · W_v`` — so a decoded token reads
+      ``rank + rope`` values a position instead of ``heads x (nope + rope +
+      v)``.
+
+    The cache follows :func:`cached_attention`'s conventions with one KV
+    leaf: ``'key'`` is ``[batch, max_seq, lanes]`` contiguous, or with
+    ``pages = (num_blocks, block_size)`` the pool ``[num_blocks *
+    block_size, lanes]`` behind the per-row ``'table'`` of
+    :func:`paged_attention` (same trash block, same cursor leaf ``'index'``,
+    no ``'value'`` leaf): the serving engine's admission, table and cursor
+    edits match the leaves by those names and need no other. ``lanes`` is
+    ``rank + rope`` rounded up to whole lanes of 128 (576 -> 640, the rest
+    zeros): the TPU's tiled layout pads a row to whole lanes anyway, and
+    given a minor dimension that is not lane-dense it keeps the *slot*
+    dimension minor instead, so that every program that writes or gathers
+    rows first transposes the whole pool.
+
+    One decoded token a row over the paged pool is read in place by the
+    Pallas kernel (:func:`tpusystem.ops.pallas.latent_attention.
+    paged_latent_attention`) on the TPU where its plan tiles; every other
+    read (a longer window, the contiguous cache, off the TPU) gathers a
+    bucketed window as :func:`paged_attention` does, whole blocks at a
+    time. Scopes: ``kv_write`` and ``kv_read`` (the gather included)."""
+    batch, length, width = latent.shape
+    rank = w_key.shape[0]
+    lanes = -(-width // 128) * 128
+    if pages is not None:
+        num_blocks, block = pages
+        if max_seq % block:
+            raise ValueError(f'max_seq ({max_seq}) must be a multiple of '
+                             f'the page block_size ({block})')
+        shape = (num_blocks * block, lanes)
+    else:
+        if length > max_seq:
+            raise ValueError(
+                f'prompt length {length} exceeds the cache capacity '
+                f'max_seq={max_seq}; raise max_seq or truncate the prompt')
+        shape = (batch, max_seq, lanes)
+    prefill = pages is None and not module.has_variable('cache', 'index')
+    cache = module.variable('cache', 'key', jnp.zeros, shape, latent.dtype)
+    if pages is not None:
+        max_blocks = max_seq // block
+        table = module.variable('cache', 'table', jnp.zeros,
+                                (batch, max_blocks), jnp.int32)
+    index = module.variable('cache', 'index',
+                            lambda: jnp.zeros((batch,), jnp.int32))
+    query = jnp.concatenate([q_content, q_rope], axis=-1)
+    if module.is_initializing():
+        return expanded_latent_attention(query, latent[..., rank:], latent,
+                                          w_key, w_value, scale)
+    cursor = index.value
+    positions = cursor[:, None] + jnp.arange(length)[None, :]   # [B, L]
+    rows = jnp.pad(latent.astype(cache.value.dtype),
+                   ((0, 0), (0, 0), (0, lanes - width)))
+    with jax.named_scope('kv_write'):
+        if pages is not None:
+            logical = jnp.minimum(positions // block, max_blocks - 1)
+            physical = jnp.take_along_axis(table.value, logical, axis=1)
+            slots = (physical * block + positions % block).reshape(-1)
+            cache.value = cache.value.at[slots].set(rows.reshape(-1, lanes))
+        elif per_row:
+            cache.value = cache.value.at[
+                jnp.arange(batch)[:, None], positions].set(rows)
+        else:
+            if _debug_cache_enabled():
+                jax.debug.callback(_assert_uniform_cursor, cursor)
+            cache.value = jax.lax.dynamic_update_slice(
+                cache.value, rows, (0, cursor[0], 0))
+        index.value = cursor + length
+    if prefill:
+        return expanded_latent_attention(query, latent[..., rank:], latent,
+                                          w_key, w_value, scale)
+
+    with jax.named_scope('mla_proj'):
+        absorbed = jnp.concatenate(
+            [jnp.einsum('blhd,chd->blhc', q_content, w_key), q_rope], axis=-1)
+
+    if pages is not None and length == 1 and on_tpu():
+        from tpusystem.ops.pallas.latent_attention import (
+            latent_plan, paged_latent_attention)
+        heads = q_content.shape[2]
+        if latent_plan(heads, rank, block, max_blocks, cache.value.dtype,
+                       False) is not None:
+            with jax.named_scope('kv_read'):
+                mixed = paged_latent_attention(
+                    absorbed[:, 0], cache.value, table.value, cursor,
+                    rank=rank, width=width, block=block, scale=scale)
+            with jax.named_scope('mla_proj'):
+                return jnp.einsum('blhc,chd->blhd', mixed[:, None], w_value)
+
+    def attend_over(width_: int):
+        def run():
+            with jax.named_scope('kv_read'):
+                if pages is not None:
+                    # whole blocks: a block is whole sublane tiles, so the
+                    # pool seen block by block is the stored pool itself
+                    mapped = jax.lax.slice_in_dim(table.value, 0, width_,
+                                                  axis=1)
+                    window = jnp.take(
+                        cache.value.reshape(num_blocks, block, lanes), mapped,
+                        axis=0).reshape(batch, width_ * block, lanes)
+                else:
+                    window = jax.lax.slice_in_dim(cache.value, 0, width_,
+                                                  axis=1)
+                window = window[..., :width]
+                span = window.shape[1]
+                scores = jnp.einsum(
+                    'blhc,bwc->bhlw', absorbed, window,
+                    preferred_element_type=jnp.float32) * scale
+                mask = (jnp.arange(span)[None, None, :]
+                        <= positions[:, :, None])              # [B, L, W]
+                scores = jnp.where(mask[:, None], scores, NEG_INF)
+                weights = jax.nn.softmax(scores, axis=-1)
+                return jnp.einsum('bhlw,bwc->blhc',
+                                  weights.astype(window.dtype),
+                                  window[..., :rank])
+        return run
+
+    if pages is not None:
+        buckets = _read_buckets(max(1, 64 // block), max_blocks)
+        filled = (jnp.max(positions) + block) // block
+    else:
+        buckets = _read_buckets(256, max_seq)
+        filled = jnp.max(positions) + 1
+    mixed = _switch_on_depth(buckets, filled, attend_over)
+    with jax.named_scope('mla_proj'):
+        return jnp.einsum('blhc,chd->blhd', mixed, w_value)
 
 
 def dot_product_attention(query, key, value, *, causal: bool = True,

@@ -1043,3 +1043,165 @@ def moe_partition_rules():
         (r'moe/b2$', P(EXPERT, None)),
         (r'moe/router$', P()),
     )
+
+
+# --- gated experts with a group-limited router and a held share -----------
+
+def group_limited_top_k(scores: jax.Array, k: int, groups: int,
+                        keep_groups: int):
+    """Group-limited greedy top-k (DeepSeek-V2's ``group_limited_greedy``).
+
+    ``scores`` is ``[tokens, experts]`` (softmax probabilities, float32);
+    the experts lie in ``groups`` equal consecutive groups. A group's score
+    is its largest member; the ``keep_groups`` best groups stay and every
+    other expert's score is set to 0; the ``k`` largest of what is left
+    are the token's experts. Returns ``(ids, weights)``, both ``[tokens,
+    k]``, the weights being the chosen scores themselves (not
+    renormalised). Ties go to the lower index (``lax.top_k``)."""
+    tokens, experts = scores.shape
+    if experts % groups:
+        raise ValueError(f'{experts} experts do not split into {groups} '
+                         'equal groups')
+    per_group = experts // groups
+    group_score = jnp.max(scores.reshape(tokens, groups, per_group), axis=-1)
+    _, kept = lax.top_k(group_score, keep_groups)
+    keep = jnp.any(kept[:, :, None] == jnp.arange(groups)[None, None, :],
+                   axis=1)                                  # [tokens, groups]
+    limited = jnp.where(jnp.repeat(keep, per_group, axis=1), scores, 0.0)
+    weights, ids = lax.top_k(limited, k)
+    return ids.astype(jnp.int32), weights
+
+
+def seat_held(ids: jax.Array, start: int, count: int):
+    """Seat the assignments that fall on the ``count`` experts held here
+    (``start .. start + count - 1``), sorted by expert.
+
+    ``ids`` is ``[tokens, k]``, every token's chosen experts over the whole
+    router. Returns ``(order, held, sizes)``: ``order [tokens * k]`` lists
+    the flattened assignments expert by expert, those on experts held
+    elsewhere last; ``held [tokens * k]`` says which sorted rows are seated
+    here; ``sizes [count]`` is how many each held expert got — the group
+    sizes of a grouped matrix product over the sorted rows. Nothing is
+    ever dropped: the bound is the static ``tokens * k``."""
+    local = ids.reshape(-1) - start
+    mine = (local >= 0) & (local < count)
+    key = jnp.where(mine, local, count)           # elsewhere sorts last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                    dtype=jnp.int32)
+    return order, jnp.take(mine, order), sizes
+
+
+class GatedExperts(nn.Module):
+    """A bias-free expert layer told which experts it holds.
+
+    The router scores **all** ``experts`` (softmax in float32, from the
+    router matrix as handed, whatever its type), picks ``k`` by
+    :func:`group_limited_top_k` and weighs each by ``scale`` times its
+    score, not renormalised. ``held = (start, count)`` names the experts
+    whose matrices live here (``None``: all of them); the layer sums
+    ``w_e * E_e(h)`` over a token's chosen experts *that it holds* — what
+    the others would have added is left out, as on one chip of an
+    expert-parallel deployment before the exchange — plus the shared
+    experts ``S(h)`` (one gated MLP of ``shared_width``; 0: none), which
+    every holder computes alike. ``E(h) = down(silu(gate h) * up h)`` at
+    ``width``, which need not be a multiple of the model's width.
+
+    Work and memory follow the assignments seated here, not ``experts x
+    tokens``: the (token, choice) pairs on held experts are sorted by
+    expert (:func:`seat_held`) and the three products run as grouped
+    products (``jax.lax.ragged_dot``: on the TPU one Mosaic grouped-matmul
+    program that skips an expert nobody chose) over at most ``tokens * k``
+    rows. No capacity, no dropped token at any batch, and a row's output
+    does not depend on what is batched with it.
+
+    Scopes (``jax.named_scope``): ``router`` (scores, group limit, top-k,
+    the sort), ``experts`` (the gathers, the grouped products, the weighted
+    sum), ``shared``. Counters: where the caller makes the ``expert_load``
+    collection mutable, ``sow`` leaves one int32 scalar under each of
+    :attr:`LOAD` for this call (``seated``: assignments seated here;
+    ``hit``: held experts that got any; ``largest``: the most one held
+    expert got); the serving engine sums the layers' under those names and
+    reads them with the tick's tokens. Where the caller makes ``routing``
+    mutable, ``chosen`` holds the ``k`` experts every token was given
+    (``[tokens, k]`` int32, of all ``experts``, held or not): what a
+    caller needs to replay the layer's choices (``Engine(routing_sink=)``)."""
+
+    LOAD = ('seated', 'hit', 'largest')
+
+    experts: int                    # the router's width
+    k: int
+    width: int
+    groups: int = 1
+    keep_groups: int = 1
+    scale: float = 1.0
+    shared_width: int = 0
+    held: tuple | None = None       # (first expert held, how many)
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, hidden):
+        batch_shape, dim = hidden.shape[:-1], hidden.shape[-1]
+        start, count = self.held if self.held is not None \
+            else (0, self.experts)
+        if start < 0 or count < 1 or start + count > self.experts:
+            raise ValueError(f'held={self.held} lies outside the '
+                             f"router's {self.experts} experts")
+        compute = jnp.dtype(self.dtype)
+        init = nn.initializers.lecun_normal()
+        router = self.param('router', nn.initializers.normal(0.02),
+                            (dim, self.experts), jnp.float32)
+        gate = self.param('gate', init, (count, dim, self.width), jnp.float32)
+        up = self.param('up', init, (count, dim, self.width), jnp.float32)
+        down = self.param('down', init, (count, self.width, dim), jnp.float32)
+        flat = hidden.reshape(-1, dim)
+        tokens = flat.shape[0]
+
+        with jax.named_scope('router'):
+            # scores from the input as handed (float32 where the caller
+            # keeps its residual stream so): a rounding here flips experts
+            logits = jnp.dot(flat.astype(jnp.float32),
+                             router.astype(jnp.float32),
+                             precision=lax.Precision.HIGHEST)
+            flat = flat.astype(compute)
+            ids, weights = group_limited_top_k(
+                jax.nn.softmax(logits, axis=-1), self.k, self.groups,
+                self.keep_groups)
+            order, held, sizes = seat_held(ids, start, count)
+            token_of = order // self.k
+            weight_of = jnp.where(
+                held, self.scale * jnp.take(weights.reshape(-1), order), 0.0)
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(order.shape[0], dtype=order.dtype))
+        latest = dict(reduce_fn=lambda _, new: new)
+        for name, count in zip(self.LOAD, (jnp.sum(sizes), jnp.sum(sizes > 0),
+                                           jnp.max(sizes))):
+            self.sow('expert_load', name, count.astype(jnp.int32),
+                     init_fn=lambda: jnp.zeros((), jnp.int32), **latest)
+        self.sow('routing', 'chosen', ids.astype(jnp.int32),
+                 init_fn=lambda: jnp.zeros((tokens, self.k), jnp.int32),
+                 **latest)
+
+        with jax.named_scope('experts'):
+            rows = jnp.take(flat, token_of, axis=0)       # [tokens * k, dim]
+            grown = nn.silu(lax.ragged_dot(rows, gate.astype(compute), sizes)) \
+                * lax.ragged_dot(rows, up.astype(compute), sizes)
+            shrunk = lax.ragged_dot(grown, down.astype(compute), sizes)
+            # rows past the seated ones hold whatever the product left
+            weighed = jnp.where(held[:, None],
+                                shrunk.astype(jnp.float32)
+                                * weight_of[:, None], 0.0).astype(compute)
+            routed = jnp.sum(
+                jnp.take(weighed, back, axis=0).reshape(tokens, self.k, dim),
+                axis=1, dtype=jnp.float32)
+        output = routed
+        if self.shared_width:
+            with jax.named_scope('shared'):
+                dense = lambda features, name: nn.Dense(
+                    features, use_bias=False, dtype=compute,
+                    name=f'shared_{name}')
+                shared = dense(dim, 'down')(
+                    nn.silu(dense(self.shared_width, 'gate')(flat))
+                    * dense(self.shared_width, 'up')(flat))
+            output = output + shared.astype(jnp.float32)
+        return output.reshape(*batch_shape, dim).astype(hidden.dtype)

@@ -75,6 +75,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 import time
 
 import jax
@@ -163,17 +164,23 @@ def engine_unsupported_reason(module) -> str | None:
     """None when the paged engine can serve this module, else why not
     (the ``fused_paged_reason`` capability-gate discipline).
 
-    Served today: both family LMs (GPT2 / Llama, unrolled), including
-    **MoE** stacks — decode-mode expert dispatch runs at full capacity
-    (capacity = the step's token count, so routing never drops a token
-    and each token's expert mix is independent of co-batched traffic;
-    :class:`tpusystem.ops.moe.MoEMLP` ``full_capacity``). The remaining
-    gate is layout, not architecture."""
+    Served today, all unrolled: the GPT2 and Llama families, including
+    **MoE** GPT2 stacks (decode-mode expert dispatch at full capacity:
+    :class:`tpusystem.ops.moe.MoEMLP` ``full_capacity``), and the
+    **DeepSeek-V2** family — latent attention over a latent paged pool
+    (one ``key`` leaf ``[slots, kv_rank + rope]`` a layer and no
+    ``value`` leaf: admission, tables and cursors go by leaf name, so
+    the pool's row is whatever the module's cache collection declares)
+    and :class:`tpusystem.ops.moe.GatedExperts` layers holding a share
+    of the router's experts (grouped products over the seated
+    assignments: no capacity, no drop, no dependence on co-batched
+    rows; their ``expert_load`` counters ride the tick's token read).
+    The remaining gate is layout, not architecture."""
     for field in ('decode', 'max_seq', 'per_row_decode', 'decode_pages'):
         if not hasattr(module, field):
             return (f'module {type(module).__name__} has no {field!r} '
                     'field — the engine needs the family decode '
-                    'conventions (GPT2 / Llama)')
+                    'conventions (GPT2 / Llama / DeepSeekV2)')
     if getattr(module, 'scan_layers', False):
         return ('scan_layers stacks the per-layer caches at a leading '
                 'layer dim; the engine admission writes are unrolled-'
@@ -193,14 +200,17 @@ def prefill_bucket(length: int, block_size: int, max_seq: int) -> int:
 
 
 @functools.cache
-def _compiled_prefill(decoder, bucket: int):
+def _compiled_prefill(decoder, bucket: int, routed: bool = False):
     """One compiled prefill program per (decode clone, pad bucket) —
     ``cache_info()`` is the compile-count witness the bucketing tests
     pin."""
-    return _build_prefill(decoder, bucket)
+    return _build_prefill(decoder, bucket, routed)
 
 
-def _build_prefill(decoder, bucket: int):
+def _build_prefill(decoder, bucket: int, routed: bool = False):
+    """``routed``: the program also returns the experts every padded
+    position was given, ``[bucket, expert layers, k]`` (an engine with a
+    ``routing_sink``)."""
     del bucket          # part of the cache key; shapes key the jit cache
 
     @jax.jit
@@ -210,14 +220,42 @@ def _build_prefill(decoder, bucket: int):
         # right-pad junk is causally invisible to the real positions
         logits, state = decoder.apply(
             {'params': _dequant(params, decoder)}, padded,
-            mutable=['cache'])
+            mutable=['cache', 'routing'] if routed else ['cache'])
         # the first token samples at the row's own (seed, position)
         # counter — greedy defaults reproduce the classic argmax bitwise
         first = select_tokens(logits[0, length - 1], seed, position, temp,
                               topk, topp, mask)
+        if routed:
+            return first, state['cache'], _routing_of(state)
         return first, state['cache']
 
     return run
+
+
+def _in_layer_order(tree) -> dict:
+    """``{leaf name: [that leaf of every layer, first layer first]}`` of a
+    collection the layers ``sow`` into (``layer_10`` sorts after
+    ``layer_9``, not after ``layer_1``)."""
+    natural = lambda item: [int(part) if part.isdigit() else part for part
+                            in re.split(r'(\d+)',
+                                        jax.tree_util.keystr(item[0]))]
+    found: dict = {}
+    for path, leaf in sorted(jax.tree_util.tree_leaves_with_path(tree),
+                             key=natural):
+        found.setdefault(path[-1].key, []).append(leaf)
+    return found
+
+
+def _compact(chosen: np.ndarray) -> np.ndarray:
+    """Routing as a ``routing_sink`` gets it: a byte an expert where the
+    router is narrow enough."""
+    return chosen.astype(np.uint8) if chosen.max(initial=0) < 256 else chosen
+
+
+def _routing_of(state) -> jax.Array:
+    """``[tokens, expert layers, k]`` from the ``routing`` collection
+    (``ops.moe.GatedExperts``' ``chosen``, ``[tokens, k]`` a layer)."""
+    return jnp.stack(_in_layer_order(state['routing'])['chosen'], axis=1)
 
 
 @functools.cache
@@ -315,6 +353,8 @@ def _copy_winner_windows(cache, win_rows_wide, cursor, speculate: int,
             src = (src_phys * block + positions % block).reshape(-1)
             out = dict(node)
             for name in ('key', 'value'):
+                if name not in node:     # a latent pool has one KV leaf
+                    continue
                 pool = node[name]
                 out[name] = pool.at[dst].set(jnp.take(pool, src, axis=0))
             return out
@@ -354,6 +394,7 @@ class StepReport:
     report because eviction frees the row's state)."""
     emitted: dict
     finished: list                   # [(row, reason, tokens), ...]
+    expert_load: dict | None = None  # the tick's Engine.last_expert_load
 
 
 @dataclasses.dataclass
@@ -366,6 +407,8 @@ class _RowState:
     prior: tuple = ()                # tokens emitted in a previous life
     #                                  (replay prefix) — position and
     #                                  mask_fn both see prior + tokens
+    prompt: object = None            # kept only for a routing_sink, with
+    routing: list | None = None      # [positions, expert layers, k] pieces
 
     @property
     def sampled(self) -> bool:
@@ -425,6 +468,18 @@ class Engine:
             takes the manual shard_map path (decode's ``[rows, 1]``
             shapes typically fall back to GSPMD; prefill buckets may
             qualify).
+        routing_sink: ``routing_sink(tag, prompt, tokens, routing)`` is
+            called when a row retires, with the experts its expert
+            layers (``ops.moe.GatedExperts``) gave every position that
+            went through them: ``routing [len(prompt) + len(tokens) - 1,
+            expert layers, k]`` uint8 or int32, the prompt's positions
+            from the prefill program and each decoded token's input from
+            the tick's one read. What a caller needs to replay the
+            model with the same experts (a reference forward pass, a
+            trainer's routing replay). Plain admission only: it does not
+            compose with ``share_prefix`` (a shared prefix went through
+            the experts in another request's life), speculative rows, a
+            mesh, or admission of a prefill made elsewhere.
 
     The decode step traces exactly once per engine (``trace_count`` is
     the witness); admissions and evictions are host-side table edits
@@ -437,7 +492,8 @@ class Engine:
                  stream_dtype: str = 'auto', decode_impl: str = 'auto',
                  share_prefix: bool = False, draft_module=None,
                  draft_params=None, speculate: int = 4,
-                 tree_fanout: int = 1, mesh=None, schedule=None) -> None:
+                 tree_fanout: int = 1, mesh=None, schedule=None,
+                 routing_sink=None) -> None:
         reason = engine_unsupported_reason(module)
         if reason is not None:
             raise ValueError(f'the serving engine cannot run this module: '
@@ -490,11 +546,29 @@ class Engine:
         self.decode_impl = self._resolve_decode_impl(decode_impl)
         self.pool = PagedKVCache(rows, blocks, block_size, self.max_seq,
                                  share_prefix=share_prefix)
-        shapes = jax.eval_shape(
+        collections = jax.eval_shape(
             functools.partial(self._decoder.init, jax.random.PRNGKey(0)),
-            jnp.zeros((rows, 1), jnp.int32))['cache']
+            jnp.zeros((rows, 1), jnp.int32))
         self._cache = jax.tree.map(
-            lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), shapes)
+            lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+            collections['cache'])
+        # what the module's layers sow for the host beside the tokens: the
+        # names of their expert_load counters, and, for a routing_sink, the
+        # [expert layers, k] of the experts a token was given
+        self._load_names = tuple(sorted(_in_layer_order(
+            collections.get('expert_load', {}))))
+        self._routing_sink, self._routed = routing_sink, None
+        if routing_sink is not None:
+            chosen = _in_layer_order(
+                collections.get('routing', {})).get('chosen')
+            if (not chosen or share_prefix or self._spec
+                    or self.tp_plan.path == 'gspmd'
+                    or self.decode_impl != 'flax'):
+                raise ValueError(
+                    'routing_sink needs a module whose expert layers sow '
+                    'their choices (ops.moe.GatedExperts), on the plain '
+                    'flax step: no share_prefix, draft or mesh')
+            self._routed = (len(chosen), chosen[0].shape[-1])
         if self.tp_plan.path == 'gspmd':
             self._cache = jax.device_put(
                 self._cache, pool_shardings(
@@ -555,6 +629,15 @@ class Engine:
         # counted on the host from the seated rows' SamplingParams (the
         # device decides from the same temperatures, never read back)
         self.selection = {'greedy_ticks': 0, 'sampled_ticks': 0}
+        # what the expert layers of a module that counts its load
+        # (ops.moe.GatedExperts) were given, read with each tick's tokens
+        # under the names the layers sow them by, each summed over the
+        # layers: running sums over the ticks, and the last tick's own
+        # (None until a tick has counted; a module with no such layer
+        # never does)
+        self.expert_load = {'ticks': 0,
+                            **{name: 0 for name in self._load_names}}
+        self.last_expert_load = None
         # seated requests decoding with temperature > 0, kept at register
         # and evict (the observability plane's sampled-traffic gauge)
         self.sampled_rows = 0
@@ -607,7 +690,9 @@ class Engine:
                 logits, updated = self._decoder.apply(
                     {'params': _dequant(params, self._decoder),
                      'cache': cache},
-                    tokens[:, None], mutable=['cache'])
+                    tokens[:, None],
+                    mutable=['cache', 'expert_load']
+                    + (['routing'] if self._routed else []))
                 with jax.named_scope('select'):
                     token = select_tokens(logits[:, -1], seed, pos, temp,
                                           topk, topp, mask)
@@ -616,10 +701,23 @@ class Engine:
                 # walking off the table; active rows keep the cursor
                 # cached_attention advanced
                 cursor = read_cursor(cache)
-                return (token,
-                        rewind(updated['cache'],
-                               jnp.where(active, cursor + 1, 0)),
-                        jnp.where(active, pos + 1, pos))
+                out = (token,
+                       rewind(updated['cache'],
+                              jnp.where(active, cursor + 1, 0)),
+                       jnp.where(active, pos + 1, pos))
+                # expert layers that count their load (GatedExperts) hand
+                # the counts, and for a routing_sink their choices, to the
+                # host behind the tokens, in the one array the tick reads
+                # anyway
+                loads = _in_layer_order(updated.get('expert_load', {}))
+                behind = [jnp.stack([sum(loads[name])
+                                     for name in self._load_names])] \
+                    if self._load_names else []
+                if self._routed:
+                    behind.append(_routing_of(updated).reshape(-1))
+                if behind:
+                    out += (jnp.concatenate([token, *behind]),)
+                return out
 
         self._step = jax.jit(step_fn, donate_argnums=(1,))
 
@@ -905,13 +1003,14 @@ class Engine:
                 self._grammar_mask(sampling, list(emitted)))
 
     def _run_prefill(self, decoder, bucket: int, padded, length: int,
-                     ops=None):
-        try:
-            run = _compiled_prefill(decoder, bucket)
+                     ops=None, routed: bool = False):
+        try:      # the plain program under the key it has always had
+            run = _compiled_prefill(
+                *((decoder, bucket, True) if routed else (decoder, bucket)))
         except TypeError:        # unhashable module field (e.g. live mesh)
             run = self._prefills.setdefault(
                 (decoder is self._prefiller, bucket),
-                _build_prefill(decoder, bucket))
+                _build_prefill(decoder, bucket, routed))
         if ops is None:
             ops = self._greedy_ops(decoder.vocab_size)
         return run(self._params if decoder is self._prefiller
@@ -923,7 +1022,8 @@ class Engine:
         adopted a shareable prefix (and the suffix window fits), the
         plain full-prompt program otherwise. Returns the first token and
         the contiguous strip to adopt (valid at every prompt position at
-        or past each row's own shared depth)."""
+        or past each row's own shared depth) — and, for a
+        ``routing_sink``, the prompt's routing behind them."""
         shared = self.pool.shared_tokens(rows[0])
         suffix = prompt.size - shared
         if shared and shared + self.bucket(suffix) <= self.max_seq:
@@ -945,7 +1045,8 @@ class Engine:
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :prompt.size] = prompt
         return self._run_prefill(self._prefiller, bucket, padded,
-                                 prompt.size, ops)
+                                 prompt.size, ops,
+                                 routed=self._routed is not None)
 
     def _validate(self, prompt, max_new: int, sampling=None) -> None:
         if prompt.size < 1:
@@ -1014,7 +1115,7 @@ class Engine:
 
     def _register(self, rep: int, rows: list[int], prompt, first: int,
                   max_new: int, stop_token: int | None, tag,
-                  sampling=None, emitted=()) -> Admission:
+                  sampling=None, emitted=(), routing=None) -> Admission:
         """The host-side admission tail: sharing counters, row state,
         token/active mirrors, the seat program over the per-row device
         arrays, and the admitted-already-finished check."""
@@ -1048,6 +1149,9 @@ class Engine:
                                         stop=stop_token, tag=tag,
                                         sampling=sampling,
                                         prior=tuple(emitted))
+        if self._routed:
+            self._rowstate[rep].prompt = prompt
+            self._rowstate[rep].routing = [routing]
         self.sampled_rows += self._rowstate[rep].sampled
         reason = self._finish_reason(rep)
         if reason is not None:
@@ -1090,7 +1194,8 @@ class Engine:
         # times, so a device trace and the accumulators agree
         started = time.perf_counter()
         with annotate('tpusystem.engine.prefill'):
-            first, prefill_cache = self._prefill_rows(prompt, rows, ops)
+            first, prefill_cache, *routing = self._prefill_rows(
+                prompt, rows, ops)
             first = int(first)
         self.timings['prefill'] += time.perf_counter() - started
 
@@ -1109,8 +1214,10 @@ class Engine:
                     self._dcache, draft_cache, jnp.asarray(rows, jnp.int32),
                     prompt.size)
         self.timings['admit'] += time.perf_counter() - started
-        return self._register(rep, rows, prompt, first, max_new,
-                              stop_token, tag, sampling, emitted)
+        return self._register(
+            rep, rows, prompt, first, max_new, stop_token, tag, sampling,
+            emitted, routing=_compact(np.asarray(routing[0])[:prompt.size])
+            if routing else None)
 
     # ------------------------------------------------- disaggregated prefill
 
@@ -1191,6 +1298,10 @@ class Engine:
                 'admit_prefilled does not compose with speculative rows — '
                 'the draft cache has no handoff strip; disaggregate the '
                 'plain engine')
+        if self._routed:
+            raise ValueError(
+                'a routing_sink covers plain admission only: this prefill '
+                'went through the experts on another engine')
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         self._validate(prompt, max_new, sampling)
         prefill_cache = self._strip_cache(kv)     # validate BEFORE seating
@@ -1232,13 +1343,16 @@ class Engine:
         started = time.perf_counter()
         with annotate('tpusystem.engine.dispatch',
                       select=self._count_selection()):
-            token_dev, self._cache, self._pos_dev = self._step(
+            token_dev, self._cache, self._pos_dev, *counted = self._step(
                 self._params, self._cache, self._tokens_dev,
                 self._active_dev, self._seed_dev, self._pos_dev,
                 self._temp_dev, self._topk_dev, self._topp_dev,
                 self._mask_dev)
         with annotate('tpusystem.engine.read'):
-            token = np.asarray(token_dev)
+            token = np.asarray(counted[0] if counted else token_dev)
+        routing = None
+        if counted:
+            token, routing = self._split_read(token)
         # retired rows' stale device token stays as-is (in-vocab junk an
         # inactive row may keep embedding — masked, never emitted)
         self._tokens_dev = token_dev
@@ -1251,6 +1365,8 @@ class Engine:
                 self._tokens[row] = int(token[row])
                 emitted[row] = [int(token[row])]
                 state = self._rowstate[row]
+                if routing is not None:      # of the token this tick took in
+                    state.routing.append(routing[row][None])
                 state.tokens.append(int(token[row]))
                 reason = self._finish_reason(row)
                 if reason is not None:
@@ -1265,7 +1381,30 @@ class Engine:
                         state.sampling,
                         list(state.prior) + list(state.tokens))
                     self._mask_dev = self._mask_dev.at[row].set(mask)
-        return StepReport(emitted, finished)
+        return StepReport(emitted, finished, self.last_expert_load)
+
+    def _split_read(self, read: np.ndarray):
+        """The tick's read into its tokens and what the expert layers put
+        behind them: their counters, under the names they sow them by
+        (kept as the tick's own in ``last_expert_load`` and as running sums
+        in ``expert_load``), and for a ``routing_sink`` every row's
+        ``[expert layers, k]`` choices. Returns ``(tokens, routing |
+        None)``."""
+        names, routed = self._load_names, self._routed or (0, 0)
+        assert read.size == self.rows * (1 + routed[0] * routed[1]) \
+            + len(names), (read.size, self.rows, names, routed)
+        counts = read[self.rows:self.rows + len(names)]
+        if names:
+            self.last_expert_load = {name: int(count) for name, count
+                                     in zip(names, counts)}
+            self.expert_load['ticks'] += 1
+            for name, count in self.last_expert_load.items():
+                self.expert_load[name] += count
+        routing = None
+        if self._routed:
+            routing = read[self.rows + len(names):].reshape(
+                self.rows, *routed)
+        return read[:self.rows], routing
 
     def _count_selection(self) -> str:
         """Count the decode dispatch about to run on the side of
@@ -1353,6 +1492,9 @@ class Engine:
         self.sampled_rows -= state.sampled
         self._cache = write_tables(self._cache, self.pool.table)
         self._free_rows.append(row)
+        if self._routed:
+            self._routing_sink(state.tag, state.prompt, list(state.tokens),
+                               _compact(np.concatenate(state.routing)))
         return self._rowstate.pop(row)
 
     def tokens(self, row: int) -> list:

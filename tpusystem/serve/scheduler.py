@@ -525,6 +525,12 @@ class Scheduler:
             admitted, completed = self._admit()
 
         report = self.engine.step()
+        if self.tracer is not None and report.emitted \
+                and report.expert_load is not None:
+            # what the expert layers were given this tick (a module that
+            # counts it: StepReport.expert_load), one mark a tick
+            self.tracer.instant('expert_load', cat='engine',
+                                args=dict(report.expert_load))
         emitted = {}
         for row, tokens in report.emitted.items():
             if row in self._seated:

@@ -65,8 +65,10 @@ def fused_paged_reason(decoder) -> str | None:
     (:func:`build_fused_paged_step`) cannot run this decode clone, or
     ``None`` when it can. The paged step owns per-row cursors and the
     block-table scatter write — the gates are the step-math ones (GPT-2
-    dense, unrolled), the TP mesh (no ring arms yet) and the pool shapes
-    the paged-attention kernel can tile."""
+    dense, unrolled: Llama, MoE stacks and the latent-attention DeepSeekV2
+    decoder take the flax paged step, and the message says why), the TP
+    mesh (no ring arms yet) and the pool shapes the paged-attention kernel
+    can tile."""
     from tpusystem.models.gpt2 import GPT2
     mesh = getattr(decoder, 'mesh', None)
     if mesh is not None and dict(getattr(mesh, 'shape', {})).get(
@@ -76,8 +78,18 @@ def fused_paged_reason(decoder) -> str | None:
                 "decode_impl='auto' serves through the sharded flax "
                 'paged step (token-exact vs single-device)')
     if not isinstance(decoder, GPT2):
-        return ('the fused paged step implements the GPT2 family only '
-                f'(got {type(decoder).__name__})')
+        reason = ('the fused paged step implements the GPT2 family only '
+                  f'(got {type(decoder).__name__})')
+        if hasattr(decoder, 'kv_rank'):
+            # a latent-attention decoder (DeepSeekV2): nothing of the
+            # chain fits, so 'auto' serves it through the flax paged step
+            reason += (": a latent-attention decoder's pool row is one "
+                       'latent [kv_rank + rope], not a key and a value per '
+                       'head (the paged-attention kernel reads those), its '
+                       'attention is absorbed into the query and the '
+                       'output, and its FFN is a gated grouped expert '
+                       "product — the engine's flax paged step serves it")
+        return reason
     if decoder.scan_layers:
         return ('scan_layers stacks params under a leading layer dim the '
                 'fused per-layer sweep does not walk')

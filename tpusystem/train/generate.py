@@ -77,26 +77,43 @@ def _stream_params(decoder, params, stream_dtype: str):
     return _quantizer(stream_dtype)(params)
 
 
-@functools.cache
+def _is_cast(path, leaf) -> bool:
+    """Whether streaming casts this leaf. Leaves the model consumes at
+    f32 stay f32: embedding tables (the embed step ADDS wte+wpe rows in
+    f32 before casting; the scan-hoisted head cast keeps the head matmul
+    bf16 anyway) and MoE routers (gate logits are an f32 matmul — a
+    bf16-rounded router could flip near-tie expert choices). Only f32
+    matrices are cast: a leaf handed narrower already is streamed as it
+    is."""
+    from tpusystem.parallel.sharding import leaf_path
+    path = leaf_path(path)
+    if 'embedding' in path or 'router' in path:
+        return False
+    return leaf.ndim >= 2 and leaf.dtype == jnp.float32
+
+
 def _caster(compute_name: str):
+    """The cast to a target dtype: the tree itself, buffer for buffer,
+    when no leaf is cast (a 10 GB tree handed in bfloat16 has no room
+    for a copy, and a jitted identity would make one), else one cached
+    jitted program (:func:`_cast_program`)."""
+    def cast(params):
+        if not any(_is_cast(path, leaf) for path, leaf
+                   in jax.tree_util.tree_leaves_with_path(params)):
+            return params
+        return _cast_program(compute_name)(params)
+    return cast
+
+
+@functools.cache
+def _cast_program(compute_name: str):
     """One cached jitted cast program per target dtype: per-leaf eager
     casts would pay a host dispatch each (~60 per generate() call), and
     an uncached jit would *retrace and recompile* the cast every call."""
     compute = jnp.dtype(compute_name)
 
     def cast(path, leaf):
-        # leaves the model consumes at f32 must stay f32: embedding
-        # tables (the embed step ADDS wte+wpe rows in f32 before
-        # casting; the scan-hoisted head cast keeps the head matmul
-        # bf16 anyway) and MoE routers (gate logits are an f32 matmul —
-        # a bf16-rounded router could flip near-tie expert choices)
-        from tpusystem.parallel.sharding import leaf_path
-        path = leaf_path(path)
-        if 'embedding' in path or 'router' in path:
-            return leaf
-        if leaf.ndim >= 2 and leaf.dtype == jnp.float32:
-            return leaf.astype(compute)
-        return leaf
+        return leaf.astype(compute) if _is_cast(path, leaf) else leaf
 
     return jax.jit(functools.partial(jax.tree_util.tree_map_with_path, cast))
 
