@@ -293,13 +293,15 @@ def latent_reference(query, pool, table, cursor, block, rank, width, scale):
 
 @pytest.mark.parametrize('dtype, tolerance', [(jnp.float32, 2e-5),
                                               (jnp.bfloat16, 2e-2)])
-def test_latent_kernel_reads_each_row_to_its_own_depth(dtype, tolerance):
+def test_latent_kernel_reads_each_row_to_its_own_depth(dtype, tolerance,
+                                                       monkeypatch):
     """Ragged depths over shuffled blocks, two rows sharing a block, a
     parked row on the trash block, a row that fills its whole table: the
     chunk walk (two chunks of four blocks here) skips what a row does not
     hold and masks inside its last chunk. The pool's rows are padded to
     whole lanes, and the padding is never attended."""
     from tpusystem.ops.pallas import latent_attention as kernel_module
+    monkeypatch.setattr(kernel_module, 'CHUNK_POSITIONS', 16)
     rows, heads, rank, rope, block, max_blocks = 5, 8, 32, 8, 4, 8
     width, lanes = rank + rope, 128
     keys = jax.random.split(jax.random.PRNGKey(3), 3)
@@ -322,15 +324,81 @@ def test_latent_kernel_reads_each_row_to_its_own_depth(dtype, tolerance):
                                atol=tolerance, rtol=tolerance)
 
 
+# four rows over tables of eight blocks of four positions, walked two
+# chunks of four blocks (16 positions) to a full table
+LATENT_WALKS = {
+    'a depth ends exactly on a chunk boundary': dict(cursor=[15, 31, 16, 7]),
+    # the full row's last chunk prefetches the next row's first, of one block
+    'depth 1 straight after a full table': dict(cursor=[31, 0, 31, 0]),
+    'every row parked': dict(cursor=[0, 0, 0, 0], parked=(0, 1, 2, 3)),
+    'two rows share all their blocks': dict(cursor=[29, 29, 11, 20],
+                                            shared=True),
+    'unheld blocks hold inf': dict(cursor=[13, 0, 31, 18], poisoned=True),
+}
+
+
+@pytest.mark.parametrize('walk', LATENT_WALKS)
+def test_the_latent_walk_fetches_a_rows_own_blocks_and_no_others(
+        walk, monkeypatch):
+    """The double-buffered window at its edges, against
+    ``latent_reference``. With ``inf`` in every block no row holds (the
+    trash block too) nothing but zeros may stand in the window past a
+    row's last held block: such a block is never fetched, the window was
+    zeroed once, and a position past the cursor inside a held block is
+    finite (as in a real pool) and weighs exactly zero."""
+    from tpusystem.ops.pallas import latent_attention as kernel_module
+    monkeypatch.setattr(kernel_module, 'CHUNK_POSITIONS', 16)
+    case = LATENT_WALKS[walk]
+    rows, heads, rank, rope, block, max_blocks = 4, 8, 32, 8, 4, 8
+    width, lanes = rank + rope, 128
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    pool = jnp.pad(jax.random.normal(keys[0], (33 * block, width)),
+                   ((0, 0), (0, lanes - width)), constant_values=7.0)
+    query = jax.random.normal(keys[1], (rows, heads, width))
+    table = np.asarray(jax.random.permutation(keys[2], 32)).reshape(
+        rows, max_blocks).astype(np.int32) + 1
+    cursor = np.array(case['cursor'], np.int32)
+    for row in case.get('parked', ()):
+        table[row] = 0
+    if case.get('shared'):
+        table[1] = table[0]
+        query = query.at[1].set(query[0])
+    if case.get('poisoned'):
+        held = np.zeros(33, bool)
+        for row in range(rows):
+            held[table[row, :cursor[row] // block + 1]] = True
+        pool = jnp.where(jnp.repeat(jnp.asarray(held), block)[:, None],
+                         pool, jnp.inf)
+    got = np.asarray(kernel_module.paged_latent_attention(
+        query, pool, jnp.asarray(table), jnp.asarray(cursor), rank=rank,
+        width=width, block=block, scale=0.2, interpret=True))
+    want = latent_reference(query, pool, table, cursor, block, rank, width,
+                            0.2)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    if case.get('shared'):
+        np.testing.assert_array_equal(got[0], got[1])
+    for row in case.get('parked', ()):       # one position: its own content
+        np.testing.assert_allclose(
+            got[row], np.broadcast_to(np.asarray(pool[0, :rank]),
+                                      (heads, rank)), atol=1e-6)
+
+
 def test_latent_plan_refuses_what_the_tpu_cannot_tile():
     from tpusystem.ops.pallas.latent_attention import (latent_plan,
                                                        paged_latent_attention)
-    assert latent_plan(128, 512, 16, 320, jnp.bfloat16, False) == 16
+    assert latent_plan(128, 512, 16, 320, jnp.bfloat16, False) == 32
+    assert latent_plan(128, 512, 16, 320, jnp.bfloat16, False, 640) == 32
     assert latent_plan(128, 512, 16, 4, jnp.bfloat16, False) == 4
     assert latent_plan(4, 16, 16, 8, jnp.float32, True) == 8   # interpreted
     for heads, rank, block in ((128, 500, 16), (12, 512, 16), (128, 512, 8)):
         assert latent_plan(heads, rank, block, 320, jnp.bfloat16,
                            False) is None
+    # what does not fit VMEM: a window of one 4096-position block, the
+    # query and result blocks of 4096 heads, rows stored on 8192 lanes
+    assert latent_plan(128, 512, 4096, 4, jnp.bfloat16, False) is None
+    assert latent_plan(4096, 512, 16, 320, jnp.bfloat16, False) is None
+    assert latent_plan(128, 512, 16, 320, jnp.bfloat16, False, 8192) is None
+    assert latent_plan(128, 512, 4096, 4, jnp.bfloat16, True) == 1
     with pytest.raises(ValueError, match='cannot tile'):
         paged_latent_attention(
             jnp.zeros((2, 12, 576), jnp.bfloat16),
@@ -375,9 +443,10 @@ def test_the_engine_reads_the_latent_pool_through_the_kernel(monkeypatch):
 def test_the_latent_kernel_compiles_for_a_v5e_at_serving_widths(one_chip):
     """DeepSeek-V2's share as the cell serves it: 64 rows x 128 heads over
     rows of 576 stored on 640 lanes, block 16, 320 table columns. Mosaic
-    takes it, and the pool goes in as stored: no copy or transpose of a
-    pool-shaped operand (a 576-wide pool is kept slot-minor by the TPU and
-    transposed whole around every use: why the rows are padded)."""
+    takes it, and the pool goes in once and as stored: one pool-shaped
+    operand of the call, no copy or transpose of one around it (a 576-wide
+    pool is kept slot-minor by the TPU and transposed whole around every
+    use: why the rows are padded)."""
     from tpusystem.ops.pallas.latent_attention import paged_latent_attention
     rows, heads, block, max_blocks = 64, 128, 16, 320
     slots = (rows * max_blocks + 1) * block
@@ -399,6 +468,8 @@ def test_the_latent_kernel_compiles_for_a_v5e_at_serving_widths(one_chip):
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1
     assert '%paged_latent_attention' in calls[0].split(' = ')[0]
+    operands = calls[0].split('operand_layout_constraints={')[1].split('}}')[0]
+    assert operands.count(f'bf16[{slots},640]') == 1, operands
     moved = [line for line in compiled.splitlines()
              if f'bf16[{slots},640]' in line.split(' = ')[-1].split('(')[0]
              and 'parameter(' not in line]

@@ -5,27 +5,37 @@ The decode path of :func:`tpusystem.ops.attention.latent_attention`
 attends one new token per row over a paged pool whose row is one latent
 ``[c_kv (rank) ; k_rope]`` shared by every head, with the up-projection
 absorbed into the query. :func:`paged_latent_attention` reads that pool
-where it lies: the grid is ``(rows, chunks)``; the table and the cursors
-are scalar-prefetch operands, and each of a chunk's blocks is a block
-operand of its own whose index map looks the physical block up in the
-row's table, so the pipeline fetches the next chunk's blocks while this
-one is attended and nothing is gathered into HBM first. Past a row's
-cursor the maps stay on its last filled block: an operand whose block
-does not change is not fetched again, and the chunk's arithmetic is
-skipped, so a row pays for the positions it holds (to the chunk), not for
-the deepest row's bucket. Flash's online softmax carries a running
-maximum, denominator and accumulator in float32 across a row's chunks.
+where it lies, on the walk of its sibling
+:func:`tpusystem.ops.pallas.paged_attention.paged_decode_attention`:
+
+* the grid is ``(rows,)``; the pool stays in HBM (``memory_space=
+  pl.ANY``) and goes in once; the table and the cursors are
+  scalar-prefetch operands, so every address is known before a row starts;
+* for each row the kernel walks that row's own table columns ``0 …
+  cursor // block`` in a ``fori_loop`` over its chunks and DMAs those
+  blocks, one contiguous ``[block, lanes]`` run each, and no others,
+  into a double-buffered VMEM window of ``CHUNK_POSITIONS`` rows, the
+  next chunk (or the next row's first) in flight while the current one is
+  attended. A row pays nothing for chunks past its cursor, not even a
+  grid step;
+* flash's online softmax carries a running maximum, denominator and
+  accumulator in float32 across a row's chunks. Positions past the cursor
+  inside the last chunk are masked; blocks past it are never fetched (the
+  window is zeroed once, so what a block never fetched leaves there is
+  finite and its probability is exactly zero). A parked row (cursor 0 on
+  the trash block) reads one position.
 
 Every head attends the same rows, so a chunk's scores are one product
 ``Q [heads, rank + rope] · chunk^T`` (taken as the content part plus the
-rope part, both starting on a lane boundary) and its mix ``P · chunk[:,
-:rank]``. The pool is read as stored, ``[slots, lanes]`` with the rows
-padded to whole lanes (:func:`tpusystem.ops.attention.latent_attention`
-says why).
+rope part, both starting on a lane boundary, so the lanes that pad a
+stored row never enter a product) and its mix ``P · chunk[:, :rank]``,
+both straight from the window as it lies. The pool is read as stored,
+``[slots, lanes]`` with the rows padded to whole lanes
+(:func:`tpusystem.ops.attention.latent_attention` says why).
 
 ``interpret=None`` auto-selects interpreter mode off-TPU;
 :func:`latent_plan` answers from shapes alone whether the TPU can tile
-them (``None``: the caller keeps its XLA read).
+them and hold the window (``None``: the caller keeps its XLA read).
 """
 
 from __future__ import annotations
@@ -37,49 +47,93 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpusystem.ops.pallas import auto_interpret
+from tpusystem.ops.pallas import auto_interpret, streamed_from_hbm
 
 NEG_INF = -1e30
 LANES = 128
-CHUNK_POSITIONS = 256       # latent rows attended per grid step
+CHUNK_POSITIONS = 512       # latent rows in one window: PERF.md §6, PR 33
+VMEM_BYTES = 12 * 2 ** 20   # of the 16 MiB a kernel may scope on a v5e
 
 
 def latent_plan(heads: int, rank: int, block: int, max_blocks: int, dtype,
-                interpret: bool) -> int | None:
+                interpret: bool, lanes: int | None = None) -> int | None:
     """How many table columns one chunk walks, or ``None`` where the TPU
     cannot run these shapes: the content part (``rank``) must fill whole
-    lanes (the rope part then starts on a lane boundary), the heads whole
-    sublane tiles of the query, and a block whole sublane tiles of
-    ``dtype`` (each block is a block operand of its own). Interpret mode
-    has no tiling constraints."""
+    lanes, the heads whole sublane tiles of the query, a block whole
+    sublane tiles of ``dtype`` (each block is one DMA into the window),
+    and what the kernel keeps in VMEM (the double window, a row's query
+    and result blocks twice, the accumulator, a chunk's scores) must fit
+    there. ``lanes`` is the stored width of a pool row where the caller
+    knows it; left out, the least a pool of this rank has (one lane tile
+    of rope after the content). Interpret mode has no constraints."""
+    chunk = max(1, min(max_blocks, CHUNK_POSITIONS // block))
     if not interpret:
-        sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+        itemsize = jnp.dtype(dtype).itemsize
+        sublanes = 8 * max(1, 4 // itemsize)
         if rank % LANES or heads % sublanes or block % sublanes:
             return None
-    return max(1, min(max_blocks, CHUNK_POSITIONS // block))
+        lanes, span = lanes or rank + LANES, chunk * block
+        kept = (2 * span * lanes * itemsize
+                + 2 * heads * (lanes + rank) * itemsize
+                + heads * rank * 4 + 3 * heads * span * 4)
+        if kept > VMEM_BYTES:
+            return None
+    return chunk
 
 
-def _kernel(table_ref, cursor_ref, q_ref, *refs, rank: int, width: int,
+def _kernel(table_ref, cursor_ref, q_ref, pool_hbm, out_ref, window, acc,
+            top, denom, slot_ref, sems, *, rank: int, width: int,
             block: int, chunk: int, max_seq: int, scale: float):
-    del table_ref                     # the index maps read it
-    blocks, (out_ref, acc, top, denom) = refs[:chunk], refs[chunk:]
-    row, index = pl.program_id(0), pl.program_id(1)
-    span = chunk * block
-    depth = jnp.minimum(cursor_ref[row] + 1, max_seq)
+    row, rows = pl.program_id(0), pl.num_programs(0)
+    span = chunk * block                       # positions per chunk
 
-    @pl.when(index == 0)
-    def _start():
-        acc[...] = jnp.zeros_like(acc)
-        top[...] = jnp.full_like(top, NEG_INF)
-        denom[...] = jnp.zeros_like(denom)
+    def depth_of(r):        # positions row r holds, this step's included
+        return jnp.minimum(cursor_ref[r] + 1, max_seq)
 
-    @pl.when(index * span < depth)
-    def _attend():
-        query = q_ref[...]                               # [heads, width]
-        latent = jnp.concatenate([ref[...] for ref in blocks],
-                                 axis=0).astype(query.dtype)
-        content = latent[:, :rank]                       # [span, rank]
-        transposed = (((1,), (1,)), ((), ()))
+    def move(r, index, slot, start: bool):
+        """Start (or wait for) the DMAs of chunk ``index`` of row ``r``
+        into window ``slot``: one per table column the row has filled."""
+        first = index * chunk
+        filled = jnp.clip(pl.cdiv(depth_of(r), block) - first, 0, chunk)
+
+        def one(offset, carry):
+            source = pl.multiple_of(table_ref[r, first + offset] * block,
+                                    block)
+            target = pl.multiple_of(offset * block, block)
+            copy = pltpu.make_async_copy(
+                pool_hbm.at[pl.ds(source, block)],
+                window.at[slot, pl.ds(target, block)], sems.at[slot])
+            copy.start() if start else copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, filled, one, None)
+
+    @pl.when(row == 0)
+    def _prime():
+        window[...] = jnp.zeros_like(window)
+        slot_ref[0] = 0
+        move(0, 0, 0, start=True)
+
+    query = q_ref[...]                                   # [heads, width]
+    acc[...] = jnp.zeros_like(acc)
+    top[...] = jnp.full_like(top, NEG_INF)
+    denom[...] = jnp.zeros_like(denom)
+    depth = depth_of(row)
+    chunks = pl.cdiv(depth, span)
+    transposed = (((1,), (1,)), ((), ()))
+
+    def attend(index, carry):
+        slot = slot_ref[0]
+        more = index + 1 < chunks       # else: the next row's first chunk
+
+        @pl.when(more | (row + 1 < rows))
+        def _prefetch():
+            move(jnp.where(more, row, jnp.minimum(row + 1, rows - 1)),
+                 jnp.where(more, index + 1, 0), 1 - slot, start=True)
+
+        move(row, index, slot, start=False)
+        latent = window[slot].astype(query.dtype)        # [span, lanes]
+        content = latent[:, :rank]
         scores = (jax.lax.dot_general(
             query[:, :rank], content, transposed,
             preferred_element_type=jnp.float32)
@@ -99,10 +153,11 @@ def _kernel(table_ref, cursor_ref, q_ref, *refs, rank: int, width: int,
             weights.astype(query.dtype), content, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         top[...] = after
+        slot_ref[0] = 1 - slot
+        return carry
 
-    @pl.when(index == pl.num_programs(1) - 1)
-    def _finish():
-        out_ref[...] = (acc[...] / denom[...]).astype(out_ref.dtype)
+    jax.lax.fori_loop(0, chunks, attend, 0)
+    out_ref[...] = (acc[...] / denom[...]).astype(out_ref.dtype)
 
 
 def paged_latent_attention(query, pool, table, cursor, *, rank: int,
@@ -115,8 +170,8 @@ def paged_latent_attention(query, pool, table, cursor, *, rank: int,
             already carried through the key half of the up-projection
             (``rank`` wide), then its rotated rope query.
         pool: ``[slots, lanes]`` as stored (``lanes >= width``: the rows
-            padded to whole lanes), this step's rows already written. Only
-            the blocks a row holds are read.
+            padded to whole lanes), this step's rows already written. Left
+            in HBM; only the blocks a row holds are read.
         table: ``[rows, max_blocks]`` int32 physical block per logical
             block (unmapped columns point at the trash block).
         cursor: ``[rows]`` int32 position of this step's token; a row
@@ -134,45 +189,43 @@ def paged_latent_attention(query, pool, table, cursor, *, rank: int,
     if query.shape[2] != width or lanes < width:
         raise ValueError(f'query {query.shape} and pool {pool.shape} do not '
                          f'hold rows of {width}')
-    chunk = latent_plan(heads, rank, block, max_blocks, pool.dtype, interpret)
+    chunk = latent_plan(heads, rank, block, max_blocks, pool.dtype, interpret,
+                        lanes)
     if chunk is None:
         raise ValueError(
             f'paged_latent_attention cannot tile heads={heads} rank={rank} '
-            f'block={block} {pool.dtype} on the TPU')
-    max_seq = max_blocks * block
-
-    def held(offset: int):
-        """The physical block behind column ``chunk * index + offset`` of
-        the row's table, or its last filled block past the cursor."""
-        def index_map(row, index, table_ref, cursor_ref):
-            last = jnp.minimum(cursor_ref[row], max_seq - 1) // block
-            return table_ref[row, jnp.minimum(index * chunk + offset,
-                                              last)], 0
-        return pl.BlockSpec((block, lanes), index_map)
-
+            f'block={block} lanes={lanes} {pool.dtype} on the TPU')
+    span, max_seq = chunk * block, max_blocks * block
     per_row = lambda minor: pl.BlockSpec((None, heads, minor),
-                                         lambda row, index, *_: (row, 0, 0))
+                                         lambda row, *_: (row, 0, 0))
     kernel = functools.partial(_kernel, rank=rank, width=width, block=block,
                                chunk=chunk, max_seq=max_seq, scale=scale)
-    positions = rows * max_seq
+    # a row sits anywhere between an empty table and a full one
+    positions = rows * max_seq // 2
+    itemsize = jnp.dtype(pool.dtype).itemsize
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(rows, pl.cdiv(max_blocks, chunk)),
-            in_specs=[per_row(width)] + [held(offset)
-                                         for offset in range(chunk)],
+            grid=(rows,),
+            in_specs=[per_row(width), pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=per_row(rank),
-            scratch_shapes=[pltpu.VMEM((heads, rank), jnp.float32),
-                            pltpu.VMEM((heads, 1), jnp.float32),
-                            pltpu.VMEM((heads, 1), jnp.float32)]),
+            scratch_shapes=[
+                pltpu.VMEM((2, span, lanes), pool.dtype),
+                pltpu.VMEM((heads, rank), jnp.float32),
+                pltpu.VMEM((heads, 1), jnp.float32),
+                pltpu.VMEM((heads, 1), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ]),
         out_shape=jax.ShapeDtypeStruct((rows, heads, rank), query.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('arbitrary', 'arbitrary')),
+            dimension_semantics=('arbitrary',)),
         cost_estimate=pl.CostEstimate(
             flops=2 * positions * heads * (width + rank),
-            bytes_accessed=positions * lanes * jnp.dtype(pool.dtype).itemsize,
+            bytes_accessed=(positions * lanes
+                            + rows * heads * (width + rank)) * itemsize,
             transcendentals=positions * heads),
         interpret=interpret,
         name='paged_latent_attention',
-    )(table, cursor, query, *([pool] * chunk))
+    )(table, cursor, query, streamed_from_hbm(pool, interpret))
