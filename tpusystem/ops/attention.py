@@ -155,6 +155,8 @@ def paged_attention(module, query, key, value, max_seq: int,
     output is independent of its co-batched traffic in
     window-length-invariant arithmetic (f32; the same caveat as
     speculative verify applies at the TPU MXU's default precision).
+    Scopes, as :func:`latent_attention`'s: ``kv_write`` (the scatter) and
+    ``kv_read`` (the gather and the attention over it).
     """
     num_blocks, block = pages
     if max_seq % block:
@@ -182,11 +184,13 @@ def paged_attention(module, query, key, value, max_seq: int,
     logical = jnp.minimum(positions // block, max_blocks - 1)
     physical = jnp.take_along_axis(table.value, logical, axis=1)
     slots = (physical * block + positions % block).reshape(-1)  # [B*L]
-    cache_key.value = cache_key.value.at[slots].set(
-        key.reshape(-1, kv_heads * head_dim).astype(cache_key.value.dtype))
-    cache_value.value = cache_value.value.at[slots].set(
-        value.reshape(-1, kv_heads * head_dim).astype(
-            cache_value.value.dtype))
+    with jax.named_scope('kv_write'):
+        cache_key.value = cache_key.value.at[slots].set(
+            key.reshape(-1, kv_heads * head_dim).astype(
+                cache_key.value.dtype))
+        cache_value.value = cache_value.value.at[slots].set(
+            value.reshape(-1, kv_heads * head_dim).astype(
+                cache_value.value.dtype))
     index.value = cursor + length
 
     # bucketed block-window read: gather the first `width` table columns'
@@ -213,9 +217,10 @@ def paged_attention(module, query, key, value, max_seq: int,
     # nearly free, so fine-grained switching buys little); the paged
     # read is a GATHER whose cost is proportional to the window, so it
     # starts at 64 tokens — shallow rows read 4x less pool
-    return _switch_on_depth(_read_buckets(max(1, 64 // block), max_blocks),
-                            (jnp.max(positions) + block) // block,
-                            attend_over)
+    with jax.named_scope('kv_read'):
+        return _switch_on_depth(
+            _read_buckets(max(1, 64 // block), max_blocks),
+            (jnp.max(positions) + block) // block, attend_over)
 
 
 def cached_attention(module, query, key, value, max_seq: int,
@@ -557,8 +562,26 @@ def dot_product_attention(query, key, value, *, causal: bool = True,
     ``dropout`` > 0 (with ``dropout_rng``) drops attention probabilities.
     """
     input_dtype = query.dtype
-    head_dim = query.shape[-1]
+    batch, length, heads, head_dim = query.shape
+    kv_heads = key.shape[2]
     scale = scale if scale is not None else head_dim ** -0.5
+    if (kv_heads != heads and not causal and dropout == 0.0
+            and mask is not None and mask.ndim == 4):
+        # grouped queries over a cache window (the decode read): the query
+        # heads of a group go against their one key/value head as they are
+        # stored — a [group, head_dim] x [head_dim, keys] product a head —
+        # and nothing repeats the window's keys and values per query head
+        # (at 32 query heads on 2 that is 16 copies of every cached row)
+        assert heads % kv_heads == 0, (heads, kv_heads)
+        group = heads // kv_heads
+        scores = jnp.einsum(
+            'bqhgd,bkhd->bhgqk',
+            query.reshape(batch, length, kv_heads, group, head_dim), key,
+            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(mask[:, :, None], scores, NEG_INF)
+        weights = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum('bhgqk,bkhd->bqhgd', weights.astype(input_dtype),
+                          value).reshape(batch, length, heads, head_dim)
     key, value = repeat_kv_heads(query, key, value)
 
     scores = jnp.einsum('bqhd,bkhd->bhqk', query, key,

@@ -1072,6 +1072,23 @@ def group_limited_top_k(scores: jax.Array, k: int, groups: int,
     return ids.astype(jnp.int32), weights
 
 
+def corrected_top_k(scores: jax.Array, correction: jax.Array, k: int):
+    """Top-k by a corrected score, weighed by the uncorrected one (the
+    ``nemotron_h`` family's router, DeepSeek-V3's ``noaux_tc`` with one
+    group).
+
+    ``scores`` is ``[tokens, experts]`` (sigmoid scores, float32) and
+    ``correction [experts]`` the router's learned per-expert term: the ``k``
+    chosen are the largest of ``scores + correction``, and the term enters
+    nothing else. Returns ``(ids, weights)``, both ``[tokens, k]``: the
+    weights are the chosen experts' own scores over their sum (+ 1e-20), so
+    they add up to 1. Ties go to the lower index (``lax.top_k``)."""
+    _, ids = lax.top_k(scores + correction.astype(scores.dtype), k)
+    chosen = jnp.take_along_axis(scores, ids, axis=-1)
+    return ids.astype(jnp.int32), chosen / (
+        jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+
+
 def seat_held(ids: jax.Array, start: int, count: int):
     """Seat the assignments that fall on the ``count`` experts held here
     (``start .. start + count - 1``), sorted by expert.
@@ -1095,17 +1112,33 @@ def seat_held(ids: jax.Array, start: int, count: int):
 class GatedExperts(nn.Module):
     """A bias-free expert layer told which experts it holds.
 
-    The router scores **all** ``experts`` (softmax in float32, from the
-    router matrix as handed, whatever its type), picks ``k`` by
-    :func:`group_limited_top_k` and weighs each by ``scale`` times its
-    score, not renormalised. ``held = (start, count)`` names the experts
+    The router scores **all** ``experts`` in float32, from the router
+    matrix as handed, whatever its type. ``scoring='softmax'`` (DeepSeek-V2)
+    picks ``k`` by :func:`group_limited_top_k` and weighs each by ``scale``
+    times its softmax score, not renormalised; ``scoring='sigmoid'`` (the
+    ``nemotron_h`` family) picks by :func:`corrected_top_k` — sigmoid scores
+    plus the learned ``correction`` leaf for the choice only — and weighs
+    each by ``scale`` times its own score over the chosen ones' sum.
+    ``held = (start, count)`` names the experts
     whose matrices live here (``None``: all of them); the layer sums
     ``w_e * E_e(h)`` over a token's chosen experts *that it holds* — what
     the others would have added is left out, as on one chip of an
     expert-parallel deployment before the exchange — plus the shared
     experts ``S(h)`` (one gated MLP of ``shared_width``; 0: none), which
-    every holder computes alike. ``E(h) = down(silu(gate h) * up h)`` at
+    every holder computes alike. ``form='gated'``: ``E(h) = down(silu(gate
+    h) * up h)``, three matrices; ``form='relu2'``: ``E(h) = down(relu(up
+    h)²)``, two, and no ``gate`` leaf — routed and shared alike, at
     ``width``, which need not be a multiple of the model's width.
+    ``pad_to`` (0: as they are) stores the routed experts' matrices with
+    both their dimensions, the model's and ``width``, rounded up to whole
+    multiples of it; what lies in the padding is read by nothing (the rows
+    go in zero-padded, the padded columns of the hidden activation are
+    zeroed, the padded output columns dropped). It buys the grouped
+    product's kernel dimensions it tiles well: on a v5e a ``[576, 2688] x
+    [32, 2688, 1856]`` product takes 4.2 ms and ``[576, 2816] x [32, 2816,
+    2048]`` 0.86 ms (a minor dimension that is not lane-dense, 1856, is
+    besides kept off the minor position by the TPU, and the whole stack
+    copied every call).
 
     Work and memory follow the assignments seated here, not ``experts x
     tokens``: the (token, choice) pairs on held experts are sorted by
@@ -1115,8 +1148,8 @@ class GatedExperts(nn.Module):
     rows. No capacity, no dropped token at any batch, and a row's output
     does not depend on what is batched with it.
 
-    Scopes (``jax.named_scope``): ``router`` (scores, group limit, top-k,
-    the sort), ``experts`` (the gathers, the grouped products, the weighted
+    Scopes (``jax.named_scope``): ``router`` (scores, the choice, the
+    sort), ``experts`` (the gathers, the grouped products, the weighted
     sum), ``shared``. Counters: where the caller makes the ``expert_load``
     collection mutable, ``sow`` leaves one int32 scalar under each of
     :attr:`LOAD` for this call (``seated``: assignments seated here;
@@ -1138,9 +1171,18 @@ class GatedExperts(nn.Module):
     shared_width: int = 0
     held: tuple | None = None       # (first expert held, how many)
     dtype: jnp.dtype = jnp.bfloat16
+    scoring: str = 'softmax'        # | 'sigmoid' (corrected top-k)
+    form: str = 'gated'             # | 'relu2' (two matrices, no gate)
+    pad_to: int = 0                 # routed matrices' dims, rounded up
 
     @nn.compact
     def __call__(self, hidden):
+        if self.scoring not in ('softmax', 'sigmoid') \
+                or self.form not in ('gated', 'relu2'):
+            raise ValueError(f'scoring={self.scoring!r}, form={self.form!r}: '
+                             "expected 'softmax' | 'sigmoid' and 'gated' | "
+                             "'relu2'")
+        gated = self.form == 'gated'
         batch_shape, dim = hidden.shape[:-1], hidden.shape[-1]
         start, count = self.held if self.held is not None \
             else (0, self.experts)
@@ -1151,9 +1193,16 @@ class GatedExperts(nn.Module):
         init = nn.initializers.lecun_normal()
         router = self.param('router', nn.initializers.normal(0.02),
                             (dim, self.experts), jnp.float32)
-        gate = self.param('gate', init, (count, dim, self.width), jnp.float32)
-        up = self.param('up', init, (count, dim, self.width), jnp.float32)
-        down = self.param('down', init, (count, self.width, dim), jnp.float32)
+        if self.scoring == 'sigmoid':
+            correction = self.param('correction', nn.initializers.zeros,
+                                    (self.experts,), jnp.float32)
+        whole = lambda size: -(-size // self.pad_to) * self.pad_to \
+            if self.pad_to else size
+        wide, deep = whole(dim), whole(self.width)
+        if gated:
+            gate = self.param('gate', init, (count, wide, deep), jnp.float32)
+        up = self.param('up', init, (count, wide, deep), jnp.float32)
+        down = self.param('down', init, (count, deep, wide), jnp.float32)
         flat = hidden.reshape(-1, dim)
         tokens = flat.shape[0]
 
@@ -1164,9 +1213,13 @@ class GatedExperts(nn.Module):
                              router.astype(jnp.float32),
                              precision=lax.Precision.HIGHEST)
             flat = flat.astype(compute)
-            ids, weights = group_limited_top_k(
-                jax.nn.softmax(logits, axis=-1), self.k, self.groups,
-                self.keep_groups)
+            if self.scoring == 'sigmoid':
+                ids, weights = corrected_top_k(jax.nn.sigmoid(logits),
+                                               correction, self.k)
+            else:
+                ids, weights = group_limited_top_k(
+                    jax.nn.softmax(logits, axis=-1), self.k, self.groups,
+                    self.keep_groups)
             order, held, sizes = seat_held(ids, start, count)
             token_of = order // self.k
             weight_of = jnp.where(
@@ -1184,9 +1237,18 @@ class GatedExperts(nn.Module):
 
         with jax.named_scope('experts'):
             rows = jnp.take(flat, token_of, axis=0)       # [tokens * k, dim]
-            grown = nn.silu(lax.ragged_dot(rows, gate.astype(compute), sizes)) \
-                * lax.ragged_dot(rows, up.astype(compute), sizes)
-            shrunk = lax.ragged_dot(grown, down.astype(compute), sizes)
+            if wide != dim:
+                rows = jnp.pad(rows, ((0, 0), (0, wide - dim)))
+            grown = lax.ragged_dot(rows, up.astype(compute), sizes)
+            if gated:
+                grown = nn.silu(lax.ragged_dot(rows, gate.astype(compute),
+                                               sizes)) * grown
+            else:
+                grown = jnp.square(nn.relu(grown))
+            if deep != self.width:
+                grown = jnp.where(jnp.arange(deep) < self.width, grown, 0)
+            shrunk = lax.ragged_dot(grown, down.astype(compute),
+                                    sizes)[:, :dim]
             # rows past the seated ones hold whatever the product left
             weighed = jnp.where(held[:, None],
                                 shrunk.astype(jnp.float32)
@@ -1200,8 +1262,12 @@ class GatedExperts(nn.Module):
                 dense = lambda features, name: nn.Dense(
                     features, use_bias=False, dtype=compute,
                     name=f'shared_{name}')
-                shared = dense(dim, 'down')(
-                    nn.silu(dense(self.shared_width, 'gate')(flat))
-                    * dense(self.shared_width, 'up')(flat))
+                grown = dense(self.shared_width, 'up')(flat)
+                if gated:
+                    grown = nn.silu(dense(self.shared_width, 'gate')(flat)) \
+                        * grown
+                else:
+                    grown = jnp.square(nn.relu(grown))
+                shared = dense(dim, 'down')(grown)
             output = output + shared.astype(jnp.float32)
         return output.reshape(*batch_shape, dim).astype(hidden.dtype)

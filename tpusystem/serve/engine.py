@@ -75,6 +75,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import inspect
 import re
 import time
 
@@ -86,7 +87,8 @@ from tpusystem.observe.profile import annotate
 from tpusystem.parallel.mesh import on_tpu
 from tpusystem.serve.kvcache import (PagedKVCache, _is_kv, adopt_prefill,
                                      pool_shardings, write_tables)
-from tpusystem.train.cursors import gather_rows, is_cursor, read_cursor, rewind
+from tpusystem.train.cursors import (gather_rows, holds_row_state, is_cursor,
+                                     is_row_state, read_cursor, rewind)
 from tpusystem.train.decode_fused import (build_fused_paged_step,
                                           fused_paged_reason)
 from tpusystem.train.generate import (_decoder, _dequant, _stream_params,
@@ -174,17 +176,65 @@ def engine_unsupported_reason(module) -> str | None:
     and :class:`tpusystem.ops.moe.GatedExperts` layers holding a share
     of the router's experts (grouped products over the seated
     assignments: no capacity, no drop, no dependence on co-batched
-    rows; their ``expert_load`` counters ride the tick's token read).
-    The remaining gate is layout, not architecture."""
+    rows; their ``expert_load`` counters ride the tick's token read), and
+    the **Nemotron-H** family — a pattern string of single-mixer layers
+    whose Mamba-2 layers (:class:`tpusystem.ops.ssm.Mamba2`) keep a
+    **per-row state cache beside the paged pool**: ``state`` (float32
+    ``[rows, H, P, N]``) and ``conv`` (the convolution's last inputs) are
+    addressed by row, have no blocks, table or masked positions, and are
+    told the prompt's true length at prefill (a recurrence sees its
+    right-padding; the engine hands ``length`` to a module whose
+    ``__call__`` takes it). A retired row's state needs no clearing:
+    admission overwrites the whole row
+    (:func:`tpusystem.serve.kvcache.adopt_prefill`), and until then a parked
+    row's update reads and writes that row alone, so nothing it holds can
+    reach a seated row. What a recurrent state cannot do yet is refused by
+    name at construction (:func:`recurrent_reason`): ``share_prefix`` (a
+    shared prefix needs a state snapshot at its block boundary), a draft
+    module (a rejected token cannot be rolled out of a state) and a mesh
+    (no sharding contract for the state). The remaining gate is layout,
+    not architecture."""
     for field in ('decode', 'max_seq', 'per_row_decode', 'decode_pages'):
         if not hasattr(module, field):
             return (f'module {type(module).__name__} has no {field!r} '
                     'field — the engine needs the family decode '
-                    'conventions (GPT2 / Llama / DeepSeekV2)')
+                    'conventions (GPT2 / Llama / DeepSeekV2 / NemotronH)')
     if getattr(module, 'scan_layers', False):
         return ('scan_layers stacks the per-layer caches at a leading '
                 'layer dim; the engine admission writes are unrolled-'
                 'layout only — serve the unrolled module')
+    return None
+
+
+def is_recurrent(module) -> bool:
+    """Whether the module's decode cache holds per-row state leaves
+    (``state``/``conv``), read from the shapes its decode clone declares."""
+    shapes = jax.eval_shape(
+        functools.partial(_decoder(module).init, jax.random.PRNGKey(0)),
+        jnp.zeros((1, 1), jnp.int32))
+    return holds_row_state(shapes.get('cache', {}))
+
+
+def recurrent_reason(module, *, share_prefix: bool = False,
+                     draft_module=None, sharded: bool = False) -> str | None:
+    """Why the engine cannot serve this module with these options, where a
+    recurrent state (:func:`is_recurrent`) is in the way; else ``None``."""
+    wanted = [
+        (share_prefix, 'share_prefix=True', 'a shared prefix is adopted by '
+         'blocks, and the recurrent state at the block boundary was never '
+         'kept: prefix sharing over a recurrent state needs state snapshots '
+         'at block boundaries'),
+        (draft_module is not None, 'a draft module (speculative rows)',
+         'a rejected draft token cannot be rolled back out of a recurrent '
+         'state (cursors.rewind refuses it): speculative rows need a '
+         'rewindable state'),
+        (sharded, 'a mesh', 'the per-row recurrent state has no sharding '
+         'contract (pool_shardings shards keys and values by head)')]
+    for asked, name, why in wanted:
+        if asked and any(is_recurrent(each) for each in (module, draft_module)
+                         if each is not None):
+            return (f'{name} does not compose with a recurrent state '
+                    f'({type(module).__name__}): {why}')
     return None
 
 
@@ -212,14 +262,19 @@ def _build_prefill(decoder, bucket: int, routed: bool = False):
     position was given, ``[bucket, expert layers, k]`` (an engine with a
     ``routing_sink``)."""
     del bucket          # part of the cache key; shapes key the jit cache
+    # a module whose __call__ takes `length` is told how much of the padded
+    # prompt is real (an operand: still one program a bucket)
+    told = 'length' in inspect.signature(type(decoder).__call__).parameters
 
     @jax.jit
     def run(params, padded, length, seed, position, temp, topk, topp, mask):
         # plain contiguous prefill: one causal pass over the padded
         # prompt builds every layer's [1, max_seq, ...] KV strip; the
-        # right-pad junk is causally invisible to the real positions
+        # right-pad junk is causally invisible to the real positions of an
+        # attention layer, and a recurrent layer is told where it starts
         logits, state = decoder.apply(
             {'params': _dequant(params, decoder)}, padded,
+            **({'length': length} if told else {}),
             mutable=['cache', 'routing'] if routed else ['cache'])
         # the first token samples at the row's own (seed, position)
         # counter — greedy defaults reproduce the classic argmax bitwise
@@ -507,6 +562,12 @@ class Engine:
         self.speculate, self.tree_fanout = speculate, tree_fanout
         self._spec = draft_module is not None
         self.mesh, self.tp_plan = self._resolve_mesh(mesh)
+        reason = recurrent_reason(
+            module, share_prefix=share_prefix, draft_module=draft_module,
+            sharded=self.tp_plan.path == 'gspmd')
+        if reason is not None:
+            raise ValueError(f'the serving engine cannot run this module '
+                             f'so: {reason}')
         if self._spec and self.tp_plan.path == 'gspmd':
             raise ValueError(
                 'mesh= does not compose with speculative rows yet — the '
@@ -552,6 +613,15 @@ class Engine:
         self._cache = jax.tree.map(
             lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
             collections['cache'])
+        # how the cache divides between the two kinds: the paged pool's keys
+        # and values, and the recurrent layers' per-row state leaves (the
+        # scheduler marks it once in its tracer: `cache_bytes`)
+        self.cache_bytes = {'kv': 0, 'state': 0}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(self._cache):
+            kind = 'kv' if _is_kv(path) else 'state' \
+                if is_row_state(path) else None
+            if kind is not None:
+                self.cache_bytes[kind] += leaf.size * leaf.dtype.itemsize
         # what the module's layers sow for the host beside the tokens: the
         # names of their expert_load counters, and, for a routing_sink, the
         # [expert layers, k] of the experts a token was given
@@ -681,7 +751,8 @@ class Engine:
                                           topp, mask)
                 cursor = read_cursor(cache)
                 return (token,
-                        rewind(updated, jnp.where(active, cursor + 1, 0)),
+                        rewind(updated, jnp.where(active, cursor + 1, 0),
+                               state_stands=True),
                         jnp.where(active, pos + 1, pos))
         else:
             def step_fn(params, cache, tokens, active, seed, pos, temp,
@@ -699,11 +770,14 @@ class Engine:
                 # park retired rows' cursors at 0 so their dead writes
                 # stay in the trash block's first slots instead of
                 # walking off the table; active rows keep the cursor
-                # cached_attention advanced
+                # cached_attention advanced (a recurrent layer's state
+                # stands where its row's cursor is put: each took in the
+                # one token, and a parked row's is never read again)
                 cursor = read_cursor(cache)
                 out = (token,
                        rewind(updated['cache'],
-                              jnp.where(active, cursor + 1, 0)),
+                              jnp.where(active, cursor + 1, 0),
+                              state_stands=True),
                        jnp.where(active, pos + 1, pos))
                 # expert layers that count their load (GatedExperts) hand
                 # the counts, and for a routing_sink their choices, to the
@@ -1227,7 +1301,9 @@ class Engine:
         prefill-role half of disaggregated serving. Returns ``(first,
         kv)``: the prompt's first token and every layer's contiguous KV
         strip (``keystr path -> [1, max_seq, heads, head_dim]`` numpy,
-        host-side so the blob plane can ship it). The decode-role
+        host-side so the blob plane can ship it), a recurrent layer's
+        per-row state leaves (``state``/``conv`` as they stood at the
+        prompt's true length) among them under their own paths. The decode-role
         replica seats it with :meth:`admit_prefilled`; this engine's
         pool, rows and sharing index are untouched. A sampled request's
         first token samples at its ``(seed, len(emitted))`` counter —
@@ -1254,7 +1330,7 @@ class Engine:
         kv = {jax.tree_util.keystr(path): np.asarray(leaf)
               for path, leaf
               in jax.tree_util.tree_leaves_with_path(prefill_cache)
-              if _is_kv(path)}
+              if _is_kv(path) or is_row_state(path)}
         return first, kv
 
     def _strip_cache(self, kv: dict):
@@ -1267,7 +1343,7 @@ class Engine:
             jnp.zeros((1, 1), jnp.int32))['cache']
 
         def fill(path, leaf):
-            if not _is_kv(path):
+            if not (_is_kv(path) or is_row_state(path)):
                 return jnp.zeros(leaf.shape, leaf.dtype)
             name = jax.tree_util.keystr(path)
             if name not in kv:

@@ -331,11 +331,14 @@ def adopt_prefill(cache, prefill_cache, slots, row, length):
     pad-bucket junk beyond the prompt scatters into trash or into
     positions the decode write overwrites before the mask ever exposes
     them); ``row``/``length`` set the row's cursors to the prompt length.
+    A recurrent layer's per-row state leaves (``state``/``conv``:
+    :func:`tpusystem.train.cursors.is_row_state`) have no slots: the
+    prefill's row 0 overwrites row ``row`` whole, in this same program.
     Tables are not touched here — :func:`write_tables` is the one table
     authority. One compiled program for every admission (prefill strips
     share one shape across buckets: the cache is allocated ``max_seq``
     wide regardless of prompt length)."""
-    from tpusystem.train.cursors import is_cursor
+    from tpusystem.train.cursors import is_cursor, is_row_state
     source = {jax.tree_util.keystr(path): leaf for path, leaf
               in jax.tree_util.tree_leaves_with_path(prefill_cache)}
 
@@ -344,6 +347,9 @@ def adopt_prefill(cache, prefill_cache, slots, row, length):
             strip = source[jax.tree_util.keystr(path)][0]  # [max_seq, h, d]
             return leaf.at[slots].set(
                 strip.reshape(strip.shape[0], -1).astype(leaf.dtype))
+        if is_row_state(path):
+            return leaf.at[row].set(
+                source[jax.tree_util.keystr(path)][0].astype(leaf.dtype))
         if is_cursor(path):
             return leaf.at[row].set(jnp.asarray(length, leaf.dtype))
         return leaf
@@ -378,7 +384,9 @@ def pool_shardings(cache, mesh, kv_heads: int):
     split through the middle of a head serves nobody), the same
     divisibility discipline as
     :meth:`~tpusystem.parallel.sharding.ShardingPolicy.spec`. Everything
-    else — block tables, cursors, masks — replicates: the host-side
+    else — block tables, cursors, masks, a recurrent layer's per-row state
+    (no engine shards one yet: ``Engine`` refuses a mesh over a recurrent
+    module) — replicates: the host-side
     :class:`PagedKVCache` stays the ONE block-table authority and
     ``adopt_prefill``/``write_tables`` keep their contracts unchanged.
     """

@@ -199,6 +199,11 @@ class Scheduler:
         self._trace_open: dict[str, object] = {}    # request id -> Span
         self._trace_admitting: dict[str, object] = {}   # open 'admit' spans
         self._trace_roots: dict[str, object] = {}   # roots THIS end owns
+        if tracer is not None and hasattr(engine, 'cache_bytes'):
+            # how the engine's cache divides between the paged pool and
+            # the recurrent layers' per-row state, once a trace
+            tracer.instant('cache_bytes', cat='engine',
+                           args=dict(engine.cache_bytes))
 
     @property
     def queue_depth(self) -> int:

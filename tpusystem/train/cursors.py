@@ -10,6 +10,14 @@ same two kinds of cache leaves: the per-layer ``index`` cursor that
 offset. This module is the single implementation of those edits, so the
 speculative path and the engine cannot drift on which leaves count as
 cursors or how scanned stacks broadcast.
+
+A recurrent layer (:class:`tpusystem.ops.ssm.Mamba2`) keeps a third kind of
+leaf beside keys/values and cursors: **per-row state** (``state``, the
+float32 recurrent state, and ``conv``, the convolution's last inputs),
+addressed by row, with no positions a cursor could mask. :func:`is_row_state`
+names them for every path that edits a cache: a row is written whole
+(admission), gathered whole (:func:`gather_rows`), and never rewound
+(:func:`rewind`).
 """
 
 from __future__ import annotations
@@ -24,18 +32,53 @@ CURSOR_KEYS = (jax.tree_util.DictKey('index'),
                jax.tree_util.DictKey('position'))
 
 
+# The per-row state leaves of a recurrent layer, by name -> how many
+# trailing dims follow the batch axis (``state [batch, H, P, N]``, ``conv
+# [batch, taps - 1, channels]``).
+ROW_STATE_DIMS = {jax.tree_util.DictKey('state'): 3,
+                  jax.tree_util.DictKey('conv'): 2}
+
+
 def is_cursor(path) -> bool:
     """True when a cache tree path addresses a cursor leaf."""
     return path[-1] in CURSOR_KEYS
 
 
-def rewind(cache, cursor):
+def is_row_state(path) -> bool:
+    """True when a cache tree path addresses a recurrent layer's per-row
+    state leaf (``state`` or ``conv``)."""
+    return path[-1] in ROW_STATE_DIMS
+
+
+def holds_row_state(cache) -> bool:
+    """Whether a cache tree (arrays or shapes) has any per-row state leaf:
+    whether the module that declared it is recurrent."""
+    return any(is_row_state(path) for path, _
+               in jax.tree_util.tree_leaves_with_path(cache))
+
+
+def rewind(cache, cursor, *, state_stands: bool = False):
     """Set every cache cursor to ``cursor`` (``[batch]`` int, or a
     scalar broadcast over rows) — rows beyond it are garbage from
     rejected speculation or a retired serving row, masked out by the
     cursor-based attention mask and overwritten by the next accepted
     tokens. Scanned stacks carry cursors at a leading layer dim; the
-    ``[batch]`` cursor broadcasts into whatever shape the leaf has."""
+    ``[batch]`` cursor broadcasts into whatever shape the leaf has.
+
+    A per-row state leaf cannot be rewound: the state after the rejected
+    tokens has no masked positions to fall back behind. A cache that holds
+    one is **refused** (``ValueError`` at trace time) unless the caller
+    says the state already stands where the cursors are put
+    (``state_stands=True``: the serving engine's tick, which moves an active
+    row's cursor past the one token its state took in and parks a retired
+    row's, whose state nobody reads again)."""
+    if not state_stands and holds_row_state(cache):
+        raise ValueError(
+            'this cache holds a recurrent state (state/conv leaves): a '
+            'state cannot be rewound to an earlier position — speculative '
+            'decoding over a recurrent layer needs a state snapshot to '
+            'fall back to')
+
     def fix(path, leaf):
         if is_cursor(path):
             return jnp.broadcast_to(jnp.asarray(cursor, leaf.dtype),
@@ -62,10 +105,17 @@ def gather_rows(cache, rows):
     ``ndim - 4`` for the contiguous ``[..., batch, max_seq, heads,
     head_dim]`` cache layout, which also covers scanned stacks' leading
     layer dim — and cursor leaves (``index``/``position``) on their last
-    axis. Contiguous caches only: a paged cache's pool has no batch axis
-    (rows alias blocks through the table), so row copies there are block
-    copies, owned by :class:`tpusystem.serve.PagedKVCache`."""
+    axis; a per-row state leaf (``state``/``conv``) is gathered whole by
+    row, on its own batch axis. Contiguous caches only: a paged cache's
+    pool has no batch axis (rows alias blocks through the table), so row
+    copies there are block copies, owned by
+    :class:`tpusystem.serve.PagedKVCache`."""
     def fix(path, leaf):
-        axis = leaf.ndim - 1 if is_cursor(path) else leaf.ndim - 4
+        if is_cursor(path):
+            axis = leaf.ndim - 1
+        elif is_row_state(path):
+            axis = leaf.ndim - 1 - ROW_STATE_DIMS[path[-1]]
+        else:
+            axis = leaf.ndim - 4
         return jnp.take(leaf, rows, axis=axis)
     return jax.tree_util.tree_map_with_path(fix, cache)
