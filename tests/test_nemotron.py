@@ -469,6 +469,50 @@ def test_a_row_seated_after_a_retired_one_reads_as_on_a_fresh_engine(served):
     assert engine.membership_traces == {'seat': 1, 'clear': 1}
 
 
+@pytest.mark.parametrize('read, widths, why', [
+    ('kernel', dict(kv_heads=2, head_dim=64), None),       # rows of 128 lanes
+    ('gather', dict(kv_heads=2, head_dim=16), 'cannot tile')],  # of 32
+    ids=['rows of 128 lanes', 'rows of 32'])
+def test_the_engine_reads_the_pool_through_the_kernel_where_it_tiles(
+        read, widths, why, monkeypatch):
+    """On the TPU one decoded token a row goes through the paged-attention
+    kernel where its plan tiles the stored row; steered here (the look for
+    a TPU says yes, the kernel runs interpreted), it is called once an
+    attention layer in the one decode trace, a plan that refuses leaves the
+    gather, the engine's ``paged_read`` says which, and either way the
+    tokens are ``generate``'s."""
+    from tpusystem.ops import attention
+    from tpusystem.ops.pallas import paged_attention as kernel_module
+    module = nemotron_tiny(pattern='M*E*', max_seq=64, **widths)
+    params = module.init(jax.random.PRNGKey(3),
+                         jnp.zeros((1, 4), jnp.int32))['params']
+    prompts = [tokens_of(60 + n, n).tolist() for n in (21, 6)]
+    want = [standalone(module, params, prompt, 5) for prompt in prompts]
+    assert Engine(module, params, rows=2, block_size=8).paged_read == {
+        'read': 'gather', 'reason': 'not on a TPU'}
+    calls = []
+    real = kernel_module.paged_decode_attention
+    monkeypatch.setattr(kernel_module, 'paged_decode_attention',
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    monkeypatch.setattr(attention, 'on_tpu', lambda: True)
+    tracer = Tracer('serve')
+    service = InferenceService(module, params, rows=2, block_size=8,
+                               decode_impl='flax', tracer=tracer)
+    engine = service.engine
+    assert engine.paged_read['read'] == read
+    assert (why or '') in (engine.paged_read['reason'] or '')
+    marks = [event['args'] for event in tracer.events()
+             if event['name'] == 'paged_read']
+    assert len(marks) == 1 and {name: marks[0][name] for name
+                                in ('read', 'reason')} == engine.paged_read
+    rows = [engine.admit(prompt, max_new=5).row for prompt in prompts]
+    tokens = drain(engine)
+    monkeypatch.setattr(attention, 'on_tpu', lambda: False)
+    assert engine.trace_count == 1
+    assert len(calls) == (2 if read == 'kernel' else 0)   # once a `*` layer
+    assert [tokens[row] for row in rows] == want
+
+
 def tiny_draft():
     draft = nemotron_tiny(pattern='M*', held=None)
     return draft, draft.init(jax.random.PRNGKey(1),

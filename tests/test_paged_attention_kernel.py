@@ -1,6 +1,8 @@
 """The paged decode-attention kernel (interpret mode on the CPU) against a
 float32 ``jax.numpy`` reference written here: seeded pools, ragged depths,
-shared blocks, parked rows, and the in-place write of the step around it."""
+shared blocks, parked rows, grouped queries (several query heads to a stored
+key/value head) against ``dot_product_attention`` too, and the in-place write
+of the step around it."""
 
 from __future__ import annotations
 
@@ -11,6 +13,7 @@ import pytest
 
 from tpusystem.models import gpt2_tiny
 from tpusystem.models.gpt2 import GPT2
+from tpusystem.ops.attention import dot_product_attention
 from tpusystem.ops.pallas.paged_attention import (CHUNK_POSITIONS,
                                                   paged_decode_attention,
                                                   paged_plan)
@@ -18,38 +21,55 @@ from tpusystem.train.decode_fused import fused_paged_reason
 
 HEAD_DIM = 64
 
+# query heads, stored key/value heads, head_dim: GPT-2's one to one (the
+# kernel's first layout), Nemotron-H's 32 on 2 x 128, and 8 on 2 x 64
+SHAPES = {'12': (12, 12, 64), '32on2x128': (32, 2, 128),
+          '8on2x64': (8, 2, 64)}
+shapes = pytest.mark.parametrize('shape', SHAPES)
+
 
 def reference(query, key_pool, value_pool, table, cursor, block: int):
     """Each row attends positions ``0 … cursor`` of its own blocks: plain
-    float32 softmax attention, one row and one head at a time."""
+    float32 softmax attention, one row and one head at a time, a query
+    head against key/value head ``head // group`` of the stored row."""
     rows, heads, head_dim = query.shape
-    keys = np.asarray(key_pool, np.float32).reshape(-1, heads, head_dim)
-    values = np.asarray(value_pool, np.float32).reshape(-1, heads, head_dim)
+    keys = np.asarray(key_pool, np.float32).reshape(
+        key_pool.shape[0], -1, head_dim)
+    values = np.asarray(value_pool, np.float32).reshape(keys.shape)
+    stored = np.arange(heads) // (heads // keys.shape[1])
     out = np.zeros((rows, heads, head_dim), np.float32)
     for row in range(rows):
         held = np.arange(int(cursor[row]) + 1)
         slots = np.asarray(table)[row, held // block] * block + held % block
         scores = np.einsum('hd,thd->ht', np.asarray(query[row], np.float32),
-                           keys[slots]) * head_dim ** -0.5
+                           keys[slots][:, stored]) * head_dim ** -0.5
         weights = np.exp(scores - scores.max(-1, keepdims=True))
         weights /= weights.sum(-1, keepdims=True)
-        out[row] = np.einsum('ht,thd->hd', weights, values[slots])
+        out[row] = np.einsum('ht,thd->hd', weights, values[slots][:, stored])
     return out
 
 
 def pools(seed: int, rows: int, heads: int, block: int, max_blocks: int,
-          dtype=jnp.float32):
+          dtype=jnp.float32, kv_heads: int | None = None,
+          head_dim: int = HEAD_DIM):
     """Seeded K and V pools (block 0 is trash, filled like the rest), a
     table of distinct physical blocks per row, and a query."""
     rng = np.random.default_rng(seed)
     blocks = rows * max_blocks + 1
-    width = heads * HEAD_DIM
+    width = (kv_heads or heads) * head_dim
     key_pool = jnp.asarray(rng.normal(size=(blocks * block, width)), dtype)
     value_pool = jnp.asarray(rng.normal(size=(blocks * block, width)), dtype)
     table = rng.permutation(np.arange(1, blocks)).reshape(
         rows, max_blocks).astype(np.int32)
-    query = jnp.asarray(rng.normal(size=(rows, heads, HEAD_DIM)), dtype)
+    query = jnp.asarray(rng.normal(size=(rows, heads, head_dim)), dtype)
     return query, key_pool, value_pool, table
+
+
+def shaped_pools(shape: str, seed, rows, block, max_blocks,
+                 dtype=jnp.float32):
+    heads, kv_heads, head_dim = SHAPES[shape]
+    return pools(seed, rows, heads, block, max_blocks, dtype, kv_heads,
+                 head_dim)
 
 
 def attend(query, key_pool, value_pool, table, cursor, block):
@@ -73,23 +93,27 @@ def test_one_row_at_every_kind_of_depth(depth):
 
 
 @pytest.mark.parametrize('block', [8, 16])
-@pytest.mark.parametrize('heads', [12, 20])
+@pytest.mark.parametrize('heads', [(12,), (20,), (32, 2, 128), (8, 2, 64)],
+                         ids=['12', '20', '32on2x128', '8on2x64'])
 def test_ragged_rows_block_sizes_and_head_counts(block, heads):
     """Rows of different depths in one call: each reads its own blocks at
     its own depth whatever its neighbours hold (the double buffer hands
     over between rows of unequal chunk counts)."""
     max_blocks = 256 // block
-    query, key_pool, value_pool, table = pools(5, 6, heads, block, max_blocks)
+    heads, *grouped = heads
+    query, key_pool, value_pool, table = pools(
+        5, 6, heads, block, max_blocks, jnp.float32, *grouped)
     cursor = np.array([0, block - 1, block, 255, 130, 77], np.int32)
     got = attend(query, key_pool, value_pool, table, cursor, block)
     want = reference(query, key_pool, value_pool, table, cursor, block)
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def test_rows_whose_tables_share_physical_blocks():
+@shapes
+def test_rows_whose_tables_share_physical_blocks(shape):
     """``share_prefix``: two rows name the same read-only blocks for their
     common prefix and private ones after it."""
-    query, key_pool, value_pool, table = pools(7, 3, 12, 16, 8)
+    query, key_pool, value_pool, table = shaped_pools(shape, 7, 3, 16, 8)
     table[1, :3] = table[0, :3]
     cursor = np.array([70, 55, 100], np.int32)
     got = attend(query, key_pool, value_pool, table, cursor, 16)
@@ -103,23 +127,28 @@ def test_rows_whose_tables_share_physical_blocks():
     np.testing.assert_array_equal(got[0], got[1])
 
 
-def test_a_parked_row_reads_one_position_of_the_trash_block():
-    query, key_pool, value_pool, table = pools(11, 3, 12, 16, 8)
+@shapes
+def test_a_parked_row_reads_one_position_of_the_trash_block(shape):
+    query, key_pool, value_pool, table = shaped_pools(shape, 11, 3, 16, 8)
     table[1] = 0                                  # unmapped: all trash
     cursor = np.array([90, 0, 17], np.int32)
     got = attend(query, key_pool, value_pool, table, cursor, 16)
     want = reference(query, key_pool, value_pool, table, cursor, 16)
     np.testing.assert_allclose(got, want, atol=2e-5)
-    # one position: its softmax weight is 1 and the context is that V row
+    # one position: its softmax weight is 1 and the context is that V row,
+    # each stored head's part once a query head of its group
+    heads, kv_heads, head_dim = SHAPES[shape]
     np.testing.assert_allclose(
-        got[1].reshape(-1), np.asarray(value_pool[0]), atol=1e-6)
+        got[1], np.repeat(np.asarray(value_pool[0]).reshape(
+            kv_heads, head_dim), heads // kv_heads, axis=0), atol=1e-6)
 
 
-def test_what_lies_past_the_cursor_is_never_read():
+@shapes
+def test_what_lies_past_the_cursor_is_never_read(shape):
     """NaN in every slot a row does not hold — the rest of its last block,
     its later blocks, other rows' blocks — leaves the result finite and
     unchanged: masked inside the last block, never fetched beyond it."""
-    query, key_pool, value_pool, table = pools(13, 2, 12, 16, 16)
+    query, key_pool, value_pool, table = shaped_pools(shape, 13, 2, 16, 16)
     cursor = np.array([37, 130], np.int32)
     want = reference(query, key_pool, value_pool, table, cursor, 16)
     held = np.zeros(key_pool.shape[0], bool)
@@ -137,16 +166,49 @@ def test_what_lies_past_the_cursor_is_never_read():
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def test_bf16_pools_keep_float32_statistics():
+@pytest.mark.parametrize('shape', [(20, 20, 64), *SHAPES.values()],
+                         ids=['20', *SHAPES])
+def test_bf16_pools_keep_float32_statistics(shape):
     """bf16 operands as on the chip: the result is the float32 reference's
     on the same (rounded) pools to within one bf16 rounding of the output."""
-    query, key_pool, value_pool, table = pools(17, 4, 20, 16, 16,
-                                               jnp.bfloat16)
+    heads, kv_heads, head_dim = shape
+    query, key_pool, value_pool, table = pools(
+        17, 4, heads, 16, 16, jnp.bfloat16, kv_heads, head_dim)
     cursor = np.array([255, 3, 100, 16], np.int32)
     got = attend(query, key_pool, value_pool, table, cursor, 16)
     assert got.dtype == jnp.bfloat16
     want = reference(query, key_pool, value_pool, table, cursor, 16)
     np.testing.assert_allclose(got.astype(np.float32), want, atol=0.03)
+
+
+@pytest.mark.parametrize('dtype, tolerance', [(jnp.float32, 2e-5),
+                                              (jnp.bfloat16, 0.02)])
+@pytest.mark.parametrize('block', [8, 16])
+@pytest.mark.parametrize('shape', ['32on2x128', '8on2x64'])
+def test_grouped_queries_read_as_dot_product_attention_does(shape, block,
+                                                            dtype, tolerance):
+    """The gather's arithmetic, row by row: ``dot_product_attention``'s
+    grouped branch over each row's own positions (same operand dtype, same
+    float32 scores and statistics, the weights rounded to the operand dtype
+    before the value product), at ragged depths that start, end and cross
+    chunks."""
+    heads, kv_heads, head_dim = SHAPES[shape]
+    max_blocks = 384 // block
+    query, key_pool, value_pool, table = shaped_pools(
+        shape, 29, 5, block, max_blocks, dtype)
+    cursor = np.array([0, block, CHUNK_POSITIONS - 1, 383, 200], np.int32)
+    got = attend(query, key_pool, value_pool, table, cursor, block)
+    for row, depth in enumerate(cursor):
+        held = np.arange(depth + 1)
+        slots = table[row, held // block] * block + held % block
+        window = (1, depth + 1, kv_heads, head_dim)
+        want = dot_product_attention(
+            query[row][None, None], key_pool[slots].reshape(window),
+            value_pool[slots].reshape(window), causal=False,
+            mask=jnp.ones((1, 1, 1, depth + 1), bool))[0, 0]
+        np.testing.assert_allclose(got[row].astype(np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=tolerance)
 
 
 @pytest.mark.parametrize('heads,block,dtype,chunk', [
@@ -162,6 +224,29 @@ def test_the_plan_answers_from_shapes_alone(heads, block, dtype, chunk):
                       interpret=True) == max(1, CHUNK_POSITIONS // block)
 
 
+@pytest.mark.parametrize('heads, kv_heads, head_dim, chunk', [
+    (32, 2, 128, 8),            # Nemotron-H: rows of 256 lanes
+    (32, 8, 128, 8),            # Llama-3 8B: rows of 1024
+    (8, 2, 64, 8),              # rows of one lane tile
+    (4, 2, 32, None),           # a 64-lane pool: not whole lanes
+    (12, 1, 64, None),          # one stored head of 64: the same
+    (12, 5, 128, None)])        # no whole group
+def test_the_plan_for_grouped_queries(heads, kv_heads, head_dim, chunk):
+    """The stored row (``kv_heads * head_dim``) is what has to fill lanes;
+    where the plan refuses, ``paged_read`` says gather and says why."""
+    from tpusystem.ops import attention
+    assert paged_plan(heads, head_dim, 16, 96, 'bfloat16', False,
+                      kv_heads) == chunk
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(attention, 'on_tpu', lambda: True)
+        read, why = attention.paged_read(1, heads, kv_heads, head_dim, 16,
+                                         96, 'bfloat16')
+    if chunk is None:
+        assert read == 'gather' and 'cannot tile' in why
+    else:
+        assert (read, why) == ('kernel', None)
+
+
 def test_the_chunk_never_passes_the_table():
     assert paged_plan(12, HEAD_DIM, 8, 4, 'float32', interpret=True) == 4
 
@@ -173,7 +258,7 @@ def test_a_refused_shape_raises_and_the_gate_names_it(monkeypatch):
                                jnp.asarray(table), jnp.zeros(1, jnp.int32),
                                block=8, interpret=False)
     with pytest.raises(ValueError, match='do not hold'):
-        paged_decode_attention(query, key_pool[:, :64], value_pool[:, :64],
+        paged_decode_attention(query, key_pool[:, :96], value_pool[:, :96],
                                jnp.asarray(table), jnp.zeros(1, jnp.int32),
                                block=8, interpret=True)
     # on the chip the engine's gate names the refusal; off it (interpret)
@@ -199,6 +284,7 @@ def test_the_step_writes_one_slot_a_row_and_no_other(block):
                          jnp.zeros((1, 8), jnp.int32))['params']
     engine = Engine(module, params, rows=3, block_size=block,
                     decode_impl='fused')
+    assert engine.paged_read == {'read': 'kernel', 'reason': None}
     rng = np.random.default_rng(23)
     engine.admit([int(t) for t in rng.integers(0, 256, (block + 3,))],
                  max_new=8)
@@ -219,6 +305,50 @@ def test_the_step_writes_one_slot_a_row_and_no_other(block):
             assert set(slots[:2]) <= set(changed)          # live rows wrote
 
 
+def test_the_flax_paged_step_reads_through_the_kernel_on_the_tpu(monkeypatch):
+    """A tiny Llama (4 query heads on 2 x 64: rows of 128 lanes) on the
+    engine's flax paged step, steered to "on the TPU" with the kernel
+    interpreted: one call a layer in the one decode trace, ``generate``'s
+    tokens. A query window longer than one token (speculative verify)
+    keeps the gather, and ``Engine.paged_read`` says so beforehand."""
+    from tpusystem.models import llama_tiny
+    from tpusystem.ops import attention
+    from tpusystem.ops.pallas import paged_attention as kernel_module
+    from tpusystem.serve import Engine
+    from tpusystem.train.generate import generate
+    module = llama_tiny(dim=256, dtype='float32', max_seq=64)
+    params = module.init(jax.random.PRNGKey(4),
+                         jnp.zeros((1, 4), jnp.int32))['params']
+    rng = np.random.default_rng(31)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (19, 7)]
+    want = [np.asarray(generate(module, params, jnp.asarray([prompt]),
+                                steps=5))[0, len(prompt):].tolist()
+            for prompt in prompts]
+    calls = []
+    real = kernel_module.paged_decode_attention
+    monkeypatch.setattr(kernel_module, 'paged_decode_attention',
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    monkeypatch.setattr(attention, 'on_tpu', lambda: True)
+    engine = Engine(module, params, rows=2, block_size=8, decode_impl='flax')
+    assert engine.paged_read == {'read': 'kernel', 'reason': None}
+    rows = [engine.admit(prompt, max_new=5).row for prompt in prompts]
+    tokens = {}
+    while engine.active_rows:
+        for row, _reason, out in engine.step().finished:
+            tokens[row] = out
+    assert len(calls) == 2 and engine.trace_count == 1    # once a layer
+    assert [tokens[row] for row in rows] == want
+    # a window of three tokens a row: the gather, and the record says why
+    window = Engine(module, params, rows=2, block_size=8, speculate=2,
+                    draft_module=module, draft_params=params)
+    assert window.paged_read['read'] == 'gather'
+    assert 'window of 3 tokens' in window.paged_read['reason']
+    del calls[:]
+    engine._decoder.apply({'params': params, 'cache': engine._cache},
+                          jnp.zeros((2, 3), jnp.int32), mutable=['cache'])
+    assert not calls
+
+
 # --- compiled for the chip, without the chip -----------------------------
 # The TPU's compiler is installed here and compiles for a described v5e:
 # what Mosaic refuses (a slice off the tiling, too much VMEM) fails here,
@@ -236,17 +366,19 @@ def one_chip():
     return SingleDeviceSharding(topology.devices[0])
 
 
-@pytest.mark.parametrize('rows,heads', [(32, 20), (8, 12)],
-                         ids=['gpt2-large', 'gpt2-125m'])
-def test_the_kernel_compiles_for_a_v5e_at_serving_widths(one_chip, rows,
-                                                         heads):
-    """The cell's shapes (32 rows x 20 heads, block 16, 64 table columns)
-    and the smoke's: Mosaic takes the kernel, the pools go in as stored —
-    no copy, transpose or slice of a pool-shaped operand around it — and
-    the compiler names the call after the kernel, not after the jitted
-    step (``decode_chain_roofline`` sums ``step_fn [tpu_custom_call]``)."""
-    block, max_blocks = 16, 64
-    slots, width = (rows * max_blocks + 1) * block, heads * HEAD_DIM
+@pytest.mark.parametrize('rows, heads, kv_heads, head_dim, max_blocks', [
+    (32, 20, 20, 64, 64), (8, 12, 12, 64, 64), (96, 32, 2, 128, 96)],
+    ids=['gpt2-large', 'gpt2-125m', 'nemotron3'])
+def test_the_kernel_compiles_for_a_v5e_at_serving_widths(
+        one_chip, rows, heads, kv_heads, head_dim, max_blocks):
+    """The cells' shapes (32 rows x 20 heads, block 16, 64 table columns;
+    96 rows x 32 query heads on 2 x 128, 96 columns) and the smoke's:
+    Mosaic takes the kernel, the pools go in as stored — no copy,
+    transpose or slice of a pool-shaped operand around it — and the
+    compiler names the call after the kernel, not after the jitted step
+    (``decode_chain_roofline`` sums ``step_fn [tpu_custom_call]``)."""
+    block = 16
+    slots, width = (rows * max_blocks + 1) * block, kv_heads * head_dim
     shaped = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip)
 
@@ -257,7 +389,7 @@ def test_the_kernel_compiles_for_a_v5e_at_serving_widths(one_chip, rows,
                                           interpret=False)
 
     compiled = jax.jit(step_fn).lower(
-        shaped((rows, heads, HEAD_DIM), jnp.bfloat16),
+        shaped((rows, heads, head_dim), jnp.bfloat16),
         shaped((slots, width), jnp.bfloat16),
         shaped((slots, width), jnp.bfloat16),
         shaped((rows, max_blocks), jnp.int32),
@@ -265,7 +397,7 @@ def test_the_kernel_compiles_for_a_v5e_at_serving_widths(one_chip, rows,
     calls = [line for line in compiled.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1
-    assert calls[0].strip().startswith('%paged_decode_attention')
+    assert '%paged_decode_attention' in calls[0].split(' = ')[0]
     pool = f'bf16[{slots},{width}]'
     moved = [line for line in compiled.splitlines()
              if pool in line.split(' = ')[-1].split('(')[0]
@@ -428,6 +560,7 @@ def test_the_engine_reads_the_latent_pool_through_the_kernel(monkeypatch):
                         lambda *a, **k: calls.append(1) or real(*a, **k))
     monkeypatch.setattr(attention, 'on_tpu', lambda: True)
     engine = Engine(module, params, rows=2, block_size=8, decode_impl='flax')
+    assert engine.paged_read['read'] is None          # no keys and values
     rows = [engine.admit(prompt, max_new=5).row for prompt in prompts]
     tokens = {}
     while engine.active_rows:

@@ -114,6 +114,37 @@ def _assert_uniform_cursor(cursor):
             'managed or speculative cursor state')
 
 
+def paged_read(length: int, heads: int, kv_heads: int, head_dim: int,
+               block: int, max_blocks: int, dtype, *, sharded: bool = False
+               ) -> tuple[str, str | None]:
+    """Which read a paged step of ``length`` new tokens a row takes over
+    ``[slots, kv_heads * head_dim]`` pools of ``dtype``, from shapes and the
+    platform alone: ``('kernel', None)`` — the pool read in place by
+    :func:`tpusystem.ops.pallas.paged_attention.paged_decode_attention` —
+    or ``('gather', why)``, the bucketed window of :func:`paged_attention`.
+    The one decision behind :func:`paged_attention`'s dispatch and the
+    engine's ``paged_read`` record (the ``fused_paged_reason``
+    discipline)."""
+    if length != 1:
+        return 'gather', (f'a query window of {length} tokens a row: the '
+                          'paged-attention kernel reads for one')
+    if not on_tpu():
+        return 'gather', 'not on a TPU'
+    if sharded:
+        return 'gather', ('the pools are sharded over a mesh and the '
+                          'paged-attention kernel is single-device')
+    from tpusystem.ops.pallas.paged_attention import paged_plan
+    if paged_plan(heads, head_dim, block, max_blocks, dtype, False,
+                  kv_heads) is None:
+        return 'gather', (
+            f'the paged-attention kernel cannot tile heads={heads} on '
+            f'{kv_heads} head_dim={head_dim} block_size={block} '
+            f'{jnp.dtype(dtype).name} on the TPU (the pool\'s minor dim '
+            'must fill 128 lanes and a block whole sublane tiles: 16 '
+            'positions of bf16, 8 of f32)')
+    return 'kernel', None
+
+
 def paged_attention(module, query, key, value, max_seq: int,
                     pages: tuple[int, int]):
     """Incremental attention over a **paged** KV cache (block pool +
@@ -147,16 +178,25 @@ def paged_attention(module, query, key, value, max_seq: int,
     path uses, so :mod:`tpusystem.train.cursors` edits apply
     unchanged).
 
-    Reads are bucketed like the contiguous path, in block units: the
-    smallest power-of-2 block window covering the deepest filled row is
-    gathered from the pool (``lax.switch`` over static widths — one
-    compiled program, capacity-independent read cost), masked at each
-    row's own depth. Masked positions contribute exact zeros, so a row's
-    output is independent of its co-batched traffic in
+    Two reads, the same mathematics, chosen by :func:`paged_read` from
+    shapes and the platform. One decoded token a row on the TPU, where the
+    kernel's plan tiles, reads the pool in place
+    (:func:`tpusystem.ops.pallas.paged_attention.paged_decode_attention`:
+    a row's own table columns ``0 … cursor // block``, whole blocks into a
+    double-buffered VMEM window, online softmax in float32, grouped
+    queries against their key/value head as stored): a row pays for the
+    positions it holds. Every other read (a longer query window —
+    speculative verify —, a shape the plan refuses, a pool sharded over a
+    mesh, off the TPU) is bucketed like the contiguous path, in block
+    units: the smallest power-of-2 block window covering the deepest
+    filled row is gathered from the pool (``lax.switch`` over static
+    widths — one compiled program, capacity-independent read cost),
+    masked at each row's own depth. Masked positions contribute exact
+    zeros, so a row's output is independent of its co-batched traffic in
     window-length-invariant arithmetic (f32; the same caveat as
     speculative verify applies at the TPU MXU's default precision).
     Scopes, as :func:`latent_attention`'s: ``kv_write`` (the scatter) and
-    ``kv_read`` (the gather and the attention over it).
+    ``kv_read`` (the kernel, or the gather and the attention over it).
     """
     num_blocks, block = pages
     if max_seq % block:
@@ -192,6 +232,18 @@ def paged_attention(module, query, key, value, max_seq: int,
             value.reshape(-1, kv_heads * head_dim).astype(
                 cache_value.value.dtype))
     index.value = cursor + length
+
+    read, _ = paged_read(
+        length, query.shape[2], kv_heads, head_dim, block, max_blocks,
+        cache_key.value.dtype,
+        sharded=getattr(module, 'mesh', None) is not None)
+    if read == 'kernel':
+        from tpusystem.ops.pallas.paged_attention import (
+            paged_decode_attention)
+        with jax.named_scope('kv_read'):
+            return paged_decode_attention(
+                query[:, 0], cache_key.value, cache_value.value, table.value,
+                cursor, block=block)[:, None]
 
     # bucketed block-window read: gather the first `width` table columns'
     # blocks and mask at each row's logical depth — the cached_attention

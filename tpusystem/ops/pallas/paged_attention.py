@@ -22,20 +22,35 @@ pool where it lies:
   and its probability is exactly zero). A parked row (cursor 0 on the
   trash block) reads one position.
 
-Every head's scores come out of ONE product: the row's ``[heads *
-head_dim]`` query is laid out block-diagonally (row ``h`` holds head
-``h``'s lanes, zeros elsewhere), so ``Q_bd @ K^T`` contracts over the
+Every head's scores come out of ONE product: the row's query is laid out
+block-diagonally — row ``h`` of a ``[padded heads, kv_heads * head_dim]``
+matrix holds head ``h``'s values in the lanes of **its key/value head**
+(``h // group``), zeros elsewhere — so ``Q_bd @ K^T`` contracts over the
 stored minor dimension and the pool is never reshaped, sliced by head or
-relaid. The value product runs the same way and the block diagonal of
-``P @ V`` is the context. Operands stay in the stored dtype (bf16 on the
-chip), scores, softmax statistics and the value accumulation are float32,
-the result is rounded once to the query's dtype.
+relaid. The value product runs the same way, and the context of head ``h``
+is the ``head_dim`` lanes of its key/value head in row ``h`` of ``P @ V``.
+``group`` (query heads to a key/value head) is read off the operands'
+shapes: ``query heads * head_dim / pool width``. At ``group == 1`` (GPT-2:
+as many query heads as the pool holds) the query goes in as one
+``[1, width]`` row, spread over the sublanes in the kernel, and the context
+leaves as the sum over rows of the block diagonal; grouped queries (32 on
+2 x 128: Nemotron-H, Llama) go in ``[heads, head_dim]``, are repeated
+across the lanes once a key/value head, and the context leaves
+``[heads, head_dim]`` through one static slice a key/value head.
+Operands stay in the stored dtype (bf16 on the chip), scores, softmax
+statistics and the value accumulation are float32, the weights are rounded
+to the operand dtype before ``P @ V`` (the roundings of
+``dot_product_attention``'s grouped branch), the result is rounded once to
+the query's dtype.
 
 Module discipline (``decode_matmul``): ``interpret=None`` auto-selects
 interpreter mode off-TPU, so tier-1 runs this body on the CPU;
 :func:`paged_plan` answers from shapes alone whether the TPU can tile
 them — ``fused_paged_reason`` names a refusal and ``decode_impl='auto'``
-then serves the flax paged step.
+then serves the flax paged step, whose own read
+(:func:`tpusystem.ops.attention.paged_attention`) asks the same plan
+(:func:`tpusystem.ops.attention.paged_read`) and keeps its gather where
+the plan refuses.
 """
 
 from __future__ import annotations
@@ -55,25 +70,31 @@ CHUNK_POSITIONS = 128   # one MXU tile of keys per product
 
 
 def paged_plan(heads: int, head_dim: int, block: int, max_blocks: int,
-               dtype, interpret: bool) -> int | None:
+               dtype, interpret: bool, kv_heads: int | None = None
+               ) -> int | None:
     """Pure tiling decision: how many table columns one chunk walks, or
     ``None`` when the TPU cannot run these shapes — the pool's minor dim
-    (``heads * head_dim``) must fill whole lanes and a block of ``block``
-    positions whole sublane tiles of ``dtype`` (16 rows of bf16, 8 of
-    f32), since each block is its own DMA into the window. Interpret mode
-    has no tiling constraints. The chunk is as many blocks as cover
-    ``CHUNK_POSITIONS`` positions, never more than the table holds."""
+    (``kv_heads * head_dim``; ``kv_heads`` left out: as many as query
+    ``heads``) must fill whole lanes and a block of ``block`` positions
+    whole sublane tiles of ``dtype`` (16 rows of bf16, 8 of f32), since
+    each block is its own DMA into the window; ``heads`` must be whole
+    groups of ``kv_heads``. Interpret mode has no tiling constraints. The
+    chunk is as many blocks as cover ``CHUNK_POSITIONS`` positions, never
+    more than the table holds."""
+    kv_heads = kv_heads or heads
+    if heads % kv_heads:
+        return None
     if not interpret:
         sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-        if (heads * head_dim) % LANES or block % sublanes:
+        if (kv_heads * head_dim) % LANES or block % sublanes:
             return None
     return max(1, min(max_blocks, CHUNK_POSITIONS // block))
 
 
 def _kernel(table_ref, cursor_ref, q_ref, k_hbm, v_hbm, out_ref,
             k_win, v_win, acc, top, denom, slot_ref, sems, *,
-            head_dim: int, block: int, chunk: int, max_seq: int,
-            scale: float):
+            head_dim: int, group: int, block: int, chunk: int,
+            max_seq: int, scale: float):
     row, rows = pl.program_id(0), pl.num_programs(0)
     span = chunk * block                       # positions per chunk
     padded_heads, width = acc.shape
@@ -108,11 +129,18 @@ def _kernel(table_ref, cursor_ref, q_ref, k_hbm, v_hbm, out_ref,
         slot_ref[0] = 0
         move(0, 0, 0, start=True)
 
-    # the query laid out block-diagonally: row h keeps head h's lanes
+    # the query laid out block-diagonally: row h keeps the lanes of head
+    # h's key/value head. One [1, width] row spread over the sublanes at
+    # group 1; grouped, [heads, head_dim] repeated once a key/value head
     lane = jax.lax.broadcasted_iota(jnp.int32, (padded_heads, width), 1)
-    head = jax.lax.broadcasted_iota(jnp.int32, (padded_heads, width), 0)
-    own = (lane >= head * head_dim) & (lane < (head + 1) * head_dim)
-    query = jnp.where(own, q_ref[...].astype(jnp.float32), 0.0).astype(
+    stored = jax.lax.broadcasted_iota(jnp.int32, (padded_heads, width), 0)
+    if group > 1:       # the key/value head of query head (row) h
+        stored = jax.lax.div(stored, group)
+    own = (lane >= stored * head_dim) & (lane < (stored + 1) * head_dim)
+    query = q_ref[...].astype(jnp.float32)
+    if group > 1:
+        query = jnp.concatenate([query] * (width // head_dim), axis=1)
+    query = jnp.where(own, query, 0.0).astype(
         q_ref.dtype)       # (selected in f32: the mask is a 32-bit one)
 
     acc[...] = jnp.zeros_like(acc)
@@ -154,8 +182,12 @@ def _kernel(table_ref, cursor_ref, q_ref, k_hbm, v_hbm, out_ref,
 
     jax.lax.fori_loop(0, chunks, attend, 0)
     context = jnp.where(own, acc[...] / denom[...], 0.0)
-    out_ref[...] = jnp.sum(context, axis=0, keepdims=True).astype(
-        out_ref.dtype)
+    if group == 1:
+        context = jnp.sum(context, axis=0, keepdims=True)
+    else:       # each row's own key/value head's lanes: the rest is zero
+        context = sum(context[:, first:first + head_dim]
+                      for first in range(0, width, head_dim))
+    out_ref[...] = context.astype(out_ref.dtype)
 
 
 def paged_decode_attention(query, key_pool, value_pool, table, cursor, *,
@@ -164,9 +196,12 @@ def paged_decode_attention(query, key_pool, value_pool, table, cursor, *,
 
     Args:
         query: ``[rows, heads, head_dim]`` (the compute dtype).
-        key_pool, value_pool: ``[slots, heads * head_dim]`` pools as
+        key_pool, value_pool: ``[slots, kv_heads * head_dim]`` pools as
             stored — this step's K and V already written at each row's
             slot. Left in HBM; only the blocks a row holds are read.
+            ``heads`` is ``kv_heads`` times a whole ``group``, read off
+            these shapes: query head ``h`` attends key/value head
+            ``h // group``.
         table: ``[rows, max_blocks]`` int32 physical block per logical
             block (unmapped columns point at the trash block).
         cursor: ``[rows]`` int32 position of this step's token; a row
@@ -175,27 +210,39 @@ def paged_decode_attention(query, key_pool, value_pool, table, cursor, *,
 
     Returns the ``[rows, heads, head_dim]`` context in ``query.dtype``.
     Raises ``ValueError`` where :func:`paged_plan` refuses the shapes —
-    the engine asks the plan first (``fused_paged_reason``)."""
+    the engine asks the plan first (``fused_paged_reason``,
+    :func:`tpusystem.ops.attention.paged_read`)."""
     interpret = auto_interpret(interpret)
     rows, heads, head_dim = query.shape
-    width = heads * head_dim
+    width = key_pool.shape[-1]
     max_blocks = table.shape[1]
-    if key_pool.shape != value_pool.shape or key_pool.shape[1:] != (width,):
+    if (key_pool.shape != value_pool.shape or key_pool.ndim != 2
+            or width % head_dim or (heads * head_dim) % width):
         raise ValueError(f'pools {key_pool.shape} / {value_pool.shape} do '
-                         f'not hold [slots, {width}]')
+                         f'not hold [slots, key/value heads * {head_dim}] '
+                         f'for {heads} query heads')
+    kv_heads = width // head_dim
+    group = heads // kv_heads
     chunk = paged_plan(heads, head_dim, block, max_blocks, key_pool.dtype,
-                       interpret)
+                       interpret, kv_heads)
     if chunk is None:
         raise ValueError(
-            f'paged_decode_attention cannot tile heads={heads} '
-            f'head_dim={head_dim} block={block} {key_pool.dtype} on the TPU')
+            f'paged_decode_attention cannot tile heads={heads} on '
+            f'{kv_heads} head_dim={head_dim} block={block} '
+            f'{key_pool.dtype} on the TPU')
     span = chunk * block
     padded_heads = -(-heads // 16) * 16        # whole bf16 sublane tiles
     kernel = functools.partial(
-        _kernel, head_dim=head_dim, block=block, chunk=chunk,
+        _kernel, head_dim=head_dim, group=group, block=block, chunk=chunk,
         max_seq=max_blocks * block, scale=head_dim ** -0.5)
     itemsize = jnp.dtype(key_pool.dtype).itemsize
-    per_row = pl.BlockSpec((None, 1, width), lambda r, *_: (r, 0, 0))
+    if group == 1:      # one row of every head's lanes, as the pool's
+        shape = (1, width)
+        query = query.reshape(rows, *shape)
+    else:               # a row a head (zero rows pad the sublane tile)
+        shape = (padded_heads, head_dim)
+        query = jnp.pad(query, ((0, 0), (0, padded_heads - heads), (0, 0)))
+    per_row = pl.BlockSpec((None, *shape), lambda r, *_: (r, 0, 0))
     in_place = pl.BlockSpec(memory_space=pl.ANY)
     context = pl.pallas_call(
         kernel,
@@ -213,7 +260,7 @@ def paged_decode_attention(query, key_pool, value_pool, table, cursor, *,
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ]),
-        out_shape=jax.ShapeDtypeStruct((rows, 1, width), query.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, *shape), query.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         cost_estimate=pl.CostEstimate(
@@ -222,7 +269,8 @@ def paged_decode_attention(query, key_pool, value_pool, table, cursor, *,
             transcendentals=rows * padded_heads * max_blocks * block),
         interpret=interpret,
         name='paged_decode_attention',
-    )(table, cursor, query.reshape(rows, 1, width),
-      streamed_from_hbm(key_pool, interpret),
+    )(table, cursor, query, streamed_from_hbm(key_pool, interpret),
       streamed_from_hbm(value_pool, interpret))
-    return context.reshape(rows, heads, head_dim)
+    if group == 1:
+        return context.reshape(rows, heads, head_dim)
+    return context[:, :heads]

@@ -84,6 +84,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpusystem.observe.profile import annotate
+from tpusystem.ops.attention import paged_read
 from tpusystem.parallel.mesh import on_tpu
 from tpusystem.serve.kvcache import (PagedKVCache, _is_kv, adopt_prefill,
                                      pool_shardings, write_tables)
@@ -622,6 +623,7 @@ class Engine:
                 if is_row_state(path) else None
             if kind is not None:
                 self.cache_bytes[kind] += leaf.size * leaf.dtype.itemsize
+        self.paged_read = self._resolve_paged_read()
         # what the module's layers sow for the host beside the tokens: the
         # names of their expert_load counters, and, for a routing_sink, the
         # [expert layers, k] of the experts a token was given
@@ -832,6 +834,32 @@ class Engine:
         if self._spec or reason is not None:
             return 'flax'
         return 'fused' if on_tpu() else 'flax'
+
+    def _resolve_paged_read(self) -> dict:
+        """Which read the decode step's attention layers take over the
+        key/value pool: ``{'read': 'kernel' | 'gather', 'reason': why a
+        gather}``, answered at construction from the cache's shapes
+        (:func:`tpusystem.ops.attention.paged_read`, the decision
+        ``paged_attention`` itself dispatches on; the fused step always
+        reads through the kernel). ``read`` is ``None`` over a latent
+        pool, whose read ``latent_attention`` chooses. The scheduler
+        marks it once in its tracer: ``paged_read``."""
+        if self.decode_impl == 'fused':
+            return {'read': 'kernel', 'reason': None}
+        pools = {path[-1].key: leaf for path, leaf
+                 in jax.tree_util.tree_leaves_with_path(self._cache)
+                 if _is_kv(path)}
+        if 'value' not in pools:
+            return {'read': None, 'reason': 'a latent pool holds no keys '
+                    'and values a head: latent_attention chooses its read'}
+        heads = self._decoder.heads
+        kv_heads = getattr(self._decoder, 'kv_heads', heads)
+        read, reason = paged_read(
+            self.speculate + 1 if self._spec else 1, heads, kv_heads,
+            pools['key'].shape[1] // kv_heads, self.block_size,
+            self.max_seq // self.block_size, pools['key'].dtype,
+            sharded=self.tp_plan.path == 'gspmd')
+        return {'read': read, 'reason': reason}
 
     # ----------------------------------------------------------- membership
 

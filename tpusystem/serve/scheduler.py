@@ -204,6 +204,11 @@ class Scheduler:
             # the recurrent layers' per-row state, once a trace
             tracer.instant('cache_bytes', cat='engine',
                            args=dict(engine.cache_bytes))
+        if tracer is not None and hasattr(engine, 'paged_read'):
+            # which read the decode step takes over the key/value pool
+            # (the Pallas walk or the bucketed gather, and why a gather)
+            tracer.instant('paged_read', cat='engine',
+                           args=dict(engine.paged_read))
 
     @property
     def queue_depth(self) -> int:
