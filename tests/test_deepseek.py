@@ -27,6 +27,7 @@ from chipbench.reference import deepseek_v2 as reference
 from tests.chipbench_tests.dsv2 import SHIPPED, tiny_config
 from tpusystem.models.deepseek import (yarn_correction_range,
                                        yarn_frequencies, yarn_softmax_scale)
+from tpusystem.observe.trace import Tracer
 from tpusystem.ops.attention import (expanded_latent_attention,
                                      latent_attention)
 from tpusystem.ops.moe import GatedExperts, group_limited_top_k, seat_held
@@ -304,7 +305,9 @@ def standalone(module, params, prompt, steps):
 def test_the_service_serves_it_through_one_step_over_a_latent_pool(served):
     config, module, params = served
     assert engine_unsupported_reason(module) is None
-    service = InferenceService(module, params, rows=4, block_size=16)
+    tracer = Tracer('serve')
+    service = InferenceService(module, params, rows=4, block_size=16,
+                               tracer=tracer)
     engine = service.engine
     assert engine.decode_impl == 'flax'
     reason = fused_paged_reason(engine._decoder)
@@ -322,7 +325,8 @@ def test_the_service_serves_it_through_one_step_over_a_latent_pool(served):
         assert service.scheduler.results[f'r{index}'].tokens == standalone(
             module, params, prompt, 8), f'r{index} diverged'
     assert engine.trace_count == 1
-    assert engine.membership_traces == {'seat': 1, 'clear': 1}
+    traced = tracer.compiled('trace')
+    assert (traced['seat'], traced['clear']) == (1, 1)
     assert engine.pool.live_blocks == 0
     engine.pool.audit()
     # the counters came with the tokens: two expert layers, 3 a token

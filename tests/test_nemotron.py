@@ -428,7 +428,8 @@ def test_the_service_serves_it_over_two_kinds_of_cache(served):
         assert service.scheduler.results[f'r{index}'].tokens == standalone(
             module, params, prompt, 8), f'r{index} diverged'
     assert engine.trace_count == 1
-    assert engine.membership_traces == {'seat': 1, 'clear': 1}
+    traced = tracer.compiled('trace')
+    assert (traced['seat'], traced['clear']) == (1, 1)
     assert engine.pool.live_blocks == 0
     engine.pool.audit()
     load = engine.expert_load
@@ -447,6 +448,7 @@ def test_a_row_seated_after_a_retired_one_reads_as_on_a_fresh_engine(served):
     _, module, params = served
     first, second, neighbour = (tokens_of(seed, size).tolist() for seed, size
                                 in ((40, 30), (41, 11), (42, 19)))
+    tracer = Tracer('rows').watch_compiles()
     engine = Engine(module, params, rows=2, block_size=16)
     gone = engine.admit(first, 4, tag='first')
     stays = engine.admit(neighbour, 30, tag='neighbour')
@@ -466,7 +468,8 @@ def test_a_row_seated_after_a_retired_one_reads_as_on_a_fresh_engine(served):
     fresh = Engine(module, params, rows=2, block_size=16)
     fresh.admit(second, 9)
     assert list(drain(fresh).values()) == [retired[again.row]]
-    assert engine.membership_traces == {'seat': 1, 'clear': 1}
+    traced = tracer.compiled('trace')     # the fresh engine's, and this one's
+    assert (traced['seat'], traced['clear']) == (2, 2)
 
 
 @pytest.mark.parametrize('read, widths, why', [

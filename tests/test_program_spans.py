@@ -188,7 +188,8 @@ def test_tracer_gets_one_admit_span_per_request_inside_queued(served):
     tracer = Tracer('serve', clock=lambda: float(next(clock)))
     service, _ = serve(served, tracer, clock=tracer.clock)
     service.run_until_idle()
-    events = [event for event in tracer.events() if event['ph'] == 'X']
+    events = [event for event in tracer.events() if event['ph'] == 'X'
+              and event['cat'] not in ('setup', 'compile')]
     by_trace = connected_traces(events)
     assert len(by_trace) == len(PROMPTS)
     for group in by_trace.values():
@@ -205,6 +206,49 @@ def test_tracer_gets_one_admit_span_per_request_inside_queued(served):
         assert admit['args']['prompt_tokens'] == len(prompt)
         assert admit['args']['bucket'] == service.engine.bucket(len(prompt))
         assert admit['args']['row'] == named['decode']['args']['row']
+
+
+def test_the_service_records_its_set_up_and_every_compile():
+    """``InferenceService(tracer=)`` watches compiles from before the
+    engine: ``setup.engine`` holds the construction, the warm-up traces the
+    seat, clear and decode programs and one prefill program a bucket, each
+    once, and twenty more ticks with admissions at the warm buckets trace
+    nothing. (A module of its own: prefill programs are kept by module and
+    bucket, and the other tests' module has its programs already.)"""
+    module = GPT2(**{**TINY, 'max_seq': 48})
+    params = module.init(jax.random.PRNGKey(1),
+                         jnp.zeros((1, 8), jnp.int32))['params']
+    tracer = Tracer('serve', clock=time.perf_counter)
+    before = time.perf_counter()
+    service, _ = serve((module, params), tracer)
+    built = time.perf_counter()
+    service.run_until_idle()
+    (engine,) = [event for event in tracer.events()
+                 if event['name'] == 'setup.engine']
+    assert engine['cat'] == 'setup'
+    assert before <= engine['ts'] * 1e-6
+    assert (engine['ts'] + engine['dur']) * 1e-6 <= built
+    traced = tracer.compiled('trace')
+    buckets = {service.engine.bucket(len(prompt)) for prompt in PROMPTS}
+    assert (traced['seat'], traced['clear'], traced['step_fn']) == (1, 1, 1)
+    assert traced['run'] == len(buckets) > 1       # the prefill programs
+    backend = tracer.compiled('backend')
+    for fun in ('seat', 'clear', 'step_fn'):
+        assert backend[f'jit({fun})'] == 1, fun
+    assert tracer.compiles['trace'] == sum(traced.values())
+    assert tracer.compiles['backend'] == sum(backend.values())
+    # every compile span lies on the tracer's clock, after the tracer began
+    spans = [event for event in tracer.events()
+             if event.get('cat') == 'compile']
+    assert min(event['ts'] for event in spans) * 1e-6 >= before - 1e-3
+    warm = len(tracer)
+    for tick in range(20):
+        if tick % 4 == 0:
+            service.submit(Request(f'again{tick}', PROMPTS[tick // 4 % 3], 3))
+        service.step()
+    assert service.scheduler.steps and service.results['again16'].tokens
+    assert [span.name for span in list(tracer._spans.values())[warm:]
+            if span.cat == 'compile'] == []
 
 
 def test_prefill_only_scheduler_closes_admit_at_the_export(served):

@@ -21,6 +21,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec
 
 from tpusystem.models import deepseek_tiny, gpt2_tiny
+from tpusystem.observe import Tracer
 from tpusystem.parallel import MeshSpec
 from tpusystem.serve import Engine, SamplingParams
 from tpusystem.serve import engine as engine_module
@@ -171,6 +172,7 @@ def churned(request, served):
     """One engine of each kind after the 24 admissions, a cancellation and
     every eviction, with each difference from the per-element expectation
     noted where it arose."""
+    tracer = Tracer('churn').watch_compiles()
     engine = build(request.param, served)
     fanout = engine.tree_fanout if request.param == 'speculative' else 1
     expected, differences = PerElement(engine), []
@@ -198,11 +200,11 @@ def churned(request, served):
         expected.stepped(engine, engine.step())
         differences += expected.differences(
             engine, f'the step after admission {admitted}')
-    return request.param, engine, differences, admitted, cancelled
+    return request.param, engine, differences, admitted, cancelled, tracer
 
 
 def test_arrays_hold_what_one_write_per_element_leaves(churned):
-    _, engine, differences, admitted, cancelled = churned
+    _, engine, differences, admitted, cancelled, _ = churned
     assert (admitted, cancelled) == (24, 1)
     assert differences == []
     assert not engine.active_rows and engine.sampled_rows == 0
@@ -213,14 +215,15 @@ def test_arrays_hold_what_one_write_per_element_leaves(churned):
 
 
 def test_each_program_traced_once_whatever_was_admitted(churned):
-    _, engine, _, admitted, _ = churned
+    _, engine, _, admitted, _, tracer = churned
     assert admitted >= 20
-    assert engine.membership_traces == {'seat': 1, 'clear': 1}
+    traced = tracer.compiled('trace')
+    assert (traced['seat'], traced['clear']) == (1, 1)
     assert engine.trace_count == 1
 
 
 def test_arrays_stay_where_the_engine_placed_them(churned):
-    kind, engine, _, _, _ = churned
+    kind, engine, _, _, _, _ = churned
     for name in ARRAYS:
         array = getattr(engine, name)
         if kind == 'sharded':
@@ -279,6 +282,7 @@ def test_host_typed_operands_retrace_no_prefill_program(served):
     """One prefill program per bucket serves every kind of request: the
     host-typed scalars carry the types the device-built ones had."""
     module, params = served
+    tracer = Tracer('prefill').watch_compiles()
     engine = Engine(module, params, rows=4, block_size=8)
     prompt = [9, 8, 7, 6, 5]
     engine.admit(prompt, 3)
@@ -290,4 +294,5 @@ def test_host_typed_operands_retrace_no_prefill_program(served):
     engine.admit(prompt + [1, 2], 3, emitted=(1, 2),
                  sampling=SamplingParams(seed=9, temperature=1.2, top_k=4))
     assert run._cache_size() == traced
-    assert engine.membership_traces == {'seat': 1, 'clear': 0}
+    programs = tracer.compiled('trace')
+    assert (programs['seat'], programs['clear']) == (1, 0)

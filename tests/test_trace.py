@@ -9,7 +9,9 @@ trace. Histogram percentiles are pinned against a literal sorted-array
 reference; merge-order invariance is pinned by merging shards in every
 permutation. The flight-recorder SIGKILL contract is drilled with a
 real subprocess (write-ahead cadence = what survives a kill that runs
-no handler)."""
+no handler). The compile spans (``Tracer.watch_compiles``) are the
+exception to "zero compiles": they compile tiny functions of their own,
+and the process-wide listeners are pinned in a subprocess of their own."""
 
 import dataclasses
 import json
@@ -716,3 +718,134 @@ def test_connected_traces_raises_on_a_dangling_parent():
     collector.merge(origin)
     collector.merge(survivor)
     connected(collector.events())        # merged: no raise
+
+
+# ---------------------------------------------------------------------------
+# compile spans: jax.monitoring's compile stages on the tracer's clock
+# ---------------------------------------------------------------------------
+
+
+def _stages(tracer, fun: str) -> list:
+    return [(event['name'], event['args'].get('cached'))
+            for event in tracer.events()
+            if event.get('cat') == 'compile' and event['args']['fun'] in
+            (fun, f'jit({fun})')]
+
+
+class TestCompileSpans:
+
+    def test_a_new_function_records_each_stage_once_a_shape(self):
+        import jax
+        import jax.numpy as jnp
+
+        tracer = Tracer('p', clock=time.perf_counter).watch_compiles()
+
+        @jax.jit
+        def widen(x):
+            return jnp.concatenate([x, x]) * 2
+
+        before = time.perf_counter()
+        widen(jnp.ones(3))
+        after = time.perf_counter()
+        names = [name for name, _ in _stages(tracer, 'widen')]
+        assert names == ['compile.trace', 'compile.lower', 'compile.backend']
+        for event in tracer.events():
+            if event.get('cat') == 'compile' and 'widen' in event['args']['fun']:
+                start = event['ts'] * 1e-6
+                assert before - 1e-3 <= start
+                assert start + event['dur'] * 1e-6 <= after + 1e-3
+        assert tracer.compiled('trace')['widen'] == 1
+        assert tracer.compiled('backend')['jit(widen)'] == 1
+        assert tracer.compiles['trace_s'] > 0 < tracer.compiles['backend_s']
+        widen(jnp.ones(3))                  # the same shape: nothing new
+        assert len(_stages(tracer, 'widen')) == 3
+        widen(jnp.ones(5))                  # a new one: every stage again
+        assert tracer.compiled('trace')['widen'] == 2
+        assert len(_stages(tracer, 'widen')) == 6
+
+    def test_a_cache_verdict_lands_on_its_backend_compile(self):
+        import jax
+
+        tracer = Tracer('p', clock=FakeClock()).watch_compiles()
+        backend = '/jax/core/compile/backend_compile_duration'
+        now = time.time()
+        jax.monitoring.record_event('/jax/compilation_cache/cache_hits')
+        jax.monitoring.record_event_time_span(backend, now - 2.0, now - 1.5,
+                                              fun_name='jit(hit)')
+        jax.monitoring.record_event('/jax/compilation_cache/cache_misses')
+        jax.monitoring.record_event_time_span(backend, now - 1.0, now,
+                                              fun_name='jit(miss)')
+        jax.monitoring.record_event_time_span(backend, now, now + 0.25,
+                                              fun_name='jit(asked_none)')
+        assert _stages(tracer, 'hit') == [('compile.backend', True)]
+        assert _stages(tracer, 'miss') == [('compile.backend', False)]
+        assert _stages(tracer, 'asked_none') == [('compile.backend', None)]
+        assert (tracer.compiles['hits'], tracer.compiles['misses'],
+                tracer.compiles['backend']) == (1, 1, 3)
+        assert tracer.compiles['backend_s'] == pytest.approx(1.75)
+        # converted once, onto the fake clock: now - 1 s reads 99 there
+        (miss,) = [event for event in tracer.events()
+                   if event['args'].get('fun') == 'jit(miss)']
+        assert miss['ts'] * 1e-6 == pytest.approx(99.0, abs=0.05)
+        assert miss['dur'] == pytest.approx(1e6)
+
+    def test_a_tracer_that_does_not_watch_records_nothing(self):
+        import jax
+        import jax.numpy as jnp
+
+        quiet = Tracer('quiet')
+        loud = Tracer('loud').watch_compiles()
+        jax.jit(lambda x: x - 7)(jnp.ones(2))
+        assert loud.compiled('trace')['<lambda>'] >= 1
+        assert len(quiet) == 0 and not quiet.compiles
+
+    def test_a_dropped_tracer_stops_receiving(self):
+        import gc
+        import weakref
+
+        import jax
+        import jax.numpy as jnp
+
+        from tpusystem.observe.trace import _CompileWatch
+
+        tracer = Tracer('gone').watch_compiles()
+        assert tracer in _CompileWatch.watchers
+        gone = weakref.ref(tracer)
+        del tracer
+        gc.collect()
+        assert gone() is None
+        assert all(watcher.process != 'gone'
+                   for watcher in _CompileWatch.watchers)
+        jax.jit(lambda x: x * 9)(jnp.ones(4))     # forwards to no one
+
+    def test_no_listener_is_installed_before_the_first_watcher(self):
+        """A process whose service was built with no tracer installs no
+        ``jax.monitoring`` listener; the first watching tracer does."""
+        script = (
+            'import jax, jax.numpy as jnp\n'
+            'from jax._src import monitoring\n'
+            'from tpusystem.models import GPT2\n'
+            'from tpusystem.observe import Tracer\n'
+            'from tpusystem.observe.trace import _CompileWatch\n'
+            'from tpusystem.serve import InferenceService, Request\n'
+            'module = GPT2(vocab_size=64, layers=1, dim=16, heads=2,'
+            ' max_seq=32, dropout=0.0)\n'
+            'params = module.init(jax.random.PRNGKey(0),'
+            ' jnp.zeros((1, 8), jnp.int32))["params"]\n'
+            'service = InferenceService(module, params, rows=2,'
+            ' block_size=8)\n'
+            'service.submit(Request("r", [3, 4, 5], 3))\n'
+            'service.run_until_idle()\n'
+            'ours = lambda: (_CompileWatch.span in'
+            ' monitoring.get_event_time_span_listeners(),'
+            ' _CompileWatch.event in monitoring.get_event_listeners())\n'
+            'print(_CompileWatch.installed, *ours())\n'
+            'Tracer("t").watch_compiles()\n'
+            'print(_CompileWatch.installed, *ours())\n')
+        done = subprocess.run(
+            [sys.executable, '-c', script], capture_output=True, text=True,
+            timeout=120, cwd=pathlib.Path(__file__).resolve().parents[1],
+            env={**os.environ, 'JAX_PLATFORMS': 'cpu'})
+        assert done.returncode == 0, done.stderr[-2000:]
+        assert done.stdout.split('\n')[:2] == ['False False False',
+                                                'True True True']

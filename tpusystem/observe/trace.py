@@ -35,6 +35,13 @@ Design rules, inherited from the rest of the framework:
   receiver and :meth:`Tracer.merge` folds any packed set in, so rank 0
   exports one JSON file showing the whole fleet.
 
+* **Compiles are spans too** — :meth:`Tracer.watch_compiles` records each
+  stage JAX reports through ``jax.monitoring`` (trace to a jaxpr, lowering
+  to MLIR, backend compile or persistent-cache load) as a ``compile.*``
+  span, and counts them in :attr:`Tracer.compiles`. The listeners are
+  process-wide: installed once, by the first tracer that watches, and never
+  before, so a process with no watching tracer runs none of this.
+
 Spans are tiny host-side records (name, ids, two floats, a small args
 dict) — never device arrays; recording happens at lifecycle edges
 (submit/admit/complete, recovery stages), never per token.
@@ -50,6 +57,8 @@ import pathlib
 import pickle
 import threading
 import time
+import weakref
+from collections import Counter
 from typing import Any, Callable, Iterator
 
 from tpusystem.observe.profile import annotate
@@ -121,6 +130,9 @@ class Tracer:
         self._lock = threading.Lock()
         self._seq = 0
         self._spans: dict[str, Span] = {}        # span_id -> Span (ordered)
+        # compile stages seen while watching: 'trace', 'lower', 'backend',
+        # 'hits', 'misses', and '<stage>_s' seconds (watch_compiles)
+        self.compiles: Counter = Counter()
 
     # ------------------------------------------------------------- record
 
@@ -205,6 +217,45 @@ class Tracer:
         if self.sink is not None:
             self.sink(span)
         return span
+
+    # ----------------------------------------------------------- compile
+
+    def watch_compiles(self) -> 'Tracer':
+        """Record every compile JAX reports from now on as a complete span
+        (``cat='compile'``, ``args={'fun': <function name>}``):
+        ``compile.trace`` (to a jaxpr), ``compile.lower`` (to an MLIR
+        module) and ``compile.backend`` (the backend compile or the load
+        from the persistent cache; ``cached`` True on a hit, False on a
+        miss, absent where the cache was not consulted or a miss was not
+        written). Each is counted in :attr:`compiles` beside its seconds.
+        Idempotent; the tracer is held weakly, so dropping it stops it."""
+        _CompileWatch.add(self)
+        return self
+
+    def compiled(self, stage: str = 'trace') -> Counter:
+        """How often each function went through ``stage`` (``'trace'``,
+        ``'lower'`` or ``'backend'``) while this tracer watched, by name:
+        ``compiled()['seat'] == 1`` says the seat program traced once."""
+        name = f'compile.{stage}'
+        with self._lock:
+            return Counter(span.args['fun'] for span in self._spans.values()
+                           if span.name == name)
+
+    def _compiled(self, stage: str, fun: str, start: float, end: float,
+                  cached: bool | None) -> None:
+        """One compile stage, timed on ``time.time()``, onto this
+        tracer's clock."""
+        offset = self.clock() - time.time()
+        args = {'fun': fun}
+        if cached is not None:
+            args['cached'] = cached
+        self.record(f'compile.{stage}', start + offset, end + offset,
+                    cat='compile', args=args)
+        with self._lock:
+            self.compiles[stage] += 1
+            self.compiles[f'{stage}_s'] += end - start
+            if cached is not None:
+                self.compiles['hits' if cached else 'misses'] += 1
 
     # ----------------------------------------------------------- collect
 
@@ -299,6 +350,51 @@ class Tracer:
     def __len__(self) -> int:
         with self._lock:
             return len(self._spans)
+
+
+class _CompileWatch:
+    """The process's one pair of ``jax.monitoring`` listeners, forwarding
+    to the tracers that watch. A cache hit or miss is an event that fires
+    inside the backend compile it belongs to: it waits, per thread, for
+    that compile's span."""
+
+    STAGES = {'/jax/core/compile/jaxpr_trace_duration': 'trace',
+              '/jax/core/compile/jaxpr_to_mlir_module_duration': 'lower',
+              '/jax/core/compile/backend_compile_duration': 'backend'}
+    CACHE = {'/jax/compilation_cache/cache_hits': True,
+             '/jax/compilation_cache/cache_misses': False}
+    watchers: 'weakref.WeakSet[Tracer]' = weakref.WeakSet()
+    installed = False
+    _pending = threading.local()       # .cached: the open compile's verdict
+    _lock = threading.Lock()
+
+    @classmethod
+    def add(cls, tracer: Tracer) -> None:
+        with cls._lock:
+            if not cls.installed:
+                import jax.monitoring
+                jax.monitoring.register_event_time_span_listener(cls.span)
+                jax.monitoring.register_event_listener(cls.event)
+                cls.installed = True
+            cls.watchers.add(tracer)
+
+    @classmethod
+    def span(cls, event: str, start: float, end: float, **meta) -> None:
+        stage = cls.STAGES.get(event)
+        if stage is None:
+            return
+        cached = None
+        if stage == 'backend':
+            cached = getattr(cls._pending, 'cached', None)
+            cls._pending.cached = None
+        for tracer in list(cls.watchers):
+            tracer._compiled(stage, str(meta.get('fun_name', '?')), start,
+                             end, cached)
+
+    @classmethod
+    def event(cls, event: str, **meta) -> None:
+        if event in cls.CACHE:
+            cls._pending.cached = cls.CACHE[event]
 
 
 def connected_traces(events: list) -> dict:

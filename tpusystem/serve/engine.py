@@ -540,7 +540,8 @@ class Engine:
     The decode step traces exactly once per engine (``trace_count`` is
     the witness); admissions and evictions are host-side table edits
     plus fixed-shape device writes, the per-row state among them in one
-    compiled program each (``membership_traces`` is their witness).
+    compiled program each (a watching :class:`~tpusystem.observe.Tracer`'s
+    ``compile.trace`` spans of ``seat`` and ``clear`` are their witness).
     """
 
     def __init__(self, module, params, *, rows: int = 4,
@@ -679,9 +680,6 @@ class Engine:
                          '_mask_dev'):
                 setattr(self, name,
                         jax.device_put(getattr(self, name), everywhere))
-        # how often each membership program was traced: 1 each, whatever
-        # was admitted (trace_count stays the decode step's alone)
-        self.membership_traces = {'seat': 0, 'clear': 0}
         self._seat_rows, self._clear_rows = self._build_membership(
             everywhere)
         # the operands of an unsampled prefill, by vocabulary (the draft's
@@ -886,7 +884,6 @@ class Engine:
                 array, block, (row,) + (0,) * (array.ndim - 1))
 
         def seat(tokens, active, seed, pos, temp, topk, topp, ints, floats):
-            self.membership_traces['seat'] += 1      # runs at trace time only
             row, token, _, position, top_k = ints.astype(jnp.int32)
             return (put(tokens, row, token), put(active, row, True),
                     put(seed, row, ints[2]), put(pos, row, position),
@@ -894,7 +891,6 @@ class Engine:
                     put(topp, row, floats[1]))
 
         def clear(active, temp, mask, row):
-            self.membership_traces['clear'] += 1     # runs at trace time only
             return (put(active, row, False), put(temp, row, 0.0),
                     put(mask, row, True))
 

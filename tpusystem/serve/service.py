@@ -27,6 +27,7 @@ no-op and the router journal carries settled results across a takeover.
 
 from __future__ import annotations
 
+import contextlib
 import random
 import time
 
@@ -51,6 +52,10 @@ class InferenceService:
     narrates every lifecycle transition on ``producer``. Drive it
     directly (:meth:`submit` / :meth:`step` / :meth:`run_until_idle`) or
     by name through :attr:`service` (``handle('submit', request)``).
+    A ``tracer`` (:class:`~tpusystem.observe.Tracer`) gets the
+    scheduler's request spans, one ``setup.engine`` span over the engine's
+    construction and, watching from before it, every compile
+    (:meth:`~tpusystem.observe.Tracer.watch_compiles`).
     """
 
     def __init__(self, module, params, *, producer: Producer | None = None,
@@ -59,8 +64,15 @@ class InferenceService:
                  clock=time.monotonic, max_queued: int | None = None,
                  watermarks=None, tracer=None, **levers) -> None:
         knobs = {**serve_levers(), **levers}
-        self.engine = Engine(module, params, rows=rows,
-                             block_size=block_size, blocks=blocks, **knobs)
+        building = contextlib.nullcontext()
+        if tracer is not None:
+            # every compile from here on is a span, the warm-up's and any
+            # under load; the construction itself is set-up's own span
+            tracer.watch_compiles()
+            building = tracer.span('setup.engine', cat='setup')
+        with building:
+            self.engine = Engine(module, params, rows=rows,
+                                 block_size=block_size, blocks=blocks, **knobs)
         self.scheduler = Scheduler(self.engine,
                                    prefill_budget=prefill_budget,
                                    clock=clock, max_queued=max_queued,
