@@ -92,3 +92,30 @@ def _cells_as_written(request, monkeypatch):
         return cell
 
     monkeypatch.setattr(harness, 'load_cell', load_cell)
+
+
+# ``test_chipbench_compile_spans`` asserts that its nine metrics are the
+# last per-layer entries and that they list the three serving cells that
+# existed when it was written. It is handed ``BENCHMARK.json`` as it was
+# then, the way ``tests/chipbench_tests/conftest.py`` hands the expert
+# cells' module its own: each list cut behind the module's last entry, and
+# the cells added since taken out of every ``workloads`` list.
+BENCHMARK_WRITTEN_AGAINST = {
+    'test_chipbench_compile_spans': {
+        'configs': 'nemotron-3-nano-30b-a3b',
+        'workloads': 'serve-nemotron3-closed96',
+        'per_layer': 'tick_gap_ms.tick'}}
+
+
+@pytest.fixture(autouse=True, scope='module')
+def _benchmark_as_written(request):
+    last = BENCHMARK_WRITTEN_AGAINST.get(
+        request.module.__name__.rpartition('.')[2])
+    if last is None:
+        yield
+        return
+    from tests.chipbench_tests.conftest import cut_off
+    whole = request.module.BENCH
+    request.module.BENCH = cut_off(whole, last)
+    yield
+    request.module.BENCH = whole

@@ -158,6 +158,35 @@ def test_prefill_compile_count_is_bounded_by_buckets():
     assert added == 2, f'{added} prefill programs for 2 buckets'
 
 
+def test_a_bucket_floor_pads_short_prompts_to_one_program():
+    """``bucket_floor`` pads every prompt under it to one prefill width:
+    one program where there would be two, the same tokens as an engine
+    without the floor, and the floored width in the admission's cost."""
+    from tpusystem.serve import engine as engine_module
+    # a config no other test decodes, so the program-cache delta is ours
+    module = gpt2_tiny(dtype='float32', max_seq=192)
+    params = module.init(jax.random.PRNGKey(0),
+                         jnp.zeros((1, 8), jnp.int32))['params']
+    plain = Engine(module, params, rows=1, block_size=16)
+    floored = Engine(module, params, rows=1, block_size=16, bucket_floor=40)
+    assert [floored.bucket(n) for n in (3, 17, 40, 65)] == [64, 64, 64, 128]
+    assert floored.admit_cost(np.arange(5)) == 64
+    assert plain.bucket(3) == 16
+    before = engine_module._compiled_prefill.cache_info().currsize
+    for length in (3, 20, 33):                     # buckets: 64 only
+        prompt = np.arange(length) % 250 + 1
+        outputs = []
+        for engine in (floored, plain):
+            engine.admit(prompt, max_new=4)
+            while engine.active_rows:
+                for _row, _reason, out in engine.step().finished:
+                    outputs.append(out)
+        assert outputs[0] == outputs[1]
+    added = engine_module._compiled_prefill.cache_info().currsize - before
+    # the two engines' prefill clones are equal, so they share the 64
+    assert added == 3, f'{added} programs: 64 floored; 16, 32 (64) plain'
+
+
 # ---------------------------------------------------------------------------
 # token-exact parity vs standalone generate(), under churn
 # ---------------------------------------------------------------------------

@@ -550,13 +550,13 @@ class Engine:
                  share_prefix: bool = False, draft_module=None,
                  draft_params=None, speculate: int = 4,
                  tree_fanout: int = 1, mesh=None, schedule=None,
-                 routing_sink=None) -> None:
+                 routing_sink=None, bucket_floor: int = 0) -> None:
         reason = engine_unsupported_reason(module)
         if reason is not None:
             raise ValueError(f'the serving engine cannot run this module: '
                              f'{reason}')
         self.rows, self.block_size = rows, block_size
-        self.max_seq = module.max_seq
+        self.max_seq, self.bucket_floor = module.max_seq, bucket_floor
         if blocks is None:
             blocks = rows * (self.max_seq // block_size) + 1
         self.stream_dtype = stream_dtype
@@ -1039,7 +1039,15 @@ class Engine:
         return total <= self.pool.free_blocks
 
     def bucket(self, prompt_len: int) -> int:
-        return prefill_bucket(prompt_len, self.block_size, self.max_seq)
+        """The prefill program's width for a prompt: the smallest power of
+        two at or above the largest of its length, the block size and
+        ``bucket_floor`` (an ``Engine`` argument, 0 by default), at most
+        ``max_seq``. A floor
+        pads short prompts to one program where the prefill costs its
+        weight stream whatever the length: fewer compiled programs, and
+        one admission cost for every prompt under the floor."""
+        return prefill_bucket(max(prompt_len, self.bucket_floor),
+                              self.block_size, self.max_seq)
 
     def prefix_cached_len(self, prompt) -> int:
         """How many leading prompt tokens the radix index would serve
@@ -1294,7 +1302,6 @@ class Engine:
         with annotate('tpusystem.engine.prefill'):
             first, prefill_cache, *routing = self._prefill_rows(
                 prompt, rows, ops)
-            first = int(first)
         self.timings['prefill'] += time.perf_counter() - started
 
         started = time.perf_counter()
@@ -1312,6 +1319,13 @@ class Engine:
                     self._dcache, draft_cache, jnp.asarray(rows, jnp.int32),
                     prompt.size)
         self.timings['admit'] += time.perf_counter() - started
+
+        # the first token is read once the adoption is dispatched: the
+        # host's adoption runs while the chip computes the prefill
+        started = time.perf_counter()
+        with annotate('tpusystem.engine.prefill'):
+            first = int(first)
+        self.timings['prefill'] += time.perf_counter() - started
         return self._register(
             rep, rows, prompt, first, max_new, stop_token, tag, sampling,
             emitted, routing=_compact(np.asarray(routing[0])[:prompt.size])
@@ -1741,6 +1755,24 @@ class Engine:
     def tokens(self, row: int) -> list:
         """Tokens emitted so far for a seated row."""
         return list(self._rowstate[row].tokens)
+
+    @property
+    def recurrence(self) -> dict:
+        """How the decode step's stack runs a token, from the module and the
+        cache's shapes: ``passes`` (how many times the stack runs: a looped
+        model's ``passes``, else 1), ``cache_layers`` (the attention
+        applications that keep a cache of their own: one ``key`` leaf each,
+        every pass of every layer in a looped model) and
+        ``kv_bytes_per_position`` (the keys and values one cached position
+        holds across them). The scheduler marks it once in its tracer:
+        ``recurrence``."""
+        pools = [(path[-1].key, leaf) for path, leaf
+                 in jax.tree_util.tree_leaves_with_path(self._cache)
+                 if _is_kv(path)]
+        return {'passes': getattr(self._decoder, 'passes', 1),
+                'cache_layers': sum(name == 'key' for name, _ in pools),
+                'kv_bytes_per_position': sum(
+                    leaf.shape[-1] * leaf.dtype.itemsize for _, leaf in pools)}
 
 
 @dataclasses.dataclass

@@ -209,6 +209,11 @@ class Scheduler:
             # (the Pallas walk or the bucketed gather, and why a gather)
             tracer.instant('paged_read', cat='engine',
                            args=dict(engine.paged_read))
+        if tracer is not None and hasattr(engine, 'recurrence'):
+            # how many times the stack runs a token and how many caches
+            # each position fills (every pass of every layer when looped)
+            tracer.instant('recurrence', cat='engine',
+                           args=dict(engine.recurrence))
 
     @property
     def queue_depth(self) -> int:
