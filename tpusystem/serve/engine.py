@@ -9,8 +9,9 @@ changes every step **without retracing**:
 
 * **admit** — the prompt prefills through a plain contiguous decode
   apply (one compiled prefill program per pad bucket —
-  :func:`prefill_bucket`), the resulting KV strip scatters into
-  free-list blocks (:func:`tpusystem.serve.kvcache.adopt_prefill`), and
+  :func:`prefill_bucket`), the blocks the prompt fills of the resulting
+  KV strip are written whole into free-list blocks
+  (:func:`tpusystem.serve.kvcache.adopt_prefill`), and
   the row's block table and cursor are edited host-side. The prefill
   logits' argmax is the request's first token.
 * **step** — every row advances one token a dispatch, sent ahead of the
@@ -1269,14 +1270,34 @@ class Engine:
                 self._mask_dev = self._mask_dev.at[row].set(mask)
         return Admission(rep, first, False)
 
-    def _adopt(self, prefill_cache, rows: list[int], length: int) -> None:
-        """Scatter a contiguous prefill strip into every row's blocks and
-        publish the edited block tables."""
-        for row in rows:
-            self._cache = adopt_prefill(
-                self._cache, prefill_cache,
-                jnp.asarray(self.pool.adoption_slots(row)), row, length)
-        self._cache = write_tables(self._cache, self.pool.table)
+    @functools.cached_property
+    def adoption(self) -> dict:
+        """What admissions wrote into the pool: ``admissions`` (adoptions
+        of a prefill strip), ``blocks`` (whole blocks written, those the
+        prompts fill past a shared prefix, every row of a group) and
+        ``shared_blocks`` (a shared prefix's blocks, left as they were)."""
+        return {'admissions': 0, 'blocks': 0, 'shared_blocks': 0}
+
+    def _adoption(self, rows: list[int],
+                  length: int) -> tuple[list[np.ndarray], int]:
+        """Each row's blocks for :func:`adopt_prefill` to write and how many
+        that is, counted in :attr:`adoption`."""
+        blocks = [self.pool.adoption_blocks(row, length) for row in rows]
+        written = sum(int((ids < self.pool.blocks).sum()) for ids in blocks)
+        self.adoption['admissions'] += 1
+        self.adoption['blocks'] += written
+        self.adoption['shared_blocks'] += sum(
+            self.pool.shared_tokens(row) for row in rows) // self.block_size
+        return blocks, written
+
+    def _adopt(self, prefill_cache, rows: list[int],
+               blocks: list[np.ndarray], length: int) -> None:
+        """Write the blocks a prompt fills of a contiguous prefill strip
+        into every row's own blocks, with the edited block tables."""
+        for row, ids in zip(rows, blocks):
+            self._cache = adopt_prefill(self._cache, prefill_cache,
+                                        jnp.asarray(ids), self.pool.table,
+                                        row, length)
 
     def admit(self, prompt, max_new: int, *, stop_token: int | None = None,
               tag=None, sampling=None, emitted=()) -> Admission:
@@ -1305,8 +1326,9 @@ class Engine:
         self.timings['prefill'] += time.perf_counter() - started
 
         started = time.perf_counter()
-        with annotate('tpusystem.engine.adopt'):
-            self._adopt(prefill_cache, rows, prompt.size)
+        blocks, written = self._adoption(rows, prompt.size)
+        with annotate('tpusystem.engine.adopt', blocks=written):
+            self._adopt(prefill_cache, rows, blocks, prompt.size)
             if self._spec:
                 dbucket = prefill_bucket(prompt.size, self.block_size,
                                          self._drafter.max_seq)
@@ -1422,8 +1444,9 @@ class Engine:
         rep, rows = self._seat(prompt, max_new)
 
         started = time.perf_counter()
-        with annotate('tpusystem.engine.adopt'):
-            self._adopt(prefill_cache, rows, prompt.size)
+        blocks, written = self._adoption(rows, prompt.size)
+        with annotate('tpusystem.engine.adopt', blocks=written):
+            self._adopt(prefill_cache, rows, blocks, prompt.size)
         self.timings['admit'] += time.perf_counter() - started
         return self._register(rep, rows, prompt, int(first), max_new,
                               stop_token, tag, sampling, emitted)

@@ -9,12 +9,13 @@ free, which row owns which blocks, and what every row's table says —
 :class:`PagedKVCache` — plus the two jitted cache edits the engine uses to
 change batch membership without retracing its decode step:
 
-* :func:`adopt_prefill` scatters a prefilled contiguous KV strip into a
-  row's allocated blocks (one program total — admission is a pair of
-  device calls, never a reshape of the pool);
+* :func:`adopt_prefill` writes the blocks a prompt fills of a prefilled
+  contiguous KV strip into a row's allocated blocks, whole, in place, and
+  the tables with them (one program total — an admission is the prefill
+  and this one device call, never a copy of the pool);
 * :func:`write_tables` replaces every layer's ``table`` cache leaf with
-  the host authority's current map (evictions and admissions both reduce
-  to this table edit).
+  the host authority's current map (an eviction is this table edit; an
+  admission makes it inside :func:`adopt_prefill`).
 
 Physical block 0 is the reserved **trash block**: unmapped table entries
 point there, so a retired row's dead writes (the fixed-shape step keeps
@@ -64,8 +65,8 @@ class PagedKVCache:
     docstring): ``admit`` then takes the row's prompt tokens, reuses
     every cached whole-block prefix match by reference, and
     :meth:`shared_tokens` tells the engine how many leading positions
-    arrived pre-filled (so it can skip recomputing them and must NOT
-    scatter over them). Default off — the unshared accounting below is a
+    arrived pre-filled (so it can skip recomputing them, and adoption
+    skips their blocks). Default off — the unshared accounting below is a
     pinned contract of its own.
     """
 
@@ -193,16 +194,13 @@ class PagedKVCache:
     # --------------------------------------------------------- admission
 
     def admit(self, row: int, tokens: int, prompt=None) -> np.ndarray:
-        """Allocate ``tokens`` worth of blocks to ``row`` and return the
-        ``[max_seq]`` physical token-slot map of the row (positions past
-        the allocation map to trash) — the scatter index
-        :func:`adopt_prefill` writes the prefilled KV through.
+        """Allocate ``tokens`` worth of blocks to ``row`` and return their
+        physical ids, in logical order.
 
         With ``share_prefix`` and a ``prompt``, the leading blocks come
-        from the radix index where it matches (refcount increment — the
-        caller must then mask its adoption scatter below
-        :meth:`shared_tokens` so shared blocks stay write-once) and the
-        prompt's own fully-covered blocks are registered for future
+        from the radix index where it matches (refcount increment — they
+        are write-once, so :meth:`adoption_blocks` leaves them out) and
+        the prompt's own fully-covered blocks are registered for future
         admissions."""
         if row in self._owned:
             raise ValueError(f'row {row} already owns blocks — evict first')
@@ -232,7 +230,7 @@ class PagedKVCache:
         if self.share_prefix and prompt is not None:
             self._shared_len[row] = len(shared_ids) * self.block_size
             self._register(ids, prompt)
-        return self.slots(row)
+        return np.asarray(ids, np.int32)
 
     def slots(self, row: int) -> np.ndarray:
         """``[max_seq]`` physical token slot of each logical position of
@@ -242,21 +240,17 @@ class PagedKVCache:
         return (physical * self.block_size
                 + positions % self.block_size).astype(np.int32)
 
-    def adoption_slots(self, row: int) -> np.ndarray:
-        """:meth:`slots` with the shared prefix redirected to trash: the
-        adoption scatter's index for a row admitted through the radix
-        index. Shared blocks already hold the prefix KV and are
-        write-once, so the positions they cache must scatter their
-        (identical, or resume-zeroed) strip values into the trash block
-        instead."""
-        slots = self.slots(row)
-        shared = self.shared_tokens(row)
-        if shared:
-            positions = np.arange(self.max_seq)
-            slots = np.where(
-                positions < shared,
-                (positions % self.block_size).astype(np.int32), slots)
-        return slots
+    def adoption_blocks(self, row: int, length: int) -> np.ndarray:
+        """``[max_blocks]``: the physical block :func:`adopt_prefill`
+        writes each logical block of a ``length``-token prompt's strip to,
+        and ``blocks`` (out of range, so not written) for every block the
+        prompt does not fill and for the shared prefix, which already holds
+        its KV and is write-once."""
+        ids = np.full(self.max_blocks, self.blocks, np.int32)
+        first = self.shared_tokens(row) // self.block_size
+        last = self.blocks_for(length)
+        ids[first:last] = self.table[row, first:last]
+        return ids
 
     def evict(self, row: int) -> int:
         """Retire ``row``: every owned block drops one reference, and a
@@ -318,26 +312,39 @@ def _is_kv(path) -> bool:
                         jax.tree_util.DictKey('value'))
 
 
+def _is_table(path) -> bool:
+    return path[-1] == jax.tree_util.DictKey('table')
+
+
+def _tables(leaf, tables):
+    """A ``table`` leaf holding the host authority's ``[rows, max_blocks]``
+    map (broadcast over a scanned stack's leading layer dim)."""
+    return jnp.broadcast_to(jnp.asarray(tables, leaf.dtype), leaf.shape)
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
-def adopt_prefill(cache, prefill_cache, slots, row, length):
+def adopt_prefill(cache, prefill_cache, blocks, tables, row, length):
     """Admit a prefilled sequence into ``row`` of the paged cache.
 
     ``prefill_cache`` is the contiguous decode cache a plain (non-paged)
     prefill apply left behind — per-layer KV strips ``[1, max_seq, heads,
-    head_dim]``, flattened here to the pool's ``[max_seq, heads *
-    head_dim]`` rows and scattered in place into the donated pool;
-    ``slots`` is the row's ``[max_seq]`` physical token-slot
-    map (:meth:`PagedKVCache.slots`, trash-padded past the allocation, so
-    pad-bucket junk beyond the prompt scatters into trash or into
-    positions the decode write overwrites before the mask ever exposes
-    them); ``row``/``length`` set the row's cursors to the prompt length.
-    A recurrent layer's per-row state leaves (``state``/``conv``:
-    :func:`tpusystem.train.cursors.is_row_state`) have no slots: the
+    head_dim]``. Each strip, seen as ``[max_blocks, block, lanes]``, is
+    written block by block into the donated pool seen as ``[num_blocks,
+    block, lanes]``: logical block ``j`` to physical block ``blocks[j]``
+    (:meth:`PagedKVCache.adoption_blocks`), whole, in place. An id out of
+    range is not written: the blocks past the prompt (decode writes each
+    of their positions before a row's mask exposes it, so they may hold
+    whatever an evicted row left) and a shared prefix (write-once).
+    ``row``/``length`` set the row's cursors to the prompt length. A
+    recurrent layer's per-row state leaves (``state``/``conv``:
+    :func:`tpusystem.train.cursors.is_row_state`) have no blocks: the
     prefill's row 0 overwrites row ``row`` whole, in this same program.
-    Tables are not touched here — :func:`write_tables` is the one table
-    authority. One compiled program for every admission (prefill strips
-    share one shape across buckets: the cache is allocated ``max_seq``
-    wide regardless of prompt length)."""
+    Every ``table`` leaf takes ``tables``, the host authority's map, as
+    :func:`write_tables` writes it: an admission is this one program. One
+    compiled program for every admission (prefill strips share one shape
+    across buckets: the cache is allocated ``max_seq`` wide regardless of
+    prompt length, and ``blocks`` is ``max_blocks`` wide whatever the
+    prompt fills)."""
     from tpusystem.train.cursors import is_cursor, is_row_state
     source = {jax.tree_util.keystr(path): leaf for path, leaf
               in jax.tree_util.tree_leaves_with_path(prefill_cache)}
@@ -345,13 +352,23 @@ def adopt_prefill(cache, prefill_cache, slots, row, length):
     def fix(path, leaf):
         if _is_kv(path):
             strip = source[jax.tree_util.keystr(path)][0]  # [max_seq, h, d]
-            return leaf.at[slots].set(
-                strip.reshape(strip.shape[0], -1).astype(leaf.dtype))
+            max_seq, lanes = strip.shape[0], leaf.shape[-1]
+            block = max_seq // blocks.shape[0]
+            # the positions moved minor and back: where the TPU keeps a
+            # strip position-minor (heads x head_dim short of whole tiles,
+            # 20 x 64), this is its one relayout pass, not two
+            rows = jnp.moveaxis(strip, 0, -1).reshape(lanes, max_seq).T
+            pool = leaf.reshape(-1, block, lanes).at[blocks].set(
+                rows.reshape(-1, block, lanes).astype(leaf.dtype),
+                mode='drop')
+            return pool.reshape(leaf.shape)
         if is_row_state(path):
             return leaf.at[row].set(
                 source[jax.tree_util.keystr(path)][0].astype(leaf.dtype))
         if is_cursor(path):
             return leaf.at[row].set(jnp.asarray(length, leaf.dtype))
+        if _is_table(path):
+            return _tables(leaf, tables)
         return leaf
     return jax.tree_util.tree_map_with_path(fix, cache)
 
@@ -359,17 +376,11 @@ def adopt_prefill(cache, prefill_cache, slots, row, length):
 @functools.partial(jax.jit, donate_argnums=(0,))
 def write_tables(cache, tables):
     """Replace every layer's ``table`` cache leaf with the host
-    authority's ``[rows, max_blocks]`` map (broadcast over a scanned
-    stack's leading layer dim). Admission maps a row's logical blocks to
-    its fresh allocation; eviction resets them to trash — either way the
-    whole membership change is this table edit plus (for admissions)
-    :func:`adopt_prefill`'s block writes."""
-    def fix(path, leaf):
-        if path[-1] == jax.tree_util.DictKey('table'):
-            return jnp.broadcast_to(jnp.asarray(tables, leaf.dtype),
-                                    leaf.shape)
-        return leaf
-    return jax.tree_util.tree_map_with_path(fix, cache)
+    authority's ``[rows, max_blocks]`` map: what an eviction writes, the
+    table half of :func:`adopt_prefill`."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: _tables(leaf, tables) if _is_table(path)
+        else leaf, cache)
 
 
 def pool_shardings(cache, mesh, kv_heads: int):
