@@ -96,15 +96,20 @@ def _cells_as_written(request, monkeypatch):
 
 # ``test_chipbench_compile_spans`` asserts that its nine metrics are the
 # last per-layer entries and that they list the three serving cells that
-# existed when it was written. It is handed ``BENCHMARK.json`` as it was
-# then, the way ``tests/chipbench_tests/conftest.py`` hands the expert
-# cells' module its own: each list cut behind the module's last entry, and
-# the cells added since taken out of every ``workloads`` list.
+# existed when it was written; ``test_chipbench_ouro`` that its
+# configuration, cell and metric are the last entries. Each is handed
+# ``BENCHMARK.json`` as it was then, the way
+# ``tests/chipbench_tests/conftest.py`` hands the expert cells' module its
+# own: each list cut behind the module's last entry, and the cells added
+# since taken out of every ``workloads`` list.
 BENCHMARK_WRITTEN_AGAINST = {
     'test_chipbench_compile_spans': {
         'configs': 'nemotron-3-nano-30b-a3b',
         'workloads': 'serve-nemotron3-closed96',
-        'per_layer': 'tick_gap_ms.tick'}}
+        'per_layer': 'tick_gap_ms.tick'},
+    'test_chipbench_ouro': {
+        'configs': 'ouro-2.6b', 'workloads': 'serve-ouro-closed8',
+        'per_layer': 'loop_weight_roofline'}}
 
 
 @pytest.fixture(autouse=True, scope='module')

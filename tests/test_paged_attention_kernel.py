@@ -6,6 +6,8 @@ of the step around it."""
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -615,5 +617,53 @@ def test_the_latent_kernel_compiles_for_a_v5e_at_serving_widths(one_chip):
     assert operands.count(f'bf16[{slots},640]') == 1, operands
     moved = [line for line in compiled.splitlines()
              if f'bf16[{slots},640]' in line.split(' = ')[-1].split('(')[0]
+             and 'parameter(' not in line]
+    assert not moved, moved
+
+
+# ----------------------------- a learned sparse attention's two walks
+
+@pytest.mark.parametrize('kernel', ['paged_index_scores',
+                                    'paged_latent_attention'])
+def test_the_sparse_walks_compile_for_a_v5e_at_serving_widths(one_chip,
+                                                              kernel):
+    """dots3-note-prev's share as the cell serves it: 96 rows, block 16,
+    704 table columns (11 264 positions); the indexer's 64 heads over keys
+    of 128, the full layers' 128 heads over rows of 576 on 640 lanes with
+    the selection as a mask. Mosaic takes each walk, and the pool goes in
+    once and as stored."""
+    from tpusystem.ops.pallas.index_scores import paged_index_scores
+    from tpusystem.ops.pallas.latent_attention import (
+        chunk_span, paged_latent_attention)
+    rows, block, max_blocks = 96, 16, 704
+    slots = (rows * max_blocks + 1) * block
+    span = chunk_span(block, max_blocks)
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    table = shaped((rows, max_blocks), jnp.int32)
+    cursor = shaped((rows,), jnp.int32)
+    if kernel == 'paged_index_scores':
+        lanes = 128
+        lowered = jax.jit(functools.partial(
+            paged_index_scores, block=block, interpret=False)).lower(
+            shaped((rows, 64, lanes), jnp.bfloat16),
+            shaped((rows, 64), jnp.float32),
+            shaped((slots, lanes), jnp.bfloat16), table, cursor)
+    else:
+        lanes = 640
+        lowered = jax.jit(lambda query, pool, table, cursor, chosen: (
+            paged_latent_attention(query, pool, table, cursor, rank=512,
+                                   width=576, block=block, scale=0.0722,
+                                   interpret=False, selected=chosen))).lower(
+            shaped((rows, 128, 576), jnp.bfloat16),
+            shaped((slots, lanes), jnp.bfloat16), table, cursor,
+            shaped((rows, max_blocks * block // span, 1, span), jnp.int32))
+    compiled = lowered.compile().as_text()
+    calls = [line for line in compiled.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert f'%{kernel}' in calls[0].split(' = ')[0]
+    moved = [line for line in compiled.splitlines()
+             if f'bf16[{slots},{lanes}]' in line.split(' = ')[-1].split('(')[0]
              and 'parameter(' not in line]
     assert not moved, moved

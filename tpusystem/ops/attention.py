@@ -648,3 +648,451 @@ def dot_product_attention(query, key, value, *, causal: bool = True,
         keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout, weights.shape)
         weights = jnp.where(keep, weights / (1.0 - dropout), 0.0)
     return jnp.einsum('bhqk,bkhd->bqhd', weights.astype(input_dtype), value)
+
+
+# ------------------------------------------------ learned sparse attention
+
+def _ordered_bits(values):
+    """float32 -> uint32 in the same order (negative values' bits flipped
+    whole, the others' sign bit set)."""
+    bits = jax.lax.bitcast_convert_type(values.astype(jnp.float32),
+                                        jnp.uint32)
+    return jnp.where(bits >= jnp.uint32(1 << 31), ~bits,
+                     bits | jnp.uint32(1 << 31))
+
+
+def select_top(scores, valid, k: int):
+    """``[..., positions]`` bool: the ``min(k, valid count)`` largest of
+    ``scores`` among the ``valid`` positions, exactly (no approximate
+    top-k): the ``k``-th largest value is found by a binary search over the
+    scores' ordered bits, 32 passes of a compare and a count, and every
+    valid position at or above it is kept (a tie at it keeps both)."""
+    keys = jnp.where(valid, _ordered_bits(scores), jnp.uint32(0))
+    need = jnp.broadcast_to(
+        jnp.minimum(jnp.sum(valid, axis=-1, keepdims=True), k),
+        keys.shape[:-1] + (1,))
+
+    def narrow(bit, floor):
+        trial = floor | (jnp.uint32(1) << (31 - bit).astype(jnp.uint32))
+        enough = jnp.sum(keys >= trial, axis=-1, keepdims=True) >= need
+        return jnp.where(enough, trial, floor)
+
+    floor = jax.lax.fori_loop(0, 32, narrow,
+                              jnp.zeros(need.shape, jnp.uint32))
+    return valid & (keys >= floor)
+
+
+def packed_selection(chosen, positions: int):
+    """``[..., n]`` bool -> ``[..., positions / 32]`` int32 words: position
+    ``p`` at bit ``p % 32`` of word ``p // 32``, positions past ``n``
+    clear. What a selection is handed out as (the ``routing`` collection's
+    ``selected``, beside the experts' ``chosen``)."""
+    pad = [(0, 0)] * (chosen.ndim - 1) + [(0, positions - chosen.shape[-1])]
+    bits = jnp.pad(chosen, pad).reshape(
+        *chosen.shape[:-1], positions // 32, 32).astype(jnp.uint32)
+    words = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                    dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def index_scores(query, weights, keys):
+    """``I(t, s) = sum_j w_j relu(q_j(t) · k_s)`` in float32: ``query
+    [batch, len, heads, dim]``, ``weights [batch, len, heads]``, ``keys
+    [batch, positions, dim]`` -> ``[batch, len, positions]``."""
+    dots = jnp.einsum('bljd,bsd->bljs', query, keys,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum('blj,bljs->bls', weights.astype(jnp.float32),
+                      jnp.maximum(dots, 0.0))
+
+
+def row_cache(module, name: str, lanes: int, dtype, batch: int, *,
+              max_seq: int, pages: tuple | None):
+    """Declare one paged (or contiguous) per-position cache leaf ``name``
+    of ``module``: ``[num_blocks * block, lanes]`` behind the table, else
+    ``[batch, max_seq, lanes]``."""
+    shape = ((pages[0] * pages[1], lanes) if pages is not None
+             else (batch, max_seq, lanes))
+    return module.variable('cache', name, jnp.zeros, shape, dtype)
+
+
+def write_positions(cache, rows, positions, table, *, pages, per_row: bool):
+    """Write ``rows [batch, len, lanes]`` at ``positions [batch, len]`` of
+    a :func:`row_cache` leaf (``table`` the per-row block table when
+    paged): what :func:`latent_attention` does under ``kv_write``."""
+    batch, length, lanes = rows.shape
+    rows = rows.astype(cache.value.dtype)
+    if pages is not None:
+        block, max_blocks = pages[1], table.shape[1]
+        logical = jnp.minimum(positions // block, max_blocks - 1)
+        physical = jnp.take_along_axis(table, logical, axis=1)
+        slots = (physical * block + positions % block).reshape(-1)
+        cache.value = cache.value.at[slots].set(rows.reshape(-1, lanes))
+    elif per_row:
+        cache.value = cache.value.at[
+            jnp.arange(batch)[:, None], positions].set(rows)
+    else:
+        cache.value = jax.lax.dynamic_update_slice(
+            cache.value, rows, (0, positions[0, 0], 0))
+    return cache.value
+
+
+def whole_rows(cache, table, pages, batch: int):
+    """Every position of every row of a :func:`row_cache` leaf as ``[batch,
+    max_seq, lanes]`` (the XLA read: a paged pool gathered whole blocks at
+    a time through the table)."""
+    if pages is None:
+        return cache
+    num_blocks, block = pages
+    lanes = cache.shape[-1]
+    return jnp.take(cache.reshape(num_blocks, block, lanes), table,
+                    axis=0).reshape(batch, -1, lanes)
+
+
+# keys one softmax row may span: on the TPU a longer float32 row of
+# [128 heads, queries, keys] scores compiles to a softmax fusion that
+# streams it a few lanes at a time (keys past ~4900 at 128 queries)
+_SOFTMAX_KEYS = 4096
+
+
+def _chunked_softmax_attention(query, key, value, mask, scale: float):
+    """:func:`dot_product_attention` of ``query [batch, block, heads, d]``
+    under ``mask [batch, 1, block, keys]``, its softmax rows cut into
+    chunks of at most :data:`_SOFTMAX_KEYS` keys: each chunk's scores,
+    largest score, exponential sum and unnormalised mix, joined exactly
+    in float32 (the chunks' exponentials rescaled to the largest score of
+    all). A chunk every key of which a query masks weighs nothing for it,
+    as long as some other chunk holds a key it attends."""
+    if key.shape[1] <= _SOFTMAX_KEYS:
+        return dot_product_attention(query, key, value, causal=False,
+                                     mask=mask, scale=scale)
+    parts = []
+    for lo in range(0, key.shape[1], _SOFTMAX_KEYS):
+        hi = lo + _SOFTMAX_KEYS
+        scores = jnp.einsum('bqhd,bkhd->bhqk', query, key[:, lo:hi],
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(mask[..., lo:hi], scores, NEG_INF)
+        top = jnp.max(scores, axis=-1, keepdims=True)
+        weights = jnp.exp(scores - top)
+        mixed = jnp.einsum('bhqk,bkhd->bhqd', weights.astype(query.dtype),
+                           value[:, lo:hi], preferred_element_type=jnp.float32)
+        parts.append((top, jnp.sum(weights, axis=-1, keepdims=True), mixed))
+    top = parts[0][0]
+    for part in parts[1:]:
+        top = jnp.maximum(top, part[0])
+    scaled = [jnp.exp(part[0] - top) for part in parts]
+    total = sum(s * part[1] for s, part in zip(scaled, parts))
+    mixed = sum(s * part[2] for s, part in zip(scaled, parts))
+    return jnp.swapaxes(mixed / total, 1, 2).astype(query.dtype)
+
+
+def _blocked(query, key, value, scale: float, mask_of, reach=None):
+    """Attention of ``query [batch, len, heads, d]`` over ``key``/``value``
+    expanded per head, queries in blocks (as
+    :func:`expanded_latent_attention`): a block ``[start, stop)`` attends
+    keys ``[lo, stop)``, ``lo = max(0, start - reach)`` (``reach=None``: 0),
+    under ``mask_of(start, stop, lo)`` (``[batch, block, stop - lo]``),
+    its softmax rows in chunks (:func:`_chunked_softmax_attention`)."""
+    batch, length, heads, _ = query.shape
+    block = length
+    while block > 128 and batch * heads * block \
+            * min(length, _SOFTMAX_KEYS) * 4 > _EXPANDED_SCORE_BYTES:
+        block //= 2
+    if length % block:
+        block = length
+    out = []
+    for start in range(0, length, block):
+        stop = start + block
+        lo = 0 if reach is None else max(0, start - reach)
+        out.append(_chunked_softmax_attention(
+            query[:, start:stop], key[:, lo:stop], value[:, lo:stop],
+            mask_of(start, stop, lo)[:, None], scale))
+    return jnp.concatenate(out, axis=1)
+
+
+def _expanded(latent, w_key, w_value, key_rope):
+    """Keys and values per head from the latent rows (prefill)."""
+    batch, length, heads = latent.shape[0], latent.shape[1], w_key.shape[1]
+    content = latent[..., :w_key.shape[0]]
+    key = jnp.concatenate(
+        [jnp.einsum('blc,chd->blhd', content, w_key),
+         jnp.broadcast_to(key_rope[:, :, None, :],
+                          (batch, length, heads, key_rope.shape[-1]))],
+        axis=-1)
+    return key, jnp.einsum('blc,chd->blhd', content, w_value)
+
+
+def selected_latent_attention(query, latent, w_key, w_value, index_query,
+                              index_weights, index_keys, *, scale: float,
+                              topk: int, keep: int | None = None):
+    """The prefill (and uncached) path of :func:`sparse_latent_attention`:
+    keys and values expanded per head, queries in blocks, each block's index
+    scores and selection made beside its attention. ``keep`` (a number of
+    positions): also return every query's selection,
+    :func:`packed_selection` ``[batch * len, keep / 32]``."""
+    rank = w_key.shape[0]
+    key, value = _expanded(latent, w_key, w_value, latent[..., rank:])
+    kept = []
+
+    def selected_over(start, stop, lo):
+        causal = (jnp.arange(start, stop)[:, None]
+                  >= jnp.arange(lo, stop)[None, :])
+        with jax.named_scope('indexer'):
+            scores = index_scores(index_query[:, start:stop],
+                                  index_weights[:, start:stop],
+                                  index_keys[:, lo:stop])
+            chosen = select_top(scores, causal[None], topk)
+            if keep:
+                kept.append(packed_selection(chosen, keep))
+            return chosen
+
+    out = _blocked(query, key, value, scale, selected_over)
+    if not keep:
+        return out
+    words = jnp.concatenate(kept, axis=1)
+    return out, words.reshape(-1, words.shape[-1])
+
+
+def banded_latent_attention(query, latent, w_key, w_value, *, scale: float,
+                            window: int):
+    """The prefill (and uncached) path of :func:`window_latent_attention`:
+    keys and values expanded per head, queries in blocks over the keys of
+    their own window."""
+    rank = w_key.shape[0]
+    key, value = _expanded(latent, w_key, w_value, latent[..., rank:])
+
+    def banded(start, stop, lo):
+        gap = (jnp.arange(start, stop)[:, None]
+               - jnp.arange(lo, stop)[None, :])
+        return ((gap >= 0) & (gap < window))[None]
+
+    return _blocked(query, key, value, scale, banded, reach=window - 1)
+
+
+def _latent_cache(module, latent, batch: int, *, max_seq: int,
+                  pages: tuple | None):
+    """The latent ``key`` leaf, the ``table`` and the ``index`` cursor of a
+    layer, as :func:`latent_attention` declares them; ``(cache, table
+    value or None, cursor, prefill)``."""
+    if pages is not None and max_seq % pages[1]:
+        raise ValueError(f'max_seq ({max_seq}) must be a multiple of the '
+                         f'page block_size ({pages[1]})')
+    prefill = pages is None and not module.has_variable('cache', 'index')
+    lanes = -(-latent.shape[-1] // 128) * 128
+    cache = row_cache(module, 'key', lanes, latent.dtype, batch,
+                      max_seq=max_seq, pages=pages)
+    table = None
+    if pages is not None:
+        table = module.variable('cache', 'table', jnp.zeros,
+                                (batch, max_seq // pages[1]),
+                                jnp.int32).value
+    index = module.variable('cache', 'index',
+                            lambda: jnp.zeros((batch,), jnp.int32))
+    return cache, table, index, prefill
+
+
+def _padded(rows, lanes: int):
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, lanes - rows.shape[-1])))
+
+
+def sparse_latent_attention(module, q_content, q_rope, latent, w_key,
+                            w_value, index_query, index_weights, index_keys,
+                            index_pool, *, scale: float, topk: int,
+                            max_seq: int, per_row: bool = False,
+                            pages: tuple | None = None):
+    """Latent attention over a **learned selection** (DeepSeek-V3.2's sparse
+    attention): each query attends only the ``min(topk, t + 1)`` positions
+    ``s <= t`` of largest index score ``I(t, s) = sum_j w_j relu(qI_j(t) ·
+    kI_s)``, taken exactly (:func:`select_top`).
+
+    Arguments as :func:`latent_attention`'s, plus the indexer's rotated
+    queries ``index_query [batch, len, heads_I, dim_I]``, their weights
+    ``index_weights [batch, len, heads_I]`` (the softmax scale folded in),
+    this call's keys ``index_keys [batch, len, dim_I]`` and, in decode, the
+    indexer's key leaf after this call's write ``index_pool`` (a
+    :func:`row_cache` leaf of the indexer module, written through the
+    layer's ``table``; unread at prefill). The layer's own cache leaves are
+    :func:`latent_attention`'s (``key``, ``table``, ``index``).
+
+    * **prefill**: keys and values expanded per head, queries in blocks,
+      each block's index scores ``[heads_I, block, keys]`` and selection
+      made beside its attention (never the whole square);
+    * **decode**: every held position of a row is scored, the top ``topk``
+      taken, and the absorbed query attends those: on the TPU one new
+      token a row reads the pool through two walks of the row's own
+      blocks (:func:`tpusystem.ops.pallas.index_scores.paged_index_scores`,
+      then :func:`~tpusystem.ops.pallas.latent_attention.
+      paged_latent_attention` with the selection as a mask: every held
+      row is fetched, the unselected masked); elsewhere both reads gather
+      whole rows.
+
+    Every query's selection is sown into the ``routing`` collection as
+    ``selected`` (:func:`packed_selection`, ``[batch * len, max_seq / 32]``)
+    where the caller makes it mutable: what a replay of the layer needs
+    beside the expert layers' ``chosen`` (``Engine(routing_sink=)``).
+
+    Scopes: ``kv_write``; ``indexer`` (scoring and selection);
+    ``kv_read/sparse_read`` (the attend over the selection)."""
+    batch, length, width = latent.shape
+    rank = w_key.shape[0]
+    cache, table, index, prefill = _latent_cache(
+        module, latent, batch, max_seq=max_seq, pages=pages)
+    query = jnp.concatenate([q_content, q_rope], axis=-1)
+
+    def handed(words):
+        # every query's selection, for a caller that replays the layer
+        # (the engine's routing_sink), beside the expert layers' choices
+        module.sow('routing', 'selected', words,
+                   init_fn=lambda: jnp.zeros(words.shape, jnp.int32),
+                   reduce_fn=lambda _, new: new)
+
+    def expanded():
+        out, words = selected_latent_attention(
+            query, latent, w_key, w_value, index_query, index_weights,
+            index_keys, scale=scale, topk=topk, keep=max_seq)
+        handed(words)
+        return out
+
+    if module.is_initializing():
+        return expanded()
+    cursor = index.value
+    positions = cursor[:, None] + jnp.arange(length)[None, :]
+    lanes = cache.value.shape[-1]
+    with jax.named_scope('kv_write'):
+        write_positions(cache, _padded(latent, lanes), positions, table,
+                        pages=pages, per_row=per_row)
+        index.value = cursor + length
+    if prefill:
+        with jax.named_scope('kv_read'), jax.named_scope('sparse_read'):
+            return expanded()
+
+    with jax.named_scope('mla_proj'):
+        absorbed = jnp.concatenate(
+            [jnp.einsum('blhd,chd->blhc', q_content, w_key), q_rope], axis=-1)
+    heads = q_content.shape[2]
+    if pages is not None and length == 1 and on_tpu():
+        from tpusystem.ops.pallas.index_scores import (index_plan,
+                                                       paged_index_scores)
+        from tpusystem.ops.pallas.latent_attention import (
+            latent_plan, paged_latent_attention)
+        block, max_blocks = pages[1], max_seq // pages[1]
+        span = index_plan(index_query.shape[2], index_query.shape[3], block,
+                          max_blocks, index_pool.dtype, False)
+        if span is not None and latent_plan(
+                heads, rank, block, max_blocks, cache.value.dtype, False,
+                lanes) is not None:
+            with jax.named_scope('indexer'):
+                scores = paged_index_scores(
+                    index_query[:, 0], index_weights[:, 0], index_pool,
+                    table, cursor, block=block)
+                flat = scores.reshape(batch, max_seq)
+                chosen = select_top(
+                    flat, jnp.arange(max_seq)[None] <= cursor[:, None], topk)
+                handed(packed_selection(chosen, max_seq))
+                chosen = chosen.astype(jnp.int32).reshape(scores.shape)
+            with jax.named_scope('kv_read'), jax.named_scope('sparse_read'):
+                mixed = paged_latent_attention(
+                    absorbed[:, 0], cache.value, table, cursor, rank=rank,
+                    width=width, block=block, scale=scale, selected=chosen)
+            with jax.named_scope('mla_proj'):
+                return jnp.einsum('blhc,chd->blhd', mixed[:, None], w_value)
+
+    with jax.named_scope('indexer'):
+        keys = whole_rows(index_pool, table, pages, batch)
+        held = jnp.arange(keys.shape[1])[None, None] <= positions[..., None]
+        chosen = select_top(index_scores(index_query, index_weights, keys),
+                            held, topk)                      # [B, L, S]
+        handed(packed_selection(chosen, max_seq).reshape(batch * length, -1))
+    with jax.named_scope('kv_read'), jax.named_scope('sparse_read'):
+        rows = whole_rows(cache.value, table, pages, batch)[..., :width]
+        scores = jnp.einsum('blhc,bwc->bhlw', absorbed, rows,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(chosen[:, None], scores, NEG_INF)
+        weights = jax.nn.softmax(scores, axis=-1)
+        mixed = jnp.einsum('bhlw,bwc->blhc', weights.astype(rows.dtype),
+                           rows[..., :rank])
+    with jax.named_scope('mla_proj'):
+        return jnp.einsum('blhc,chd->blhd', mixed, w_value)
+
+
+def window_slots(window: int) -> int:
+    """Slots a row's window cache keeps: the window rounded up to whole
+    16-position tiles, with at least one slot to spare (513 -> 528)."""
+    return (window // 16 + 1) * 16
+
+
+def window_latent_attention(module, q_content, q_rope, latent, w_key,
+                            w_value, *, scale: float, window: int,
+                            max_seq: int, per_row: bool = False,
+                            length=None):
+    """Latent attention over a **sliding window**: position ``t`` attends
+    ``t - window < s <= t``. The cache is a **per-row window cache**, leaf
+    ``window`` ``[batch, slots, lanes]`` (:func:`window_slots`): position
+    ``p`` lies at slot ``p mod slots``, so what a row keeps does not grow
+    with its context, and there is no table. A row's slot holds the latest
+    position written there; a read masks by the position each slot holds.
+    The cursor is the ``index`` leaf, as elsewhere.
+
+    * **prefill** (creates the cache): keys and values expanded per head,
+      queries in blocks over the keys of their own window; the last
+      ``slots`` positions of the real prompt (``length``, a traced count of
+      a right-padded one; ``None``: all of it) are written to their slots;
+    * **decode**: the new rows go to their slots and the absorbed query
+      attends the row's whole window cache under the mask (at most
+      ``slots - window + 1`` new tokens a call).
+
+    Scopes: ``kv_write``, ``kv_read/window_read``."""
+    batch, span, width = latent.shape
+    rank = w_key.shape[0]
+    slots = window_slots(window)
+    lanes = -(-width // 128) * 128
+    prefill = not module.has_variable('cache', 'index')
+    cache = module.variable('cache', 'window', jnp.zeros,
+                            (batch, slots, lanes), latent.dtype)
+    index = module.variable('cache', 'index',
+                            lambda: jnp.zeros((batch,), jnp.int32))
+    query = jnp.concatenate([q_content, q_rope], axis=-1)
+
+    def expanded():
+        return banded_latent_attention(query, latent, w_key, w_value,
+                                       scale=scale, window=window)
+
+    if module.is_initializing():
+        return expanded()
+    rows = _padded(latent.astype(cache.value.dtype), lanes)
+    cursor = index.value
+    if prefill:
+        real = span if length is None else length
+        with jax.named_scope('kv_write'):
+            # slot s keeps the latest real position p with p = s (mod slots)
+            last = real - 1
+            held = last - (last - jnp.arange(slots)) % slots
+            kept = jnp.take(rows, jnp.maximum(held, 0), axis=1)
+            cache.value = jnp.where((held >= 0)[None, :, None], kept, 0)
+            index.value = cursor + span
+        with jax.named_scope('kv_read'), jax.named_scope('window_read'):
+            return expanded()
+    if span > slots - window + 1:
+        raise ValueError(f'{span} new tokens overrun a window cache of '
+                         f'{slots} slots for a window of {window}')
+    positions = cursor[:, None] + jnp.arange(span)[None, :]     # [B, L]
+    with jax.named_scope('kv_write'):
+        cache.value = cache.value.at[
+            jnp.arange(batch)[:, None], positions % slots].set(rows)
+        index.value = cursor + span
+    with jax.named_scope('mla_proj'):
+        absorbed = jnp.concatenate(
+            [jnp.einsum('blhd,chd->blhc', q_content, w_key), q_rope], axis=-1)
+    with jax.named_scope('kv_read'), jax.named_scope('window_read'):
+        newest = positions[:, -1:]                               # [B, 1]
+        held = newest - (newest - jnp.arange(slots)[None]) % slots
+        gap = positions[:, :, None] - held[:, None, :]          # [B, L, W]
+        mask = (held[:, None, :] >= 0) & (gap >= 0) & (gap < window)
+        stored = cache.value[..., :width]
+        scores = jnp.einsum('blhc,bwc->bhlw', absorbed, stored,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(mask[:, None], scores, NEG_INF)
+        weights = jax.nn.softmax(scores, axis=-1)
+        mixed = jnp.einsum('bhlw,bwc->blhc', weights.astype(stored.dtype),
+                           stored[..., :rank])
+    with jax.named_scope('mla_proj'):
+        return jnp.einsum('blhc,chd->blhd', mixed, w_value)

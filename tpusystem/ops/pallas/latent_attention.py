@@ -83,7 +83,7 @@ def latent_plan(heads: int, rank: int, block: int, max_blocks: int, dtype,
 
 def _kernel(table_ref, cursor_ref, q_ref, pool_hbm, out_ref, window, acc,
             top, denom, slot_ref, sems, *, rank: int, width: int,
-            block: int, chunk: int, max_seq: int, scale: float):
+            block: int, chunk: int, max_seq: int, scale: float, chosen=None):
     row, rows = pl.program_id(0), pl.num_programs(0)
     span = chunk * block                       # positions per chunk
 
@@ -142,7 +142,10 @@ def _kernel(table_ref, cursor_ref, q_ref, pool_hbm, out_ref, window, acc,
                 preferred_element_type=jnp.float32)) * scale
         position = index * span + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 1)
-        scores = jnp.where(position < depth, scores, NEG_INF)
+        keep = position < depth
+        if chosen is not None:      # the positions a selection kept
+            keep = keep & (chosen[index] > 0)
+        scores = jnp.where(keep, scores, NEG_INF)
         before = top[...]
         after = jnp.maximum(before, jnp.max(scores, axis=-1, keepdims=True))
         shrink = jnp.exp(before - after)
@@ -162,7 +165,7 @@ def _kernel(table_ref, cursor_ref, q_ref, pool_hbm, out_ref, window, acc,
 
 def paged_latent_attention(query, pool, table, cursor, *, rank: int,
                            width: int, block: int, scale: float,
-                           interpret: bool | None = None):
+                           interpret: bool | None = None, selected=None):
     """One new token per row attended over the paged latent pool in place.
 
     Args:
@@ -178,6 +181,13 @@ def paged_latent_attention(query, pool, table, cursor, *, rank: int,
             attends positions ``0 … cursor``.
         rank: width of the content part; ``width``: ``rank + rope``;
         ``block``: positions per block; ``scale``: the softmax scale.
+
+    ``selected`` (optional): ``[rows, chunks, 1, chunk positions]`` int32,
+    a row's positions laid out chunk by chunk (:func:`chunk_span` wide),
+    non-zero where the row attends that position (a sparse selection,
+    :func:`tpusystem.ops.attention.sparse_latent_attention`): the walk is
+    the same, the rest of each chunk is masked. ``max_seq`` must then be
+    whole chunks.
 
     Returns ``softmax(Q · rows^T · scale) · rows[:, :rank]``, ``[rows,
     heads, rank]`` in ``query.dtype`` (the caller carries it through the
@@ -200,6 +210,15 @@ def paged_latent_attention(query, pool, table, cursor, *, rank: int,
                                          lambda row, *_: (row, 0, 0))
     kernel = functools.partial(_kernel, rank=rank, width=width, block=block,
                                chunk=chunk, max_seq=max_seq, scale=scale)
+    chosen_specs, chosen = [], ()
+    if selected is not None:
+        if selected.shape != (rows, max_seq // span, 1, span):
+            raise ValueError(f'selected {selected.shape} is not {rows} rows '
+                             f'of {max_seq} positions in chunks of {span}')
+        kernel = functools.partial(_chosen_kernel, kernel)
+        chosen_specs = [pl.BlockSpec((None,) + selected.shape[1:],
+                                     lambda row, *_: (row, 0, 0, 0))]
+        chosen = (selected,)
     # a row sits anywhere between an empty table and a full one
     positions = rows * max_seq // 2
     itemsize = jnp.dtype(pool.dtype).itemsize
@@ -208,7 +227,8 @@ def paged_latent_attention(query, pool, table, cursor, *, rank: int,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(rows,),
-            in_specs=[per_row(width), pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[per_row(width), *chosen_specs,
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=per_row(rank),
             scratch_shapes=[
                 pltpu.VMEM((2, span, lanes), pool.dtype),
@@ -228,4 +248,16 @@ def paged_latent_attention(query, pool, table, cursor, *, rank: int,
             transcendentals=positions * heads),
         interpret=interpret,
         name='paged_latent_attention',
-    )(table, cursor, query, streamed_from_hbm(pool, interpret))
+    )(table, cursor, query, *chosen, streamed_from_hbm(pool, interpret))
+
+
+def chunk_span(block: int, max_blocks: int) -> int:
+    """Positions one chunk of the walk covers (:func:`latent_plan`'s
+    columns times ``block``), whatever the platform."""
+    return max(1, min(max_blocks, CHUNK_POSITIONS // block)) * block
+
+
+def _chosen_kernel(kernel, table_ref, cursor_ref, q_ref, chosen_ref, *refs):
+    """The walk with a selection: ``chosen_ref`` is the row's
+    ``[chunks, 1, span]`` mask, handed to :func:`_kernel` by name."""
+    kernel(table_ref, cursor_ref, q_ref, *refs, chosen=chosen_ref)

@@ -283,7 +283,8 @@ def _build_prefill(decoder, bucket: int, routed: bool = False):
         first = select_tokens(logits[0, length - 1], seed, position, temp,
                               topk, topp, mask)
         if routed:
-            return first, state['cache'], _routing_of(state)
+            return (first, state['cache'], _routing_of(state),
+                    *_selections_of(state))
         return first, state['cache']
 
     return run
@@ -305,14 +306,35 @@ def _in_layer_order(tree) -> dict:
 
 def _compact(chosen: np.ndarray) -> np.ndarray:
     """Routing as a ``routing_sink`` gets it: a byte an expert where the
-    router is narrow enough."""
-    return chosen.astype(np.uint8) if chosen.max(initial=0) < 256 else chosen
+    router is narrow enough (and nothing else in the array is wider)."""
+    narrow = chosen.min(initial=0) >= 0 and chosen.max(initial=0) < 256
+    return chosen.astype(np.uint8) if narrow else chosen
 
 
 def _routing_of(state) -> jax.Array:
     """``[tokens, expert layers, k]`` from the ``routing`` collection
     (``ops.moe.GatedExperts``' ``chosen``, ``[tokens, k]`` a layer)."""
     return jnp.stack(_in_layer_order(state['routing'])['chosen'], axis=1)
+
+
+def _selections_of(state) -> tuple:
+    """``([tokens, sparse layers * words],)``: what sparse attention layers
+    sow into the ``routing`` collection (``selected``, each position's
+    selection as packed words, ``[tokens, words]`` a layer), side by side;
+    ``()`` where no layer sows one."""
+    found = _in_layer_order(state['routing']).get('selected')
+    return (jnp.concatenate(found, axis=1),) if found else ()
+
+
+def _prompt_routing(routing: list, size: int, selections: bool):
+    """The prompt's routing from the prefill program's outputs, as a
+    ``routing_sink`` gets it: the experts, and where ``selections`` the
+    sparse attention layers' selections behind them, flattened."""
+    experts = np.asarray(routing[0])[:size]
+    if not selections:
+        return _compact(experts)
+    return np.concatenate([experts.reshape(size, -1),
+                           np.asarray(routing[1])[:size]], axis=1)
 
 
 @functools.cache
@@ -537,6 +559,16 @@ class Engine:
             compose with ``share_prefix`` (a shared prefix went through
             the experts in another request's life), speculative rows, a
             mesh, or admission of a prefill made elsewhere.
+        routing_selections: with a ``routing_sink`` over a module whose
+            sparse attention layers sow their selections
+            (``ops.attention.sparse_latent_attention``), hand those out
+            too: ``routing [positions, expert layers * k + sparse layers *
+            max_seq / 32]`` int32, the choices flattened, then each
+            layer's selection as packed words. The programs make the
+            selections either way and are the same programs; only this
+            reads them off the device (kilobytes a row every tick,
+            megabytes a prompt), so a caller that replays a few requests
+            asks for them there and not in the traffic it times.
 
     The decode step traces exactly once per engine (``trace_count`` is
     the witness); admissions and evictions are host-side table edits
@@ -551,7 +583,8 @@ class Engine:
                  share_prefix: bool = False, draft_module=None,
                  draft_params=None, speculate: int = 4,
                  tree_fanout: int = 1, mesh=None, schedule=None,
-                 routing_sink=None, bucket_floor: int = 0) -> None:
+                 routing_sink=None, routing_selections: bool = False,
+                 bucket_floor: int = 0) -> None:
         reason = engine_unsupported_reason(module)
         if reason is not None:
             raise ValueError(f'the serving engine cannot run this module: '
@@ -566,6 +599,9 @@ class Engine:
         self._spec = draft_module is not None
         self.mesh, self.tp_plan = self._resolve_mesh(mesh)
         reason = recurrent_reason(
+            module, share_prefix=share_prefix, draft_module=draft_module,
+            sharded=self.tp_plan.path == 'gspmd')
+        reason = reason or window_reason(
             module, share_prefix=share_prefix, draft_module=draft_module,
             sharded=self.tp_plan.path == 'gspmd')
         if reason is not None:
@@ -621,17 +657,20 @@ class Engine:
         # scheduler marks it once in its tracer: `cache_bytes`)
         self.cache_bytes = {'kv': 0, 'state': 0}
         for path, leaf in jax.tree_util.tree_leaves_with_path(self._cache):
-            kind = 'kv' if _is_kv(path) else 'state' \
-                if is_row_state(path) else None
+            kind = _cache_kind(path)
             if kind is not None:
-                self.cache_bytes[kind] += leaf.size * leaf.dtype.itemsize
+                self.cache_bytes[kind] = (self.cache_bytes.get(kind, 0)
+                                          + leaf.size * leaf.dtype.itemsize)
         self.paged_read = self._resolve_paged_read()
+        self.sparse_attention = sparse_attention_of(self._decoder,
+                                                    self._cache)
         # what the module's layers sow for the host beside the tokens: the
         # names of their expert_load counters, and, for a routing_sink, the
         # [expert layers, k] of the experts a token was given
         self._load_names = tuple(sorted(_in_layer_order(
             collections.get('expert_load', {}))))
         self._routing_sink, self._routed = routing_sink, None
+        self._selections = False
         if routing_sink is not None:
             chosen = _in_layer_order(
                 collections.get('routing', {})).get('chosen')
@@ -643,6 +682,12 @@ class Engine:
                     'their choices (ops.moe.GatedExperts), on the plain '
                     'flax step: no share_prefix, draft or mesh')
             self._routed = (len(chosen), chosen[0].shape[-1])
+            self._selections = routing_selections
+        if routing_selections and not (self._routed and _in_layer_order(
+                collections.get('routing', {})).get('selected')):
+            raise ValueError(
+                'routing_selections needs a routing_sink and a module whose '
+                'sparse attention layers sow their selections')
         if self.tp_plan.path == 'gspmd':
             self._cache = jax.device_put(
                 self._cache, pool_shardings(
@@ -792,6 +837,8 @@ class Engine:
                     behind.append(_routing_of(updated).reshape(-1))
                 if behind:
                     out += (jnp.concatenate([token, *behind]),)
+                if self._routed:             # made either way, read if asked
+                    out += _selections_of(updated)
                 return out
 
         self._step = jax.jit(step_fn, donate_argnums=(1,))
@@ -1350,7 +1397,8 @@ class Engine:
         self.timings['prefill'] += time.perf_counter() - started
         return self._register(
             rep, rows, prompt, first, max_new, stop_token, tag, sampling,
-            emitted, routing=_compact(np.asarray(routing[0])[:prompt.size])
+            emitted, routing=_prompt_routing(routing, prompt.size,
+                                             self._selections)
             if routing else None)
 
     # ------------------------------------------------- disaggregated prefill
@@ -1387,10 +1435,11 @@ class Engine:
                 self._prefiller, bucket, padded, prompt.size, ops)
             first = int(first)
         self.timings['prefill'] += time.perf_counter() - started
+        from tpusystem.train.cursors import is_by_row
         kv = {jax.tree_util.keystr(path): np.asarray(leaf)
               for path, leaf
               in jax.tree_util.tree_leaves_with_path(prefill_cache)
-              if _is_kv(path) or is_row_state(path)}
+              if _is_kv(path) or is_by_row(path)}
         return first, kv
 
     def _strip_cache(self, kv: dict):
@@ -1402,8 +1451,10 @@ class Engine:
             functools.partial(self._prefiller.init, jax.random.PRNGKey(0)),
             jnp.zeros((1, 1), jnp.int32))['cache']
 
+        from tpusystem.train.cursors import is_by_row
+
         def fill(path, leaf):
-            if not (_is_kv(path) or is_row_state(path)):
+            if not (_is_kv(path) or is_by_row(path)):
                 return jnp.zeros(leaf.shape, leaf.dtype)
             name = jax.tree_util.keystr(path)
             if name not in kv:
@@ -1582,8 +1633,11 @@ class Engine:
         # inactive row may keep embedding — masked, never emitted)
         read = counted[0] if counted else self._tokens_dev
         read.copy_to_host_async()
+        words = counted[1] if self._selections else None
+        if words is not None:
+            words.copy_to_host_async()
         self.overlap['ahead' if ahead else 'synchronous'] += 1
-        return _Tick(read, rows, bool(counted))
+        return _Tick(read, rows, bool(counted), words=words)
 
     def _dispatch_ahead(self, tick: _Tick) -> _Tick | None:
         """Clear on the device every row whose token in ``tick`` is its
@@ -1622,6 +1676,9 @@ class Engine:
         token, routing = read, None
         if tick.counted:
             token, routing = self._split_read(read)
+        if tick.words is not None:           # routing_selections
+            routing = np.concatenate([routing.reshape(self.rows, -1),
+                                      np.asarray(tick.words)], axis=1)
         emitted, finished = {}, []
         with annotate('tpusystem.engine.rows'):
             for row, seated in tick.rows.items():
@@ -1808,3 +1865,93 @@ class _Tick:
     rows: dict                       # row -> _RowState
     counted: bool
     host: np.ndarray | None = None
+    words: jax.Array | None = None   # the selections, for routing_selections
+
+
+# ----------------------------------------- window and learned sparse attention
+
+def _cache_kind(path) -> str | None:
+    """Which part of the cache a leaf is, for ``Engine.cache_bytes``:
+    ``'kv'`` (the paged pool's keys, values or latent rows), ``'index'``
+    (a sparse-attention indexer's keys, paged beside them), ``'state'``
+    (a recurrent layer's per-row state), ``'window'`` (a window layer's
+    per-row window cache); ``None`` for tables and cursors."""
+    from tpusystem.train.cursors import is_window
+    if _is_kv(path):
+        indexer = jax.tree_util.DictKey('indexer') in path
+        return 'index' if indexer else 'kv'
+    if is_row_state(path):
+        return 'state'
+    return 'window' if is_window(path) else None
+
+
+def is_windowed(module) -> bool:
+    """Whether the module's decode cache holds a window cache leaf, read
+    from the shapes its decode clone declares."""
+    from tpusystem.train.cursors import holds_window
+    shapes = jax.eval_shape(
+        functools.partial(_decoder(module).init, jax.random.PRNGKey(0)),
+        jnp.zeros((1, 1), jnp.int32))
+    return holds_window(shapes.get('cache', {}))
+
+
+def window_reason(module, *, share_prefix: bool = False, draft_module=None,
+                  sharded: bool = False) -> str | None:
+    """Why the engine cannot serve this module with these options, where a
+    window cache (:func:`is_windowed`) is in the way; else ``None``."""
+    wanted = [
+        (share_prefix, 'share_prefix=True', 'a shared prefix is adopted by '
+         'blocks of the pool, and a window cache has no blocks: a row would '
+         "need the prefix's window rows copied in at admission"),
+        (draft_module is not None, 'a draft module (speculative rows)',
+         'a rejected draft token cannot be rewound out of a window cache: '
+         'its slot overwrote an older position the row still attends '
+         '(cursors.rewind refuses it)'),
+        (sharded, 'a mesh', 'the window cache has no sharding contract '
+         '(pool_shardings shards keys and values by head)')]
+    for asked, name, why in wanted:
+        if asked and any(is_windowed(each) for each in (module, draft_module)
+                         if each is not None):
+            return (f'{name} does not compose with a window cache '
+                    f'({type(module).__name__}): {why}')
+    return None
+
+
+def sparse_attention_of(decoder, cache) -> dict | None:
+    """How a decoder with window and learned sparse attention layers reads
+    its cache (``None`` for any other): ``index_topk`` and ``window`` (the
+    module's), how many layers of each kind, the indexer's bytes a
+    position (``index`` leaves) and the window cache's bytes a row
+    (``window`` leaves), and which read each kind takes: ``sparse_read``
+    ``'kernel'`` (the indexer's and the latent pool's block walks, the
+    selection a mask: every held row fetched) or ``'gather'`` (whole rows
+    gathered through the table, off the TPU or where a plan refuses the
+    shapes); ``window_read`` ``'window cache'`` (the row's window cache
+    read whole, masked by the position each slot holds). The scheduler
+    marks it once in its tracer: ``sparse_attention``."""
+    if not hasattr(decoder, 'index_topk'):
+        return None
+    leaves = jax.tree_util.tree_leaves_with_path(cache)
+    index = [leaf for path, leaf in leaves if _cache_kind(path) == 'index']
+    window = [leaf for path, leaf in leaves if _cache_kind(path) == 'window']
+    read = 'gather'
+    if on_tpu() and decoder.decode_pages is not None:
+        from tpusystem.ops.pallas.index_scores import index_plan
+        from tpusystem.ops.pallas.latent_attention import latent_plan
+        blocks, block = decoder.decode_pages
+        max_blocks = decoder.max_seq // block
+        if index and index_plan(
+                decoder.index_heads, decoder.index_dim, block, max_blocks,
+                index[0].dtype, False) is not None and latent_plan(
+                decoder.heads, decoder.kv_rank, block, max_blocks,
+                index[0].dtype, False) is not None:
+            read = 'kernel'
+    return {'index_topk': decoder.index_topk,
+            'window': decoder.window,
+            'full_layers': len(index), 'window_layers': len(window),
+            'index_bytes_per_position': sum(
+                leaf.shape[-1] * leaf.dtype.itemsize for leaf in index),
+            'window_bytes_per_row': sum(
+                leaf.size * leaf.dtype.itemsize // leaf.shape[0]
+                for leaf in window),
+            'sparse_read': read, 'window_read': 'window cache'}

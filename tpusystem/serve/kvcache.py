@@ -337,15 +337,16 @@ def adopt_prefill(cache, prefill_cache, blocks, tables, row, length):
     whatever an evicted row left) and a shared prefix (write-once).
     ``row``/``length`` set the row's cursors to the prompt length. A
     recurrent layer's per-row state leaves (``state``/``conv``:
-    :func:`tpusystem.train.cursors.is_row_state`) have no blocks: the
-    prefill's row 0 overwrites row ``row`` whole, in this same program.
+    :func:`tpusystem.train.cursors.is_row_state`) and a window layer's
+    window cache (``window``) have no blocks: the prefill's row 0
+    overwrites row ``row`` whole, in this same program.
     Every ``table`` leaf takes ``tables``, the host authority's map, as
     :func:`write_tables` writes it: an admission is this one program. One
     compiled program for every admission (prefill strips share one shape
     across buckets: the cache is allocated ``max_seq`` wide regardless of
     prompt length, and ``blocks`` is ``max_blocks`` wide whatever the
     prompt fills)."""
-    from tpusystem.train.cursors import is_cursor, is_row_state
+    from tpusystem.train.cursors import is_by_row, is_cursor
     source = {jax.tree_util.keystr(path): leaf for path, leaf
               in jax.tree_util.tree_leaves_with_path(prefill_cache)}
 
@@ -362,7 +363,7 @@ def adopt_prefill(cache, prefill_cache, blocks, tables, row, length):
                 rows.reshape(-1, block, lanes).astype(leaf.dtype),
                 mode='drop')
             return pool.reshape(leaf.shape)
-        if is_row_state(path):
+        if is_by_row(path):
             return leaf.at[row].set(
                 source[jax.tree_util.keystr(path)][0].astype(leaf.dtype))
         if is_cursor(path):

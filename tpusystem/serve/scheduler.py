@@ -214,6 +214,11 @@ class Scheduler:
             # each position fills (every pass of every layer when looped)
             tracer.instant('recurrence', cat='engine',
                            args=dict(engine.recurrence))
+        if tracer is not None and getattr(engine, 'sparse_attention', None):
+            # how the window and learned sparse attention layers read the
+            # cache (the selected-row walk or a gather; the window cache)
+            tracer.instant('sparse_attention', cat='engine',
+                           args=dict(engine.sparse_attention))
 
     @property
     def queue_depth(self) -> int:

@@ -18,6 +18,11 @@ addressed by row, with no positions a cursor could mask. :func:`is_row_state`
 names them for every path that edits a cache: a row is written whole
 (admission), gathered whole (:func:`gather_rows`), and never rewound
 (:func:`rewind`).
+
+A sliding-window layer keeps a fourth: a **window cache** (``window``,
+:func:`is_window`), the last positions of each row at slots that turn over,
+also addressed by row: written whole at admission, gathered whole, never
+rewound.
 """
 
 from __future__ import annotations
@@ -39,6 +44,15 @@ ROW_STATE_DIMS = {jax.tree_util.DictKey('state'): 3,
                   jax.tree_util.DictKey('conv'): 2}
 
 
+# A sliding-window layer's per-row window cache
+# (:func:`tpusystem.ops.attention.window_latent_attention`): ``window [batch,
+# slots, lanes]``, position ``p`` at slot ``p mod slots``. Like a recurrent
+# state it is addressed by row and holds no table; unlike one it has
+# positions, but a rewound cursor would read slots that newer positions
+# have overwritten.
+WINDOW_DIMS = {jax.tree_util.DictKey('window'): 2}
+
+
 def is_cursor(path) -> bool:
     """True when a cache tree path addresses a cursor leaf."""
     return path[-1] in CURSOR_KEYS
@@ -48,6 +62,23 @@ def is_row_state(path) -> bool:
     """True when a cache tree path addresses a recurrent layer's per-row
     state leaf (``state`` or ``conv``)."""
     return path[-1] in ROW_STATE_DIMS
+
+
+def is_window(path) -> bool:
+    """True when a cache tree path addresses a window cache leaf."""
+    return path[-1] in WINDOW_DIMS
+
+
+def is_by_row(path) -> bool:
+    """A leaf written and gathered whole by row: a per-row state or a
+    window cache."""
+    return is_row_state(path) or is_window(path)
+
+
+def holds_window(cache) -> bool:
+    """Whether a cache tree has a window cache leaf."""
+    return any(is_window(path) for path, _
+               in jax.tree_util.tree_leaves_with_path(cache))
 
 
 def holds_row_state(cache) -> bool:
@@ -78,6 +109,12 @@ def rewind(cache, cursor, *, state_stands: bool = False):
             'state cannot be rewound to an earlier position — speculative '
             'decoding over a recurrent layer needs a state snapshot to '
             'fall back to')
+    if not state_stands and holds_window(cache):
+        raise ValueError(
+            'this cache holds a window cache (window leaves): a rewound '
+            'cursor would read slots that newer positions overwrote — '
+            'speculative decoding over a window cache needs the overwritten '
+            'rows kept')
 
     def fix(path, leaf):
         if is_cursor(path):
@@ -113,8 +150,8 @@ def gather_rows(cache, rows):
     def fix(path, leaf):
         if is_cursor(path):
             axis = leaf.ndim - 1
-        elif is_row_state(path):
-            axis = leaf.ndim - 1 - ROW_STATE_DIMS[path[-1]]
+        elif is_by_row(path):
+            axis = leaf.ndim - 1 - {**ROW_STATE_DIMS, **WINDOW_DIMS}[path[-1]]
         else:
             axis = leaf.ndim - 4
         return jnp.take(leaf, rows, axis=axis)
